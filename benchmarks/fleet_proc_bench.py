@@ -31,6 +31,11 @@ The run is gated, not just measured:
 - ``readmitted_serves`` — the re-admitted replica takes real traffic again
   (its served count rises during the post-recovery level).
 
+CPU-ONLY SURFACE for now: a chip belongs to one process at a time, so N
+replica processes cannot share one. The bench forces ``JAX_PLATFORMS=cpu`` in
+this process and in every worker; its JSON line names ``"platform": "cpu"``
+and its qps is a count of what the host sustained, not a device metric.
+
 Run directly (``python benchmarks/fleet_proc_bench.py``) or as
 ``python bench.py --fleet-proc``. Prints ONE JSON line; exits nonzero when
 any gate fails.
@@ -49,9 +54,10 @@ import threading
 import time
 from concurrent.futures import ThreadPoolExecutor
 
-os.environ.setdefault("JAX_PLATFORMS", "cpu")  # before any jax import: the
-# reference engine and the worker processes must score on the SAME backend
-# or the bitwise gate compares different programs
+os.environ["JAX_PLATFORMS"] = "cpu"  # before any jax import, and inherited by
+# every worker: N processes cannot share a chip, and the reference engine and
+# the workers must score on the SAME backend or the bitwise gate compares
+# different programs
 
 import numpy as np
 
@@ -77,8 +83,7 @@ def _free_port() -> int:
 
 
 def _spawn(port: int, args) -> _Worker:
-    env = dict(os.environ)
-    env.setdefault("JAX_PLATFORMS", "cpu")
+    env = dict(os.environ)  # carries the JAX_PLATFORMS=cpu set at import
     proc = subprocess.Popen(
         [
             sys.executable, _WORKER,
@@ -414,7 +419,12 @@ def gates_green(result: dict) -> bool:
 
 
 def main(argv=None) -> int:
-    p = argparse.ArgumentParser(description=__doc__.splitlines()[0])
+    p = argparse.ArgumentParser(
+        description=__doc__.splitlines()[0],
+        epilog="CPU-only surface for now: N replica processes cannot share "
+               "one chip, so JAX_PLATFORMS=cpu is forced here and in every "
+               "worker; the qps is host work, not a device metric.",
+    )
     p.add_argument("--replicas", type=int, default=3,
                    help="replica PROCESS count behind the front router")
     p.add_argument("--rate-base", type=float, default=10.0,
@@ -445,6 +455,7 @@ def main(argv=None) -> int:
     if args.replicas < 2:
         p.error("--replicas must be >= 2 (the chaos gate kills one mid-load)")
     result = run(args)
+    result["platform"] = os.environ["JAX_PLATFORMS"]
     print(json.dumps(result))
     return 0 if gates_green(result) else 1
 

@@ -871,28 +871,28 @@ def main(argv=None) -> int:
         "columns and gates bitwise fused-vs-per-bucket parity on the mesh, "
         "run-to-run determinism, zero RE-solve DATA collectives, bounded "
         "gather/scatter collectives, tolerance vs the 1-device program, "
-        "and zero steady-state retraces. On a CPU backend the devices are "
-        "EMULATED via --xla_force_host_platform_device_count (set before "
-        "jax initializes); efficiency columns are then informational only",
+        "and zero steady-state retraces. CPU-ONLY SURFACE for now: the "
+        "devices are always EMULATED host devices (JAX_PLATFORMS is forced "
+        "to cpu), the gates are program-shape counts and the efficiency "
+        "columns informational; the four-chip check is "
+        "`chip_smoke.py --devices 4`",
     )
     args = p.parse_args(argv)
     if args.mesh_devices:
         if args.mesh_devices < 1:
             p.error("--mesh-devices must be >= 1")
         # must happen before the first jax import (all jax imports in this
-        # module are function-local for exactly this reason): emulate the
-        # device count on CPU backends; real-accelerator runs (JAX_PLATFORMS
-        # set to a device plugin) use their real devices
+        # module are function-local for exactly this reason): the mesh mode
+        # runs on emulated host devices, whatever the machine offers
         import os
 
-        if os.environ.get("JAX_PLATFORMS", "cpu") in ("", "cpu"):
-            os.environ["JAX_PLATFORMS"] = "cpu"
-            flags = os.environ.get("XLA_FLAGS", "")
-            if "xla_force_host_platform_device_count" not in flags:
-                os.environ["XLA_FLAGS"] = (
-                    flags
-                    + f" --xla_force_host_platform_device_count={args.mesh_devices}"
-                )
+        os.environ["JAX_PLATFORMS"] = "cpu"
+        flags = os.environ.get("XLA_FLAGS", "")
+        if "xla_force_host_platform_device_count" not in flags:
+            os.environ["XLA_FLAGS"] = (
+                flags
+                + f" --xla_force_host_platform_device_count={args.mesh_devices}"
+            )
         result = run_mesh(
             args.passes, args.samples, args.users, args.items, args.features,
             args.mesh_devices, args.reps,

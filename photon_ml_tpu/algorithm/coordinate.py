@@ -132,15 +132,26 @@ class Coordinate:
 
 
 def pad_fixed_effect_model(model, dataset):
-    """Pad a fixed-effect model's [D] coefficients to a feature-padded dataset's
-    dim and place them under the dataset's coefficient sharding (the 2-D mesh
-    backend, parallel/feature_sharded.py). No-op without a coef_sharding."""
-    sharding = getattr(dataset, "coef_sharding", None)
-    if sharding is None:
-        return model
+    """Place a fixed-effect model's [D] coefficients where the dataset's
+    solves will leave them. On a feature-sharded dataset (the 2-D mesh
+    backend, parallel/feature_sharded.py) that pads them to the padded dim
+    under ``coef_sharding``; on a sample-sharded (1-D mesh) dataset it
+    replicates them over the mesh — the solve returns its coefficients that
+    way, and an initial vector typed without the mesh would give the first
+    update its own jit cache key (one more trace + compile of the whole
+    solver on the second update). No-op for single-device datasets."""
     import jax
 
     from photon_ml_tpu.models.glm import Coefficients
+
+    sharding = getattr(dataset, "coef_sharding", None)
+    if sharding is None:
+        mesh = getattr(dataset.data.labels.sharding, "mesh", None)
+        if mesh is None or mesh.devices.size == 1:
+            return model
+        from photon_ml_tpu.parallel.mesh import replicated_sharding
+
+        sharding = replicated_sharding(mesh)
 
     means = model.model.coefficients.means
     if means.shape[0] < dataset.dim:

@@ -19,6 +19,10 @@ per-process maps built from data slices would diverge.
 
 The parity bar (enforced by tests/test_multiprocess.py): an N-process run
 must match the single-process driver's model numerically.
+
+CPU-only surface for now: a chip belongs to one process at a time, so N
+processes on one host run on the CPU backend (as the tests do). One process
+drives all the chips of a host through ``GameEstimator(mesh=...)``.
 """
 
 from __future__ import annotations

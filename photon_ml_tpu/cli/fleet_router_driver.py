@@ -38,7 +38,10 @@ from photon_ml_tpu.cli.parsers import add_version_argument
 def build_arg_parser() -> argparse.ArgumentParser:
     p = argparse.ArgumentParser(
         prog="photon-fleet-router",
-        description="Fault-tolerant front router over N replica processes.",
+        description="Fault-tolerant front router over N replica processes. "
+                    "CPU-only surface for now: a chip belongs to one process "
+                    "at a time, so the replica processes of one host serve "
+                    "from the CPU backend.",
     )
     add_version_argument(p)
     p.add_argument("--backend", action="append", required=True,

@@ -48,10 +48,6 @@ def build_arg_parser() -> argparse.ArgumentParser:
     p.add_argument("--off-heap-index-map-directory", default=None)
     p.add_argument("--evaluators", default=None)
     p.add_argument("--model-id", default=None, help="ID to tag scores with")
-    p.add_argument("--compilation-cache-directory", default=None,
-                   help="Persistent XLA compilation cache: repeated runs skip "
-                        "recompiling the optimizer programs (jit warm start "
-                        "across processes)")
     p.add_argument("--compute-backend", default="host", choices=["host", "mesh"],
                    help="'mesh' scores with datasets sharded over the device mesh")
     p.add_argument("--scoring-engine", default="fused", choices=["fused", "eager"],
@@ -96,7 +92,7 @@ def run(args: argparse.Namespace) -> dict:
 
     from photon_ml_tpu.cli.runtime import configure_compilation_cache
 
-    configure_compilation_cache(args)
+    configure_compilation_cache()
     root = args.root_output_directory
     from photon_ml_tpu.cli.runtime import prepare_output_root
 

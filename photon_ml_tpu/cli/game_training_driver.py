@@ -150,10 +150,6 @@ def build_arg_parser() -> argparse.ArgumentParser:
                    help="Deterministic fault injection plan, e.g. "
                         "'checkpoint.write.manifest:crash:2' (also via the "
                         "PHOTON_FAULT_PLAN env var; resilience/faultpoints.py)")
-    p.add_argument("--compilation-cache-directory", default=None,
-                   help="Persistent XLA compilation cache: repeated runs skip "
-                        "recompiling the optimizer programs (jit warm start "
-                        "across processes)")
     p.add_argument("--fe-storage-dtype", default=None, choices=["bf16"],
                    help="Store dense fixed-effect features in bfloat16 (half "
                         "the HBM traffic; f32 accumulation on the MXU). "
@@ -291,7 +287,7 @@ def run(args: argparse.Namespace, emitter: Optional[EventEmitter] = None) -> dic
     # fault plan first: distributed.init is itself an injectable fault point
     arm_fault_plan_from_args(args)
     rank, nproc = initialize_distributed_from_args(args)
-    configure_compilation_cache(args)
+    configure_compilation_cache()
     emitter = emitter or EventEmitter()
     root = args.root_output_directory
     prepare_output_root(root, args.override_output_directory, rank, nproc)

@@ -2,23 +2,32 @@
 
 from __future__ import annotations
 
+import os
 
-def configure_compilation_cache(args) -> None:
-    """Point JAX at a persistent on-disk compilation cache when the driver was
-    given --compilation-cache-directory: repeated runs skip recompiling the
-    optimizer programs (jit warm start across processes)."""
-    cache_dir = getattr(args, "compilation_cache_directory", None)
-    if not cache_dir:
-        return
-    enable_compilation_cache(cache_dir)
+# photon_ml_tpu/cli/runtime.py -> the directory that holds the package
+_CHECKOUT = os.path.dirname(
+    os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+)
 
 
-def enable_compilation_cache(cache_dir: str, min_compile_secs: float = 0.1) -> None:
-    """The one place cache policy lives (CLI drivers, bench, test conftest)."""
+def configure_compilation_cache() -> str:
+    """THE compile-cache policy of every entry point that runs on a device
+    (the four CLI drivers, bench.py, chip_smoke.py); returns the directory.
+
+    Where ``JAX_COMPILATION_CACHE_DIR`` is set, JAX has already read it and no
+    code sets a directory. Otherwise the cache lives at the fixed path
+    ``<checkout>/.jax_cache`` (git-ignored): a directory that moves between
+    runs never hits, so never the home directory, a temp name, a pid or a
+    time. Every program is cached, however quick its compile: a threshold
+    makes "did the second run add entries?" depend on timing jitter."""
     import jax
 
-    jax.config.update("jax_compilation_cache_dir", cache_dir)
-    jax.config.update("jax_persistent_cache_min_compile_time_secs", min_compile_secs)
+    cache_dir = os.environ.get("JAX_COMPILATION_CACHE_DIR")
+    if not cache_dir:
+        cache_dir = os.path.join(_CHECKOUT, ".jax_cache")
+        jax.config.update("jax_compilation_cache_dir", cache_dir)
+    jax.config.update("jax_persistent_cache_min_compile_time_secs", 0.0)
+    return cache_dir
 
 
 def add_ingest_arguments(parser) -> None:
@@ -97,7 +106,11 @@ def add_distributed_arguments(parser, purpose: str) -> None:
     drivers (one definition so the two cannot drift)."""
     parser.add_argument(
         "--distributed-coordinator", default=None,
-        help=f"host:port of process 0 (or 'auto') for {purpose}",
+        help=f"host:port of process 0 (or 'auto') for {purpose}. CPU-only "
+             "surface for now: a chip belongs to one process at a time, so "
+             "several processes on one host run on the CPU backend; one "
+             "process drives all chips of a host through --compute-backend "
+             "mesh",
     )
     parser.add_argument("--distributed-num-processes", type=int, default=None)
     parser.add_argument("--distributed-process-id", type=int, default=None)
@@ -123,7 +136,6 @@ def prepare_output_root(root: str, override: bool, rank: int, nproc: int) -> Non
     the ordering barrier before any peer's first write — no marker files,
     which would go stale across runs), so a rank-0 failure fails EVERY rank
     promptly instead of leaving peers blocked until the peer-loss timeout."""
-    import os
     import shutil
 
     failure = None

@@ -68,7 +68,6 @@ def build_arg_parser() -> argparse.ArgumentParser:
                         "serving requests must map features into the SAME global "
                         "columns the checkpointed coefficients were trained in")
     p.add_argument("--model-id", default=None)
-    p.add_argument("--compilation-cache-directory", default=None)
     from photon_ml_tpu.cli.runtime import add_ingest_arguments, add_serving_arguments
 
     add_ingest_arguments(p)
@@ -83,7 +82,7 @@ def run(args: argparse.Namespace) -> dict:
     from photon_ml_tpu.serving import FrontendConfig
     from photon_ml_tpu.serving.hotswap import GenerationWatcher, serve_from_checkpoint
 
-    configure_compilation_cache(args)
+    configure_compilation_cache()
     root = args.root_output_directory
     prepare_output_root(root, args.override_output_directory, 0, 1)
     logger = PhotonLogger(os.path.join(root, "logs", "photon.log"), level=args.log_level)

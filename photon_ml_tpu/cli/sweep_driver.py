@@ -125,7 +125,6 @@ def build_arg_parser() -> argparse.ArgumentParser:
     p.add_argument("--fault-plan", default=None,
                    help="Deterministic fault injection plan "
                         "(resilience/faultpoints.py; also PHOTON_FAULT_PLAN)")
-    p.add_argument("--compilation-cache-directory", default=None)
     from photon_ml_tpu.cli.runtime import add_ingest_arguments
 
     add_ingest_arguments(p)
@@ -141,7 +140,7 @@ def run(args: argparse.Namespace) -> dict:
     )
 
     arm_fault_plan_from_args(args)
-    configure_compilation_cache(args)
+    configure_compilation_cache()
     root = args.root_output_directory
     prepare_output_root(root, args.override_output_directory, 0, 1)
     logger = PhotonLogger(os.path.join(root, "logs", "photon.log"))

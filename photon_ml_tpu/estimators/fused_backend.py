@@ -101,8 +101,7 @@ def fused_pass_ineligibilities(estimator, opt_configs: Mapping) -> list[str]:
 def _fused_step(task, fe_config, re_configs: tuple, mesh, re_solver: str = "lbfgs"):
     """Cross-fit trace cache for the fused pass.
 
-    Data is a jit ARGUMENT here (unlike bench.py's single-process
-    make_jitted_game_step, which bakes single-device data in as constants):
+    Data is a jit ARGUMENT (as in parallel/game.make_jitted_game_step):
     estimator fits repeat — warm-up + timed runs, sweeps, notebooks — and
     with argument-form data every fit after the first is a jit-cache hit
     instead of a full retrace of the pass.

@@ -133,9 +133,9 @@ class GameEstimator:
     # bench.py gates its bf16 variant on 1% objective parity.
     fe_storage_dtype: Optional[object] = None
     # Same for the random-effect bucket blocks + per-sample scoring values on
-    # the fused pass (the on-chip profile's hot loops,
-    # benchmarks/trace_summary_tpu.md) — the configuration bench.py's bf16
-    # variant measures sets BOTH storage dtypes.
+    # the fused pass (the hot loops of the 2026-07-31 on-chip trace,
+    # ROADMAP.md S2) — the configuration bench.py's bf16 variant measures sets
+    # BOTH storage dtypes.
     re_storage_dtype: Optional[object] = None
     # Run each coordinate-descent pass as ONE jitted SPMD program
     # (parallel/game.py — the program bench.py measures) instead of the host

@@ -17,6 +17,7 @@ import dataclasses
 import enum
 from typing import Callable, Optional, Sequence
 
+import jax
 import jax.numpy as jnp
 import numpy as np
 
@@ -253,7 +254,9 @@ class EvaluationSuite:
 
         Scores longer than the label array are sliced: mesh placement pads the
         sample axis to the device count and padded rows are metric-inert."""
-        total = np.asarray(raw_scores)[: len(self.labels)] + self.offsets
+        # the one device->host transfer of a validation round, named so that
+        # runtime_guard.sync_discipline regions can hold a validating fit
+        total = np.asarray(jax.device_get(raw_scores))[: len(self.labels)] + self.offsets
         results: dict[str, float] = {}
         for ev in self.evaluators:
             if isinstance(ev, MultiEvaluator):

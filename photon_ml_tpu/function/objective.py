@@ -126,7 +126,9 @@ class GLMObjective:
             and X.values.ndim == 2
             and X.dtype in (jnp.float32, jnp.bfloat16)
             and coef.dtype == jnp.float32
-            and pallas_glm.should_fuse(X.n_cols, per_device=self.psum_axis is not None)
+            and pallas_glm.should_fuse(
+                X.n_cols, X.dtype, per_device=self.psum_axis is not None
+            )
         )
 
     def _fused_value_and_gradient(self, data: LabeledData, coef: Array, l2_weight):

@@ -46,6 +46,7 @@ import re
 import shutil
 from typing import Optional
 
+import jax
 import jax.numpy as jnp
 import numpy as np
 
@@ -134,7 +135,15 @@ def _load_npz(path: str) -> dict:
 
 
 def _model_to_arrays(model) -> tuple[dict, dict]:
-    """(json-metadata, arrays) for one coordinate model."""
+    """(json-metadata, host arrays) for one coordinate model. The model's
+    device arrays come to the host in ONE named transfer (jax.device_get):
+    a checkpoint save is an intended boundary, and
+    runtime_guard.sync_discipline disallows implicit ones."""
+    meta, arrays = _model_to_device_arrays(model)
+    return meta, {k: np.asarray(v) for k, v in jax.device_get(arrays).items()}
+
+
+def _model_to_device_arrays(model) -> tuple[dict, dict]:
     if isinstance(model, FixedEffectModel):
         glm = model.model
         meta = {
@@ -142,9 +151,9 @@ def _model_to_arrays(model) -> tuple[dict, dict]:
             "feature_shard_id": model.feature_shard_id,
             "task": TaskType(glm.task).value,
         }
-        arrays = {"means": np.asarray(glm.coefficients.means)}
+        arrays = {"means": glm.coefficients.means}
         if glm.coefficients.variances is not None:
-            arrays["variances"] = np.asarray(glm.coefficients.variances)
+            arrays["variances"] = glm.coefficients.variances
         return meta, arrays
 
     if isinstance(model, RandomEffectModel):
@@ -158,8 +167,8 @@ def _model_to_arrays(model) -> tuple[dict, dict]:
             "entity_ids_int": ids_are_int,
         }
         arrays = {
-            "coeffs": np.asarray(model.coeffs),
-            "proj_indices": np.asarray(model.proj_indices),
+            "coeffs": model.coeffs,
+            "proj_indices": model.proj_indices,
             "entity_ids": (
                 np.asarray(entity_ids, dtype=np.int64)
                 if ids_are_int
@@ -167,7 +176,7 @@ def _model_to_arrays(model) -> tuple[dict, dict]:
             ),
         }
         if model.variances is not None:
-            arrays["variances"] = np.asarray(model.variances)
+            arrays["variances"] = model.variances
         proj = model.projector
         if proj is not None:
             from photon_ml_tpu.data.projector import RandomProjector
@@ -176,15 +185,15 @@ def _model_to_arrays(model) -> tuple[dict, dict]:
                 raise TypeError(
                     f"Cannot checkpoint projector of type {type(proj).__name__}"
                 )
-            arrays["projector_matrix"] = np.asarray(proj.matrix)
+            arrays["projector_matrix"] = proj.matrix
             meta["projector_intercept_index"] = proj.intercept_index
             norm = proj.normalization
             if norm is not None:
                 meta["projector_norm_intercept_index"] = norm.intercept_index
                 if norm.factors is not None:
-                    arrays["projector_norm_factors"] = np.asarray(norm.factors)
+                    arrays["projector_norm_factors"] = norm.factors
                 if norm.shifts is not None:
-                    arrays["projector_norm_shifts"] = np.asarray(norm.shifts)
+                    arrays["projector_norm_shifts"] = norm.shifts
         return meta, arrays
 
     raise TypeError(f"Unknown model type: {type(model).__name__}")

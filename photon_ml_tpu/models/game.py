@@ -162,14 +162,14 @@ class RandomEffectModel:
             or self.entity_ids == tuple(dataset.entity_ids)
         ):
             return self
+        # the re-layout below is host work by design: its device->host reads
+        # are named (runtime_guard.sync_discipline disallows implicit ones)
+        src_proj, dst_proj = jax.device_get((self.proj_indices, dataset.proj_indices))
         if self.entity_ids == tuple(dataset.entity_ids) and np.array_equal(
-            np.asarray(self.proj_indices), np.asarray(dataset.proj_indices)
+            src_proj, dst_proj
         ):
             return self
-        src_proj = np.asarray(self.proj_indices)
-        dst_proj = np.asarray(dataset.proj_indices)
-        src = np.asarray(self.coeffs)
-        src_var = None if self.variances is None else np.asarray(self.variances)
+        src, src_var = jax.device_get((self.coeffs, self.variances))
         E, K = dst_proj.shape
         out = np.zeros((E, K), dtype=src.dtype)
         out_var = None if src_var is None else np.zeros((E, K), dtype=src_var.dtype)

@@ -29,8 +29,9 @@ even when their margins overflow the pointwise loss.
 
 Gating: OFF by default. Enable with ``enable_pallas(True)`` or
 ``PHOTON_PALLAS=1``. The fused path only engages on the TPU backend for dense
-float inputs with D <= MAX_FUSED_DIM (the whole coefficient vector and an
-[BN, D] block must fit VMEM); everything else falls back to the XLA path.
+float inputs with D <= MAX_FUSED_DIM[storage dtype] (the whole coefficient
+vector and an [BN, D] block must fit VMEM); everything else falls back to the
+XLA path.
 CPU tests run the same kernel in interpret mode.
 """
 
@@ -45,10 +46,18 @@ import jax.numpy as jnp
 
 Array = jnp.ndarray
 
-# [BLOCK_ROWS, D] f32 block + [D, 1] coefficients + [D, 1] accumulator must fit
-# in ~16 MB VMEM with headroom for double buffering: 512 x 4096 f32 = 8 MB.
+# Rows per grid step, and the widest D the value+gradient and HVP kernels are
+# admitted at, per storage dtype. The [BLOCK_ROWS, D] X block is double-buffered
+# inside the chip's 16 MiB default scoped VMEM next to the [D, 1] operands
+# (lane-padded to [D, 128]: 2 MiB each at D=4096) and the in-kernel transposed
+# copy. Measured on a v5e (PR 21), all three kernels at every D in {8, 64, 128,
+# 256, 512, 1024, 2048, 4096}: bf16 storage compiles through D=4096; f32
+# storage (f32-precision contractions, ``_dot``) compiles through D=1024, and
+# Mosaic refuses the HVP kernel at D=2048 ("Ran out of memory in memory space
+# vmem"). The gate admits only what compiled, so ``should_fuse`` never hands
+# Mosaic a shape it rejects.
 BLOCK_ROWS = 512
-MAX_FUSED_DIM = 4096
+MAX_FUSED_DIM = {"float32": 1024, "bfloat16": 4096}
 
 _enabled: bool | None = None
 
@@ -100,15 +109,25 @@ def pallas_override(on: bool | None):
 
 def interpret_mode() -> bool:
     """CPU test hook: PHOTON_PALLAS_INTERPRET=1 runs the kernel interpreted,
-    letting the integration path be exercised without a TPU."""
-    return os.environ.get("PHOTON_PALLAS_INTERPRET", "") not in ("", "0")
+    letting the integration path be exercised without a TPU. On a TPU backend
+    it is an error: an interpreted kernel there would pass for a compiled
+    one."""
+    on = os.environ.get("PHOTON_PALLAS_INTERPRET", "") not in ("", "0")
+    if on and jax.default_backend() == "tpu":
+        raise RuntimeError(
+            "PHOTON_PALLAS_INTERPRET is set on a TPU backend: the fused "
+            "kernels would run interpreted while reporting as compiled; "
+            "unset it"
+        )
+    return on
 
 
-def should_fuse(n_cols: int, *, per_device: bool = False) -> bool:
+def should_fuse(n_cols: int, dtype, *, per_device: bool = False) -> bool:
     """True when the fused kernel should replace the two-matmul XLA path.
 
-    Trace-time decision: backend is the default backend of the process. The
-    kernel is compiled for single-device execution — under a >1-device mesh
+    Trace-time decision: ``dtype`` is the design matrix's STORAGE dtype (it
+    sets the admitted width, MAX_FUSED_DIM); backend is the default backend
+    of the process. The kernel is compiled for single-device execution — under a >1-device mesh
     GSPMD cannot partition an opaque pallas_call, so the GSPMD paths keep the
     XLA lowering UNLESS the caller runs inside shard_map (``per_device=True``:
     each device fuses over its own block and the objective psums the sums —
@@ -116,17 +135,13 @@ def should_fuse(n_cols: int, *, per_device: bool = False) -> bool:
     """
     if not pallas_enabled():
         return False
-    if n_cols > MAX_FUSED_DIM:
+    if n_cols > MAX_FUSED_DIM.get(jnp.dtype(dtype).name, 0):
         return False
     if interpret_mode():
         return True
-    try:
-        if jax.default_backend() != "tpu":
-            return False
-        return per_device or len(jax.devices()) == 1
-    except Exception:
+    if jax.default_backend() != "tpu":
         return False
-
+    return per_device or len(jax.devices()) == 1
 
 
 def _block_prologue(i, x_ref, wgt_ref, n_valid):
@@ -152,6 +167,21 @@ def _mxu_dtype(x, v):
     return v.astype(jnp.bfloat16) if x.dtype == jnp.bfloat16 else v
 
 
+def _dot(a, b):
+    """MXU contraction with f32 accumulation. f32 operands contract at full
+    f32 precision: Mosaic's default rounds them to bf16 passes, which on a
+    v5e put the f32 kernels 2e-3..3e-2 (relative) off the float64 reference
+    while the stock XLA lowering of the same matrix-vector products sat at
+    1e-7 (PR 21) — f32 storage must mean f32 math, bf16 storage is the
+    opt-in. bf16 x bf16 products are exact in f32 either way."""
+    precision = (
+        jax.lax.Precision.HIGHEST
+        if a.dtype == jnp.float32 and b.dtype == jnp.float32
+        else None
+    )
+    return jnp.dot(a, b, preferred_element_type=jnp.float32, precision=precision)
+
+
 def _kernel(loss_and_dz, n_valid, x_ref, y_ref, off_ref, wgt_ref, coef_ref,
             val_ref, grad_ref, wsum_ref):
     """One grid step: fused contractions for rows [i*BN, (i+1)*BN)."""
@@ -160,7 +190,7 @@ def _kernel(loss_and_dz, n_valid, x_ref, y_ref, off_ref, wgt_ref, coef_ref,
     i = pl.program_id(0)
     f32 = jnp.float32
     x, w, live = _block_prologue(i, x_ref, wgt_ref, n_valid)
-    z = jnp.dot(x, _mxu_dtype(x, coef_ref[...]), preferred_element_type=f32)  # [BN, 1]
+    z = _dot(x, _mxu_dtype(x, coef_ref[...]))  # [BN, 1]
     z = z + off_ref[...]
     l, dz = loss_and_dz(z, y_ref[...])
     wl = jnp.where(live, w * l, 0.0)
@@ -171,9 +201,7 @@ def _kernel(loss_and_dz, n_valid, x_ref, y_ref, off_ref, wgt_ref, coef_ref,
     # them, which is how the scalar-indexed form survived CPU testing).
     part_val = jnp.sum(wl, axis=(0, 1), keepdims=True)
     part_wsum = jnp.sum(wdz, axis=(0, 1), keepdims=True)
-    part_grad = jnp.dot(
-        x.T, _mxu_dtype(x, wdz.astype(f32)), preferred_element_type=f32
-    )  # [D, 1]
+    part_grad = _dot(x.T, _mxu_dtype(x, wdz.astype(f32)))  # [D, 1]
 
     @pl.when(i == 0)
     def _init():
@@ -275,14 +303,12 @@ def _hvp_kernel(dzz, n_valid, x_ref, y_ref, off_ref, wgt_ref,
     i = pl.program_id(0)
     f32 = jnp.float32
     x, w, live = _block_prologue(i, x_ref, wgt_ref, n_valid)
-    z = jnp.dot(x, _mxu_dtype(x, coef_ref[...]), preferred_element_type=f32)
+    z = _dot(x, _mxu_dtype(x, coef_ref[...]))
     z = z + off_ref[...]  # [BN, 1]
-    dv = jnp.dot(x, _mxu_dtype(x, v_ref[...]), preferred_element_type=f32)
+    dv = _dot(x, _mxu_dtype(x, v_ref[...]))
     dv = dv + sv_ref[...]  # directional margin shift, (1, 1) broadcast
     u = jnp.where(live, w * dzz(z, y_ref[...]) * dv, 0.0)
-    part_vec = jnp.dot(
-        x.T, _mxu_dtype(x, u.astype(f32)), preferred_element_type=f32
-    )  # [D, 1]
+    part_vec = _dot(x.T, _mxu_dtype(x, u.astype(f32)))  # [D, 1]
     # (1, 1) keepdims: scalar VMEM stores are illegal on real TPU (see _kernel)
     part_usum = jnp.sum(u, axis=(0, 1), keepdims=True)
 
@@ -370,7 +396,7 @@ def _hess_kernel(dzz, n_valid, x_ref, y_ref, off_ref, wgt_ref, coef_ref,
     i = pl.program_id(0)
     f32 = jnp.float32
     x, w, live = _block_prologue(i, x_ref, wgt_ref, n_valid)
-    z = jnp.dot(x, _mxu_dtype(x, coef_ref[...]), preferred_element_type=f32)
+    z = _dot(x, _mxu_dtype(x, coef_ref[...]))
     z = z + off_ref[...]  # [BN, 1]
     d = jnp.where(live, w * dzz(z, y_ref[...]), 0.0)  # [BN, 1]
     # variance/Hessian math runs at f32 even for bf16 storage (the stock
@@ -378,7 +404,7 @@ def _hess_kernel(dzz, n_valid, x_ref, y_ref, off_ref, wgt_ref, coef_ref,
     a = x.astype(f32)
     a = (a - shift_ref[...]) * factor_ref[...]  # [BN, D], shift/factor [1, D]
     a = jnp.where(live, a, 0.0)  # masked rows contribute nothing even if inf
-    part = jnp.dot(a.T, a * d, preferred_element_type=f32)  # [D, D]
+    part = _dot(a.T, a * d)  # [D, D]
 
     @pl.when(i == 0)
     def _init():

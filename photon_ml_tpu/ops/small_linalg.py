@@ -4,8 +4,8 @@ XLA lowers ``jnp.linalg.cholesky`` / ``solve_triangular`` to LAPACK-style
 custom-calls; batched over thousands of tiny [K, K] systems (the NEWTON
 random-effect regime, K <= a few dozen) the on-chip profile shows those calls
 costing more than the entire surrounding optimizer loop
-(benchmarks/trace_summary_tpu.md: [2000, 5, 8, 8] Cholesky custom-calls ~8 ms
-per invocation). A K x K factorization is ~K^3/3 flops — microseconds of VPU
+(the 2026-07-31 on-chip trace, ROADMAP.md S2: [2000, 5, 8, 8] Cholesky
+custom-calls ~8 ms per invocation). A K x K factorization is ~K^3/3 flops — microseconds of VPU
 work when expressed as K trace-time-unrolled vector steps that XLA can fuse.
 
 These routines unroll over the (static) K axis and vectorize over arbitrary
@@ -115,7 +115,7 @@ def small_spd_inverse_diag(H: Array) -> Array:
     regardless of the K-column RHS). This is the per-entity FULL-variance
     hot op (DistributedOptimizationProblem.computeVariances semantics) —
     vmapped over entities it otherwise lowers to the slow batched-Cholesky
-    custom-call (benchmarks/trace_summary_tpu.md)."""
+    custom-call (ROADMAP.md S2)."""
     K = H.shape[-1]
     L = small_cholesky(H)
     eye = jnp.broadcast_to(jnp.eye(K, dtype=H.dtype), H.shape)

@@ -59,8 +59,8 @@ def two_loop_direction(g: Array, S: Array, Y: Array, rho: Array, n_written: Arra
     slot indices: the previous circular-buffer form indexed ``S[j]`` with a
     traced slot inside ``lax.fori_loop`` — 2m sequential dynamic-slice ops
     per optimizer iteration, pure latency in the vmapped random-effect
-    regime (the solver while_loops are the pass's measured floor,
-    benchmarks/trace_summary_tpu.md). Static slices fuse into plain vector
+    regime (the solver while_loops were the pass's floor in the 2026-07-31
+    on-chip trace, ROADMAP.md S2). Static slices fuse into plain vector
     op chains instead.
     """
     m = S.shape[0]

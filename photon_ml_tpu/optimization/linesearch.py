@@ -98,8 +98,8 @@ def strong_wolfe(
     vmapped batched solves (the random-effect regime): one while_loop body
     runs max-lane iterations, so a single already-converged lane otherwise
     drags EVERY lane through ~max_iters wasted evaluations per outer step —
-    the measured latency floor of the flagship pass
-    (benchmarks/trace_summary_tpu.md).
+    the latency floor of the flagship pass in the 2026-07-31 on-chip trace
+    (ROADMAP.md S2).
 
     ``active`` (optional bool): the caller's own keep-iterating mask. A
     batched outer while_loop FREEZES a converged lane's carry but still

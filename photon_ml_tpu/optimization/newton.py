@@ -71,7 +71,7 @@ def _newton_direction(H: Array, g: Array) -> Array:
     Small systems (the vmapped random-effect regime) use the trace-time
     unrolled factorization of ops/small_linalg: the on-chip profile showed
     XLA's batched Cholesky custom-call costing more than the whole
-    surrounding optimizer loop at K=8 (benchmarks/trace_summary_tpu.md).
+    surrounding optimizer loop at K=8 (2026-07-31 trace, ROADMAP.md S2).
     """
     from photon_ml_tpu.ops import small_linalg
 

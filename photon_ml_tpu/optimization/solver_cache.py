@@ -51,7 +51,7 @@ def compute_variances(obj: GLMObjective, data, coef, l2, variance, dtype):
         H = H + jnp.diag((jnp.diag(H) == 0.0).astype(H.dtype))
         if H.shape[-1] <= small_linalg.MAX_UNROLL_DIM:
             # per-entity (vmapped) regime: the unrolled factorization avoids
-            # the batched-Cholesky custom-call (trace_summary_tpu.md)
+            # the batched-Cholesky custom-call (ROADMAP.md S2)
             return small_linalg.small_spd_inverse_diag(H)
         L = jnp.linalg.cholesky(H)
         eye = jnp.eye(H.shape[0], dtype=H.dtype)
@@ -981,11 +981,6 @@ def shard_mapped_glm_solver(
     """
     from jax.sharding import PartitionSpec as P
 
-    try:
-        shard_map = jax.shard_map  # jax >= 0.8
-    except AttributeError:  # pragma: no cover - older jax
-        from jax.experimental.shard_map import shard_map
-
     task = TaskType(task)
     loss = loss_for_task(task)
     minimize = build_minimizer(opt_config)
@@ -1027,16 +1022,14 @@ def shard_mapped_glm_solver(
             )
         # psum'd sums make every [D] optimizer state device-invariant, but the
         # while_loop obstructs shard_map's replication inference — disable the
-        # check (named check_vma in jax >= 0.8, check_rep before).
-        kwargs = dict(
+        # check.
+        mapped = jax.shard_map(
+            solve_block,
             mesh=mesh,
             in_specs=(specs_like(data, True), P(), P(), P()),
             out_specs=P(),
+            check_vma=False,
         )
-        try:
-            mapped = shard_map(solve_block, check_vma=False, **kwargs)
-        except TypeError:  # pragma: no cover - older jax
-            mapped = shard_map(solve_block, check_rep=False, **kwargs)
         return mapped(data, x0, l2, l1)
 
     return jax.jit(solve)

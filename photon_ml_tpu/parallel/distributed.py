@@ -17,7 +17,6 @@ of executors reading their HDFS splits.
 
 from __future__ import annotations
 
-import inspect
 from typing import Optional
 
 import jax
@@ -51,25 +50,18 @@ def initialize_multi_host(
 
     Failure model (docs/ARCHITECTURE.md "Failure model & recovery"): a slow
     coordinator bounds each attempt via ``initialization_timeout`` (seconds,
-    forwarded to ``jax.distributed.initialize`` where the installed jax
-    supports it), and a failed attempt (RuntimeError/OSError: coordinator not
+    forwarded to ``jax.distributed.initialize``), and a failed attempt (RuntimeError/OSError: coordinator not
     yet listening, transient DNS/socket errors) retries up to ``retries``
     times with exponential backoff + jitter starting at ``retry_base_delay``
     seconds — a flaky startup ordering is an incident, not a crash. The
     default of 0 retries preserves fail-fast for interactive use.
     """
-    already = getattr(jax.distributed, "is_initialized", None)
-    initialized = already() if callable(already) else False
-    if not initialized and (
+    if not jax.distributed.is_initialized() and (
         auto or coordinator_address is not None or num_processes is not None
     ):
         kwargs = {}
         if initialization_timeout is not None:
-            # older jax has no initialization_timeout; gate on the signature
-            # rather than crashing every multi-host launch there
-            params = inspect.signature(jax.distributed.initialize).parameters
-            if "initialization_timeout" in params:
-                kwargs["initialization_timeout"] = int(initialization_timeout)
+            kwargs["initialization_timeout"] = int(initialization_timeout)
 
         def _attempt():
             faultpoint(FP_DISTRIBUTED_INIT)

@@ -110,9 +110,9 @@ def build_sharded_game_data(
     matrix in bf16 (matvecs read half the HBM bytes and hit the MXU natively;
     accumulation stays f32 — see DenseDesignMatrix._mxu_dot).
     ``re_storage_dtype=jnp.bfloat16`` does the same for the random-effect
-    bucket blocks and the per-sample scoring values — the on-chip profile's
-    hot loops (trace_summary_tpu.md) read exactly those arrays every solver
-    iteration. Labels, weights, scores and coefficients keep ``dtype``."""
+    bucket blocks and the per-sample scoring values — the hot loops of the
+    2026-07-31 on-chip trace (ROADMAP.md S2) read exactly those arrays every
+    solver iteration. Labels, weights, scores and coefficients keep ``dtype``."""
     from photon_ml_tpu.data.matrix import as_design_matrix_with_storage
     from photon_ml_tpu.parallel.glm import shard_labeled_data
 
@@ -677,36 +677,21 @@ def make_jitted_game_step(
     """jit(game_train_step) with params donated — call as
     ``step(params) -> (params, diagnostics)``. One compiled XLA program per pass.
 
-    On a MULTI-device mesh ``data`` is passed as a jit ARGUMENT, never closed
-    over: closed-over arrays become jaxpr constants whose committed shardings
-    GSPMD ignores (it replicates constants), silently turning the whole pass
-    into per-device full-data recomputation — measured as a clean 1/m
+    ``data`` is passed as a jit ARGUMENT, never closed over. Closed-over
+    arrays become jaxpr constants: on a multi-device mesh GSPMD ignores their
+    committed shardings (it replicates constants), silently turning the whole
+    pass into per-device full-data recomputation — measured as a clean 1/m
     throughput collapse on an m-device mesh (benchmarks/device_scaling.py
-    caught it). As an argument, the ShardedGameData pytree's NamedShardings
-    bind the partitioning.
-
-    On a SINGLE device the closure form is kept deliberately: there is no
-    replication hazard, and letting XLA treat the data as compile-time
-    constants measures 3x faster on the flagship CPU bench (229k vs 75k
-    samples/s — constant folding and layout decisions the argument form
-    cannot make)."""
+    caught it); on one device the whole dataset is embedded in the module as
+    dense HLO constants (~0.5 KB of module text per f32 design-matrix row at
+    D=64), which makes the compile and its persistent-cache entry scale with
+    the dataset and stops working near the 2 GB module limit. As an argument,
+    the ShardedGameData pytree's shardings bind the partitioning and the
+    program is the one GameEstimator(fused_pass=True) runs
+    (estimators/fused_backend._fused_step)."""
 
     fuse_fe = mesh.devices.size == 1
     shard_mesh = mesh if mesh.devices.size > 1 else None
-
-    if shard_mesh is None:
-        def step_single(params):
-            return game_train_step(
-                data, params, task, fe_config, tuple(re_configs),
-                fuse_fe=fuse_fe, re_solver=re_solver,
-            )
-
-        step1 = jax.jit(step_single, donate_argnums=(0,))
-        # same inspection surface as the multi-device form; here the jitted
-        # callable IS the step (data is baked in as constants)
-        step1.jitted = step1
-        step1.data = data
-        return step1
 
     @functools.partial(jax.jit, donate_argnums=(1,))
     def _step(d, params):

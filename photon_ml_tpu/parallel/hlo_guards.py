@@ -56,9 +56,11 @@ _COLLECTIVE_KINDS = (
 )
 
 # `%name = <shape-or-tuple> <kind>(`  — shape may be a tuple like
-# `(f32[], f32[24]{0})`; layout suffixes `{1,0}` are part of the token.
+# `(f32[], f32[24]{0})`; layout suffixes `{1,0}` are part of the token, and a
+# TPU module's layouts carry tiling in parentheses (`f32[64]{0:T(128)}`), so
+# the tuple form admits one level of nested parentheses.
 _OP_RE = re.compile(
-    r"=\s*(\([^)]*\)|\S+)\s+("
+    r"=\s*(\((?:[^()]|\([^()]*\))*\)|\S+)\s+("
     + "|".join(_COLLECTIVE_KINDS)
     + r")(-start)?\("
 )

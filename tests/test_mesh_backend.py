@@ -141,6 +141,25 @@ class TestMeshBackend:
         devices = {s.device for s in coeffs.addressable_shards}
         assert len(devices) == 8
 
+    def test_second_pass_on_mesh_reuses_the_first_pass_programs(self, rng, eight_devices):
+        """A solve on a mesh returns its [D] coefficients typed with the mesh;
+        initial zeros typed without it gave pass 2 its own jit cache key — one
+        more trace + compile of the whole fixed-effect solver (seen as 452
+        traces in the mesh bench's measured region). The initial coefficients
+        are now replicated over the mesh, so a one-pass warm-up compiles
+        everything a longer run needs."""
+        import dataclasses
+
+        from photon_ml_tpu.analysis.runtime_guard import no_retrace
+
+        train, _ = _inputs(rng)
+        one_pass = dataclasses.replace(
+            _estimator(mesh=make_mesh(8)), validation_evaluators=()
+        )
+        one_pass.fit(train)
+        with no_retrace(what="two passes after a one-pass warm-up"):
+            dataclasses.replace(one_pass, n_iterations=3).fit(train)
+
     def test_mesh_partial_retrain_and_best_model(self, rng, eight_devices):
         """Locked coordinates + validation best-model tracking work unchanged on
         the mesh backend (feature parity with the host loop, VERDICT item 2)."""

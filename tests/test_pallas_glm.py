@@ -399,7 +399,7 @@ def test_full_game_step_with_fused_fe(rng):
     with pallas_interpret():
         # guard: the fused path must actually be eligible for this setup,
         # otherwise the parity below silently compares stock against stock
-        assert pallas_glm.should_fuse(d)
+        assert pallas_glm.should_fuse(d, jnp.float32)
         from photon_ml_tpu.data.matrix import DenseDesignMatrix
         from photon_ml_tpu.function.objective import GLMObjective
         from photon_ml_tpu.function.losses import logistic_loss
@@ -504,7 +504,7 @@ def test_full_game_step_shard_map_multichip(rng):
 
     stock_coef, stock_val = run()
     with pallas_interpret():
-        assert pallas_glm.should_fuse(d, per_device=True)
+        assert pallas_glm.should_fuse(d, jnp.float32, per_device=True)
         fused_coef, fused_val = run()
     np.testing.assert_allclose(fused_coef, stock_coef, atol=5e-4)
     np.testing.assert_allclose(fused_val, stock_val, rtol=1e-4)

@@ -212,7 +212,7 @@ def test_bf16_fe_storage_game_step_close_to_f32(rng):
 
 def test_bf16_re_storage_game_step_close_to_f32(rng):
     """re_storage_dtype=bf16: bucket blocks and scoring values store half the
-    HBM bytes (the profiled hot loops, trace_summary_tpu.md); coefficients
+    HBM bytes (the profiled hot loops, ROADMAP.md S2); coefficients
     and the converged objective stay within the bench quality gate of f32."""
     from photon_ml_tpu.parallel.game import (
         build_sharded_game_data,

@@ -594,17 +594,13 @@ def _setup_env():
     import jax
 
     jax.config.update("jax_platforms", "cpu")
-    try:
-        jax.config.update("jax_num_cpu_devices", 8)
-    except AttributeError:
-        pass
+    jax.config.update("jax_num_cpu_devices", 8)
     jax.config.update("jax_enable_x64", True)
-    jax.config.update(
-        "jax_compilation_cache_dir",
-        os.environ.get(
-            "PHOTON_XLA_CACHE", os.path.expanduser("~/.cache/photon_xla")
-        ),
-    )
+    if not os.environ.get("JAX_COMPILATION_CACHE_DIR"):
+        # the test suite's out-of-tree cache (tests/conftest.py)
+        jax.config.update(
+            "jax_compilation_cache_dir", os.path.expanduser("~/.cache/photon_xla")
+        )
     jax.config.update("jax_persistent_cache_min_compile_time_secs", 0.1)
 
 
