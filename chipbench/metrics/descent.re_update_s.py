@@ -1,0 +1,5 @@
+def read(run):
+    from chipbench import trace
+
+    seconds = trace.span_seconds(run["trace"], "re_update")
+    return None if seconds is None else seconds / run["units"]
