@@ -30,6 +30,7 @@ from photon_ml_tpu.optimization.config import GLMOptimizationConfiguration
 from photon_ml_tpu.optimization.problem import GLMOptimizationProblem
 from photon_ml_tpu.sampling.down_sampler import DownSampler
 from photon_ml_tpu.types import ConvergenceReason, TaskType, VarianceComputationType
+from photon_ml_tpu.util.timed import count as timed_count
 
 Array = jnp.ndarray
 
@@ -45,7 +46,8 @@ class FixedEffectOptimizationTracker:
     ``summary()``, and by run_coordinate_descent on every tracker before its
     result is returned, restoring the str/int/float field contract for
     downstream consumers — converts them to host values in one transfer,
-    idempotently."""
+    idempotently, and publishes the solve's evaluation count to the program's
+    recorder (util/timed) under the coordinate's id."""
 
     convergence_reason: object  # str once materialized; device/int code before
     iterations: object
@@ -54,28 +56,48 @@ class FixedEffectOptimizationTracker:
     # protocol reads it for the in-program divergence select); None on the
     # update_model path, whose guard the loop computes itself
     guard_ok: object = None
+    # value-and-gradient evaluations of the solve (OptResult.evaluations);
+    # None where the minimiser, or the fused program, does not count them
+    evaluations: object = None
+    coordinate_id: Optional[str] = None
 
-    def materialize(self) -> "FixedEffectOptimizationTracker":
+    def device_values(self):
+        """What ``materialize`` reads from the device (None once it has): the
+        descent loop fetches every tracker's in ONE batched transfer."""
+        if isinstance(self.convergence_reason, str):
+            return None
+        return (
+            self.convergence_reason,
+            self.iterations,
+            self.final_value,
+            self.guard_ok,
+            self.evaluations,
+        )
+
+    def materialize(self, host=None) -> "FixedEffectOptimizationTracker":
+        """``host``: ``device_values()`` already fetched by the caller."""
         if not isinstance(self.convergence_reason, str):
-            reason_h, iters_h, value_h, ok_h = jax.device_get(
-                (
-                    self.convergence_reason,
-                    self.iterations,
-                    self.final_value,
-                    self.guard_ok,
-                )
-            )
+            if host is None:
+                host = jax.device_get(self.device_values())
+            reason_h, iters_h, value_h, ok_h, evals_h = host
             self.convergence_reason = ConvergenceReason(int(reason_h)).name
             self.iterations = int(iters_h)
             self.final_value = float(value_h)
             if ok_h is not None:
                 self.guard_ok = bool(ok_h)
+            if evals_h is not None:
+                self.evaluations = int(evals_h)
+                timed_count(
+                    "solver.evaluations", self.evaluations,
+                    cid=self.coordinate_id, kind="fe",
+                )
         return self
 
     def summary(self) -> str:
         self.materialize()
+        evals = "" if self.evaluations is None else f" evals={self.evaluations}"
         return (
-            f"reason={self.convergence_reason} iters={self.iterations} "
+            f"reason={self.convergence_reason} iters={self.iterations}{evals} "
             f"value={self.final_value:.6g}"
         )
 
@@ -273,6 +295,8 @@ class FixedEffectCoordinate(Coordinate):
             convergence_reason=result.convergence_reason,
             iterations=result.iterations,
             final_value=result.value,
+            evaluations=result.evaluations,
+            coordinate_id=self.coordinate_id,
         )
         return (
             FixedEffectModel(model=glm, feature_shard_id=self.dataset.feature_shard_id),
@@ -677,7 +701,7 @@ class RandomEffectCoordinate(Coordinate):
         self, initial_model: Optional[RandomEffectModel], partial_scores: Array
     ) -> tuple[RandomEffectModel, RandomEffectTracker]:
         offsets_plus_scores = self.base_offsets + partial_scores
-        return train_random_effect(
+        model, tracker = train_random_effect(
             self.dataset,
             self.task,
             self.configuration,
@@ -688,6 +712,8 @@ class RandomEffectCoordinate(Coordinate):
             per_entity_reg_weights=self.per_entity_reg_weights,
             re_solver=self._solver_plan(offsets_plus_scores, initial_model),
         )
+        tracker.publish(self.coordinate_id)
+        return model, tracker
 
     def update_model_active(
         self,
@@ -959,6 +985,8 @@ class RandomEffectCoordinate(Coordinate):
                 buckets=buckets,
                 view=view,
                 tracker_masks=tracker_masks,
+                # padded rows per lane of each bucket: the tracker's lane waste
+                lane_rows=tuple(b.shape[0] for b in buckets),
             )
         return self._fused_static
 
@@ -1106,7 +1134,7 @@ class RandomEffectCoordinate(Coordinate):
         score_prev = owned_or_copy("score", prev_score)
         offsets_plus_scores = self.base_offsets + partial_scores
 
-        coeffs_out, score_out, var_out, ok, reasons, iters = program(
+        coeffs_out, score_out, var_out, ok, reasons, iters, evals = program(
             coeffs_prev,
             score_prev,
             var_prev,
@@ -1129,7 +1157,9 @@ class RandomEffectCoordinate(Coordinate):
             projector=ds.projector,
         )
         tracker = LazyRandomEffectTracker(
-            reasons, iters, guard_ok=ok, real_masks=st["tracker_masks"]
+            reasons, iters, guard_ok=ok, real_masks=st["tracker_masks"],
+            evals_parts=evals, lane_rows=st["lane_rows"],
+            coordinate_id=self.coordinate_id,
         )
         return model, score_out, tracker
 

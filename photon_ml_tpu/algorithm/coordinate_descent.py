@@ -20,7 +20,6 @@ from __future__ import annotations
 
 import dataclasses
 import logging
-import time
 from typing import Mapping, Optional
 
 import jax
@@ -35,6 +34,7 @@ from photon_ml_tpu.evaluation.evaluators import EvaluationSuite
 from photon_ml_tpu.models.game import FixedEffectModel, GameModel, RandomEffectModel
 from photon_ml_tpu.resilience import faultpoint, register_fault_point
 from photon_ml_tpu.resilience.incidents import Incident
+from photon_ml_tpu.util.timed import span
 
 Array = jnp.ndarray
 
@@ -43,6 +43,13 @@ logger = logging.getLogger(__name__)
 # armed as coord.update.<coordinate_id> (hierarchical match): chaos proves a
 # crash between any two coordinate updates resumes to the identical model
 FP_COORD_UPDATE = register_fault_point("coord.update")
+
+
+def _model_kind(model) -> str:
+    """``fe`` | ``re``: the ``kind`` attribute of the spans of a coordinate."""
+    if isinstance(model, FixedEffectModel):
+        return "fe"
+    return "re" if isinstance(model, RandomEffectModel) else type(model).__name__
 
 
 def _device_guard(model, tracker) -> tuple:
@@ -162,7 +169,8 @@ def _flush_guards(pending: list, incidents: list, models: dict) -> None:
     variance schema of first-update rejects)."""
     if not pending:
         return
-    host = jax.device_get([p.guard for p in pending])
+    with span("descent.guard", iteration=pending[0].iteration):
+        host = jax.device_get([p.guard for p in pending])
     for p, (coefs_ok, value_ok, final_value) in zip(pending, host):
         cause = _guard_cause(coefs_ok, value_ok, final_value)
         if cause is None:
@@ -369,29 +377,32 @@ def run_coordinate_descent(
     models: dict[str, object] = {}
     train_scores: dict[str, Array] = {}
     val_scores: dict[str, Array] = {}
-    for cid, coord in coordinates.items():
-        init = None if initial_models is None else initial_models.get(cid)
-        if init is None and active_sets is not None and active_sets.get(cid) is not None:
-            # without a warm start, initialize_model() would silently supply a
-            # ZERO model and the pass would export coefficient 0 for every
-            # inactive entity — an active set only makes sense over the
-            # previous generation's coefficients
-            raise ValueError(
-                f"Coordinate {cid!r} has an active set but no initial model: "
-                "active-set delta updates keep inactive entities' previous "
-                "coefficients, so a warm-start model is required "
-                "(initial_models or a resumable checkpoint)"
-            )
-        if init is not None:
-            # adapt external/restored models to the coordinate's dataset:
-            # RE models re-align entity rows, FE models pad + place
-            # coefficients for feature-sharded datasets
-            init = coord.prepare_initial_model(init)
-        model = init if init is not None else coord.initialize_model()
-        models[cid] = model
-        train_scores[cid] = coord.score(model)
-        if validate:
-            val_scores[cid] = score_model_on_dataset(model, validation_datasets[cid])
+    with span("descent.init"):
+        for cid, coord in coordinates.items():
+            init = None if initial_models is None else initial_models.get(cid)
+            if init is None and active_sets is not None and active_sets.get(cid) is not None:
+                # without a warm start, initialize_model() would silently supply a
+                # ZERO model and the pass would export coefficient 0 for every
+                # inactive entity — an active set only makes sense over the
+                # previous generation's coefficients
+                raise ValueError(
+                    f"Coordinate {cid!r} has an active set but no initial model: "
+                    "active-set delta updates keep inactive entities' previous "
+                    "coefficients, so a warm-start model is required "
+                    "(initial_models or a resumable checkpoint)"
+                )
+            if init is not None:
+                # adapt external/restored models to the coordinate's dataset:
+                # RE models re-align entity rows, FE models pad + place
+                # coefficients for feature-sharded datasets
+                init = coord.prepare_initial_model(init)
+            model = init if init is not None else coord.initialize_model()
+            models[cid] = model
+            # scoring the INITIAL model: of a fresh fit, the zero model
+            with span("descent.init_score", cid=cid, kind=_model_kind(model)):
+                train_scores[cid] = coord.score(model)
+                if validate:
+                    val_scores[cid] = score_model_on_dataset(model, validation_datasets[cid])
 
     n = {int(s.shape[0]) for s in train_scores.values()}
     if len(n) != 1:
@@ -431,107 +442,110 @@ def run_coordinate_descent(
         for cid in updatable:
             coord = coordinates[cid]
             faultpoint(f"{FP_COORD_UPDATE}.{cid}")
-            t0 = time.perf_counter()
-            # Residual trick (CoordinateDescent.scala:197-204)
-            partial = full_train_score - train_scores[cid]
             prev_model = models[cid]
-            prev_score = train_scores[cid]
-            prev_had_var = _has_variances(prev_model)
-            active = None if active_sets is None else active_sets.get(cid)
-            # duck-typed coordinates (test wrappers, external impls) may
-            # predate the fused protocol — treat a missing method as "no
-            # fused path". Active-set updates always take the generic path:
-            # the delta program gathers/scatters host-chosen lane sets, which
-            # the donated fused program cannot express.
-            update_and_score = (
-                getattr(coord, "update_and_score", None) if active is None else None
-            )
-            fused = (
-                update_and_score(prev_model, partial, prev_score, donate=cid in donating)
-                if update_and_score is not None
-                else None
-            )
-            if fused is not None:
-                model, new_score, tracker = fused
-                donating.add(cid)
-                guard_ok = getattr(tracker, "guard_ok", None)
-                if guard_ok is None:
-                    # the fused protocol applies its reject IN-PROGRAM and
-                    # must surface the flag: without it the loop could store
-                    # a diverged model while recording "previous model kept"
-                    raise TypeError(
-                        f"Coordinate {cid!r}: update_and_score must return a "
-                        "tracker exposing the device-side guard_ok flag"
-                    )
-                guard = (guard_ok, None, None)
-                # the fused program applied the reject select internally (and
-                # consumed the previous buffers): state always moves to the
-                # returned arrays — on a reject they HOLD the previous values
-                models[cid] = model
-                train_scores[cid] = new_score
-            elif active is not None:
-                update_active = getattr(coord, "update_model_active", None)
-                if update_active is None:
-                    raise TypeError(
-                        f"Coordinate {cid!r} has an active set but no "
-                        "update_model_active method (active-set delta updates "
-                        "are a random-effect capability)"
-                    )
-                model, tracker = update_active(prev_model, partial, active)
-                guard = _device_guard(model, tracker)
-            else:
-                model, tracker = coord.update_model(prev_model, partial)
-                guard = _device_guard(model, tracker)
-            trackers[cid].append(tracker)
-
-            if sync_guard:
-                # validating (or defer_guard=False) runs resolve per update
-                # on purpose: a rejected update must skip validation
-                cause = _guard_cause(*jax.device_get(guard))  # jaxlint: disable=HS001 deliberate per-update read, validation gates on the reject decision
-                if cause is not None:
-                    # Divergence guard: REJECT the update — the previous model
-                    # for this coordinate is kept (scores unchanged), an
-                    # incident is recorded, and the descent continues over the
-                    # remaining coordinates. Graceful degradation instead of a
-                    # poisoned GAME model, mirroring eager Photon's keep-best
-                    # semantics. full_train_score stays the pre-update total.
-                    incident = Incident(
-                        kind="divergence",
-                        cause=cause,
-                        action="update rejected; previous model kept",
-                        coordinate_id=cid,
-                        iteration=iteration,
-                    )
-                    incidents.append(incident)
-                    logger.warning("iter %d %s", iteration, incident.summary())
-                    if fused is not None and not prev_had_var:
-                        # the in-program reject substituted zeros for the
-                        # absent previous variances; restore variances=None
-                        models[cid] = _strip_variances(models[cid])
-                    continue
-                if fused is None:
-                    models[cid] = model
-                    new_score = coord.score(model)
-                    train_scores[cid] = new_score
-                full_train_score = partial + new_score
-            else:
-                if fused is None:
-                    # device-side reject: keep the previous values without
-                    # reading the flag (scoring the selected model reproduces
-                    # the previous score bit-for-bit on a reject)
-                    ok = guard[0] if guard[1] is None else jnp.logical_and(*guard[:2])
-                    model = _select_update(ok, model, prev_model)
-                    models[cid] = model
-                    new_score = coord.score(model)
-                    train_scores[cid] = new_score
-                # on a (not-yet-known) reject this rebuilds the total as
-                # partial + previous-score values — possibly one ulp off the
-                # pre-update total; the iteration-boundary recompute restores
-                # exactness, and healthy updates are bit-identical
-                full_train_score = partial + new_score
-                pending.append(
-                    _PendingGuard(iteration, cid, guard, prev_had_no_variances=not prev_had_var)
+            with span(
+                "descent.update", cid=cid, kind=_model_kind(prev_model), iteration=iteration
+            ) as update_span:
+                # Residual trick (CoordinateDescent.scala:197-204)
+                partial = full_train_score - train_scores[cid]
+                prev_score = train_scores[cid]
+                prev_had_var = _has_variances(prev_model)
+                active = None if active_sets is None else active_sets.get(cid)
+                # duck-typed coordinates (test wrappers, external impls) may
+                # predate the fused protocol — treat a missing method as "no
+                # fused path". Active-set updates always take the generic path:
+                # the delta program gathers/scatters host-chosen lane sets, which
+                # the donated fused program cannot express.
+                update_and_score = (
+                    getattr(coord, "update_and_score", None) if active is None else None
                 )
+                fused = (
+                    update_and_score(prev_model, partial, prev_score, donate=cid in donating)
+                    if update_and_score is not None
+                    else None
+                )
+                if fused is not None:
+                    model, new_score, tracker = fused
+                    donating.add(cid)
+                    guard_ok = getattr(tracker, "guard_ok", None)
+                    if guard_ok is None:
+                        # the fused protocol applies its reject IN-PROGRAM and
+                        # must surface the flag: without it the loop could store
+                        # a diverged model while recording "previous model kept"
+                        raise TypeError(
+                            f"Coordinate {cid!r}: update_and_score must return a "
+                            "tracker exposing the device-side guard_ok flag"
+                        )
+                    guard = (guard_ok, None, None)
+                    # the fused program applied the reject select internally (and
+                    # consumed the previous buffers): state always moves to the
+                    # returned arrays — on a reject they HOLD the previous values
+                    models[cid] = model
+                    train_scores[cid] = new_score
+                elif active is not None:
+                    update_active = getattr(coord, "update_model_active", None)
+                    if update_active is None:
+                        raise TypeError(
+                            f"Coordinate {cid!r} has an active set but no "
+                            "update_model_active method (active-set delta updates "
+                            "are a random-effect capability)"
+                        )
+                    model, tracker = update_active(prev_model, partial, active)
+                    guard = _device_guard(model, tracker)
+                else:
+                    model, tracker = coord.update_model(prev_model, partial)
+                    guard = _device_guard(model, tracker)
+                trackers[cid].append(tracker)
+
+                if sync_guard:
+                    # validating (or defer_guard=False) runs resolve per update
+                    # on purpose: a rejected update must skip validation
+                    with span("descent.guard", cid=cid, iteration=iteration):
+                        cause = _guard_cause(*jax.device_get(guard))  # jaxlint: disable=HS001 deliberate per-update read, validation gates on the reject decision
+                    if cause is not None:
+                        # Divergence guard: REJECT the update — the previous model
+                        # for this coordinate is kept (scores unchanged), an
+                        # incident is recorded, and the descent continues over the
+                        # remaining coordinates. Graceful degradation instead of a
+                        # poisoned GAME model, mirroring eager Photon's keep-best
+                        # semantics. full_train_score stays the pre-update total.
+                        incident = Incident(
+                            kind="divergence",
+                            cause=cause,
+                            action="update rejected; previous model kept",
+                            coordinate_id=cid,
+                            iteration=iteration,
+                        )
+                        incidents.append(incident)
+                        logger.warning("iter %d %s", iteration, incident.summary())
+                        if fused is not None and not prev_had_var:
+                            # the in-program reject substituted zeros for the
+                            # absent previous variances; restore variances=None
+                            models[cid] = _strip_variances(models[cid])
+                        continue
+                    if fused is None:
+                        models[cid] = model
+                        new_score = coord.score(model)
+                        train_scores[cid] = new_score
+                    full_train_score = partial + new_score
+                else:
+                    if fused is None:
+                        # device-side reject: keep the previous values without
+                        # reading the flag (scoring the selected model reproduces
+                        # the previous score bit-for-bit on a reject)
+                        ok = guard[0] if guard[1] is None else jnp.logical_and(*guard[:2])
+                        model = _select_update(ok, model, prev_model)
+                        models[cid] = model
+                        new_score = coord.score(model)
+                        train_scores[cid] = new_score
+                    # on a (not-yet-known) reject this rebuilds the total as
+                    # partial + previous-score values — possibly one ulp off the
+                    # pre-update total; the iteration-boundary recompute restores
+                    # exactness, and healthy updates are bit-identical
+                    full_train_score = partial + new_score
+                    pending.append(
+                        _PendingGuard(iteration, cid, guard, prev_had_no_variances=not prev_had_var)
+                    )
 
             if logger.isEnabledFor(logging.INFO):
                 # summary() materializes device trackers: only pay the sync
@@ -541,58 +555,73 @@ def run_coordinate_descent(
                     iteration,
                     cid,
                     tracker.summary(),
-                    time.perf_counter() - t0,
+                    update_span.seconds,
                 )
 
             if validate:
-                val_scores[cid] = score_model_on_dataset(model, validation_datasets[cid])
-                total_val = sum(val_scores.values())
-                metrics = evaluation_suite.evaluate(total_val)
-                metrics_history.append((iteration, cid, metrics))
-                metric = metrics[primary.name]
-                logger.info("iter %d coordinate %s: validation %s", iteration, cid, metrics)
-                if primary.better_than(metric, best_metric):
-                    best_metric = metric
-                    best_metrics = metrics
-                    best_model = GameModel(models=_snapshot_models(models, donating))
+                with span("descent.validate", cid=cid, iteration=iteration):
+                    with span("descent.validate_score", cid=cid):
+                        val_scores[cid] = score_model_on_dataset(model, validation_datasets[cid])
+                        total_val = sum(val_scores.values())
+                    # ends in the evaluators' own host read of the scores
+                    with span("descent.evaluate", cid=cid):
+                        metrics = evaluation_suite.evaluate(total_val)
+                    metrics_history.append((iteration, cid, metrics))
+                    metric = metrics[primary.name]
+                    logger.info("iter %d coordinate %s: validation %s", iteration, cid, metrics)
+                    if primary.better_than(metric, best_metric):
+                        best_metric = metric
+                        best_metrics = metrics
+                        with span("descent.snapshot", cid=cid):
+                            best_model = GameModel(models=_snapshot_models(models, donating))
 
         # incident details for the whole iteration in ONE batched transfer
         # (the reject itself already happened device-side)
         _flush_guards(pending, incidents, models)
 
         if checkpointer is not None:
-            checkpointer.maybe_save(
-                iteration + 1,
-                dict(models),
-                None if best_model is None else dict(best_model.models),
-                best_metric,
-                best_metrics,
-                force=(iteration + 1 == n_iterations),
-                incidents=incidents,
-            )
+            with span("descent.checkpoint", iteration=iteration):
+                checkpointer.maybe_save(
+                    iteration + 1,
+                    dict(models),
+                    None if best_model is None else dict(best_model.models),
+                    best_metric,
+                    best_metrics,
+                    force=(iteration + 1 == n_iterations),
+                    incidents=incidents,
+                )
 
-    # Restore the host-value tracker contract before results escape: fixed-
-    # effect trackers buffered device scalars through the sync-free loop;
-    # materialize them now, outside the hot path. Probe the CLASS, not the
-    # instance: LazyRandomEffectTracker's __getattr__ would treat an instance
-    # probe as a field read and eagerly sync — those trackers keep their
-    # on-demand materialization (attribute access already yields host values).
-    for tracker_list in trackers.values():
-        for t in tracker_list:
-            materialize = getattr(type(t), "materialize", None)
-            if materialize is not None:
-                materialize(t)
+    with span("descent.finish"):
+        # Restore the host-value tracker contract before results escape: the
+        # trackers buffered device values through the sync-free loop;
+        # materialize them now, outside the hot path (this is also where the
+        # solver counters are published, util/timed). Every update's guard has
+        # been read by now (per update, or in the iteration's batched flush),
+        # so the programs these values come from have ended: a transfer, no
+        # new wait, and ONE batched transfer for all of them (a round trip per
+        # tracker was 11 ms of a 7.8 s unit on the v5e; PERF.md section 6).
+        # Probe the CLASS, not the instance: LazyRandomEffectTracker's
+        # __getattr__ treats an instance probe as a field read.
+        buffered = [
+            t
+            for tracker_list in trackers.values()
+            for t in tracker_list
+            if getattr(type(t), "device_values", None) is not None
+        ]
+        fetched = jax.device_get([type(t).device_values(t) for t in buffered])
+        for t, host in zip(buffered, fetched):
+            type(t).materialize(t, host)
 
-    final_model = GameModel(models=dict(models))
-    if best_model is None:
-        best_model = final_model
-    return CoordinateDescentResult(
-        model=final_model,
-        best_model=best_model,
-        best_metric=best_metric,
-        metrics_history=metrics_history,
-        trackers=trackers,
-        training_scores=dict(train_scores),
-        best_metrics=best_metrics,
-        incidents=incidents,
-    )
+        final_model = GameModel(models=dict(models))
+        if best_model is None:
+            best_model = final_model
+        return CoordinateDescentResult(
+            model=final_model,
+            best_model=best_model,
+            best_metric=best_metric,
+            metrics_history=metrics_history,
+            trackers=trackers,
+            training_scores=dict(train_scores),
+            best_metrics=best_metrics,
+            incidents=incidents,
+        )

@@ -22,7 +22,7 @@ from __future__ import annotations
 
 import dataclasses
 import functools
-from typing import Optional
+from typing import Optional, Sequence
 
 import jax
 import jax.numpy as jnp
@@ -44,36 +44,85 @@ from photon_ml_tpu.types import (
     TaskType,
     VarianceComputationType,
 )
+from photon_ml_tpu.util.timed import count as timed_count
 
 Array = jnp.ndarray
 
 
 @dataclasses.dataclass
 class RandomEffectTracker:
-    """Aggregate per-entity convergence stats (RandomEffectOptimizationTracker.scala:158)."""
+    """Aggregate per-entity convergence stats (RandomEffectOptimizationTracker.scala:158).
+
+    ``evaluations_mean`` is the mean over entities of the solver's own count
+    of value-and-gradient evaluations; ``lane_waste`` the share of
+    row-evaluations the vmapped bucket loops executed for lanes that had
+    already stopped: a bucket of E lanes of S padded rows runs until its
+    slowest lane ends, E * S * max(ev) row-evaluations, of which S * sum(ev)
+    were some lane's own. Both None where a bucket's minimiser counts no
+    evaluations (``OptResult.evaluations``)."""
 
     convergence_reason_counts: dict[str, int]
     iterations_mean: float
     iterations_max: int
     n_entities: int
+    evaluations_mean: Optional[float] = None
+    lane_waste: Optional[float] = None
+    padded_rows: int = 0  # sum over buckets of lanes x padded rows per lane
 
     @staticmethod
-    def from_arrays(reasons: np.ndarray, iterations: np.ndarray) -> "RandomEffectTracker":
+    def from_arrays(
+        reasons: np.ndarray,
+        iterations: np.ndarray,
+        evaluations: Optional[Sequence] = None,
+        lane_rows: Optional[Sequence] = None,
+    ) -> "RandomEffectTracker":
+        """``evaluations``: per bucket, the real lanes' counts (or None);
+        ``lane_rows``: per bucket, the padded rows per lane."""
         counts: dict[str, int] = {}
         for code, cnt in zip(*np.unique(reasons, return_counts=True)):
             counts[ConvergenceReason(int(code)).name] = int(cnt)
-        return RandomEffectTracker(
+        tracker = RandomEffectTracker(
             convergence_reason_counts=counts,
             iterations_mean=float(iterations.mean()) if len(iterations) else 0.0,
             iterations_max=int(iterations.max()) if len(iterations) else 0,
             n_entities=len(reasons),
         )
+        if evaluations and all(ev is not None for ev in evaluations):
+            # per bucket: padded rows per lane, lanes, sum and max of the counts
+            stats = [
+                (int(s), len(ev), int(ev.sum()), int(ev.max()))
+                for s, ev in zip(lane_rows, evaluations)
+                if len(ev)
+            ]
+            ran = sum(s * lanes * most for s, lanes, _total, most in stats)
+            if ran:
+                own = sum(s * total for s, _lanes, total, _most in stats)
+                lanes = sum(lanes for _s, lanes, _total, _most in stats)
+                tracker.evaluations_mean = sum(total for _s, _l, total, _m in stats) / lanes
+                tracker.lane_waste = 1.0 - own / ran
+                tracker.padded_rows = sum(s * lanes for s, lanes, _total, _most in stats)
+        return tracker
+
+    def publish(self, coordinate_id: str) -> None:
+        """The solver counters of one update, to the program's recorder."""
+        if self.evaluations_mean is not None:
+            timed_count(
+                "solver.evaluations", self.evaluations_mean,
+                cid=coordinate_id, kind="re", entities=self.n_entities,
+            )
+            timed_count(
+                "solver.lane_waste", self.lane_waste,
+                cid=coordinate_id, kind="re", rows=self.padded_rows,
+            )
 
     def summary(self) -> str:
-        return (
+        text = (
             f"entities={self.n_entities} reasons={self.convergence_reason_counts} "
             f"iters mean={self.iterations_mean:.1f} max={self.iterations_max}"
         )
+        if self.evaluations_mean is not None:
+            text += f" evals mean={self.evaluations_mean:.1f} lane waste={self.lane_waste:.0%}"
+        return text
 
 
 class LazyRandomEffectTracker:
@@ -93,15 +142,34 @@ class LazyRandomEffectTracker:
     ``rows < E`` filter, applied lazily at materialization so the stats of
     the sharded and per-bucket paths agree."""
 
-    def __init__(self, reasons_parts, iters_parts, guard_ok=None, real_masks=None):
+    def __init__(
+        self, reasons_parts, iters_parts, guard_ok=None, real_masks=None,
+        evals_parts=None, lane_rows=None, coordinate_id=None,
+    ):
         self.guard_ok = guard_ok
-        self._pending = (tuple(reasons_parts), tuple(iters_parts))
+        self._pending = (
+            tuple(reasons_parts),
+            tuple(iters_parts),
+            None if evals_parts is None else tuple(evals_parts),
+        )
         self._masks = None if real_masks is None else tuple(real_masks)
+        self._lane_rows = lane_rows
+        self._coordinate_id = coordinate_id
         self._inner: Optional[RandomEffectTracker] = None
 
-    def _materialize(self) -> RandomEffectTracker:
+    def device_values(self):
+        """What ``materialize`` reads from the device (None once it has): the
+        descent loop fetches every tracker's in ONE batched transfer."""
+        return self._pending
+
+    def materialize(self, host=None) -> RandomEffectTracker:
+        """One batched ``device_get`` of the update's per-lane stats (or
+        ``host``: ``device_values()`` already fetched by the caller), once;
+        publishes the solver counters under the coordinate's id."""
         if self._inner is None:
-            reasons_h, iters_h = jax.device_get(self._pending)
+            reasons_h, iters_h, evals_h = (
+                jax.device_get(self._pending) if host is None else host
+            )
             masks = (
                 self._masks
                 if self._masks is not None
@@ -121,16 +189,28 @@ class LazyRandomEffectTracker:
                 if iters_h
                 else np.zeros(0, np.int32)
             )
-            self._inner = RandomEffectTracker.from_arrays(reasons, iters)
+            evals = (
+                None
+                if evals_h is None
+                else [
+                    None if a is None else np.asarray(a)[m]
+                    for a, m in zip(evals_h, masks)
+                ]
+            )
+            self._inner = RandomEffectTracker.from_arrays(
+                reasons, iters, evals, self._lane_rows
+            )
             self._pending = None
+            if self._coordinate_id is not None:
+                self._inner.publish(self._coordinate_id)
         return self._inner
 
     def summary(self) -> str:
-        return self._materialize().summary()
+        return self.materialize().summary()
 
     def __getattr__(self, name):
         # only reached for names not set in __init__ (materialized fields)
-        return getattr(self._materialize(), name)
+        return getattr(self.materialize(), name)
 
 
 def _gather_norm_vectors(
@@ -355,7 +435,7 @@ def measure_auto_solvers(
                 task, configuration.optimizer_config, False,
                 VarianceComputationType.NONE, solver,
             )
-            _, reasons_b, iters_b, _ = solve(
+            _, reasons_b, iters_b, _, _ = solve(
                 X_b, y_b, w_b, off_b, init_b, l2_b, l1_arr
             )
             reasons_h, iters_h = jax.device_get((reasons_b, iters_b))  # jaxlint: disable=HS001 once-per-shape measurement probe, first pass only — the read IS the product
@@ -451,7 +531,7 @@ def train_random_effect(
     # tracker inputs stay DEVICE arrays inside the loop: a host sync per bucket
     # (np.asarray) would block dispatch of the next bucket's solve; everything
     # transfers in one device_get after the last bucket is enqueued
-    reasons_parts, iters_parts, rows_parts = [], [], []
+    reasons_parts, iters_parts, evals_parts, rows_parts = [], [], [], []
 
     # re_bucket_solver is lru-cached, so per-bucket resolution costs a dict
     # hit; a tuple plan (measured "auto" — measure_auto_solvers) picks the
@@ -473,7 +553,7 @@ def train_random_effect(
         if normalization is not None and not normalization.is_identity:
             init_b = _to_transformed(init_b, factors, shifts, icpt_mask)
 
-        w_b, reasons_b, iters_b, var_b = solve(
+        w_b, reasons_b, iters_b, evals_b, var_b = solve(
             bucket.X,
             bucket.labels,
             bucket.weights,
@@ -499,6 +579,7 @@ def train_random_effect(
             variances_global = variances_global.at[bucket.entity_rows, :K].set(var_b)
         reasons_parts.append(reasons_b)
         iters_parts.append(iters_b)
+        evals_parts.append(evals_b)
         rows_parts.append(bucket.entity_rows)
 
     if table_rows > E:
@@ -514,15 +595,19 @@ def train_random_effect(
 
     if reasons_parts:
         # the one host sync for the tracker, after every bucket solve is queued
-        reasons_h, iters_h, rows_h = jax.device_get(
-            (reasons_parts, iters_parts, rows_parts)
+        reasons_h, iters_h, evals_h, rows_h = jax.device_get(
+            (reasons_parts, iters_parts, evals_parts, rows_parts)
         )
         real = [np.asarray(r) < E for r in rows_h]
         reasons_all = np.concatenate([np.asarray(a)[m] for a, m in zip(reasons_h, real)])
         iters_all = np.concatenate([np.asarray(a)[m] for a, m in zip(iters_h, real)])
+        evals_real = [None if a is None else np.asarray(a)[m] for a, m in zip(evals_h, real)]
     else:
         reasons_all = iters_all = np.zeros(0, np.int32)
-    tracker = RandomEffectTracker.from_arrays(reasons_all, iters_all)
+        evals_real = None
+    tracker = RandomEffectTracker.from_arrays(
+        reasons_all, iters_all, evals_real, [b.shape[0] for b in dataset.buckets]
+    )
     model = RandomEffectModel(
         re_type=dataset.re_type,
         feature_shard_id=dataset.feature_shard_id,
@@ -752,7 +837,7 @@ def train_random_effect_delta(
         if normalization is not None and not normalization.is_identity:
             init_b = _to_transformed(init_b, factors, shifts, icpt_mask)
 
-        coefs_b, reasons_b, iters_b, var_b = solve(
+        coefs_b, reasons_b, iters_b, _evals_b, var_b = solve(
             X_b,
             y_b,
             w_b,

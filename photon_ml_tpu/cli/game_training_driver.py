@@ -49,6 +49,7 @@ from photon_ml_tpu.types import (
     VarianceComputationType,
 )
 from photon_ml_tpu.util import Event, EventEmitter, PhotonLogger, Timed
+from photon_ml_tpu.util.timed import summary as span_summary
 from photon_ml_tpu.util.date_range import resolve_input_paths
 
 BEST_DIR = "best"
@@ -557,6 +558,10 @@ def run(args: argparse.Namespace, emitter: Optional[EventEmitter] = None) -> dic
                 results = estimator.fit(
                     train_input, validation_data=validation_input, initial_model=initial_model
                 )
+        # the program's spans (util/timed), once: where the time of set-up,
+        # ingest and training went, by span name
+        for name, (n, seconds) in sorted(span_summary().items()):
+            logger.info("span %s: %d x, %.3f s", name, n, seconds)
 
         # -- hyperparameter tuning (GameTrainingDriver.runHyperparameterTuning) --
         tuning_mode = HyperparameterTuningMode(args.hyper_parameter_tuning)

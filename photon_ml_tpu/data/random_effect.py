@@ -38,6 +38,7 @@ import scipy.sparse as sp
 
 from photon_ml_tpu.normalization import NormalizationContext
 from photon_ml_tpu.types import intercept_key
+from photon_ml_tpu.util.timed import span
 
 Array = jnp.ndarray
 
@@ -110,6 +111,9 @@ class RandomEffectDataset:
     # None on the host backend. Rows >= n_entities are always-zero padding.
     coeffs_sharding: Optional[object] = None
     coeffs_rows: Optional[int] = None
+    # share of the padded bucket rows that hold no sample: 1 - active samples /
+    # sum over buckets of entities x padded rows per entity (0 without buckets)
+    padding_waste: float = 0.0
 
     @property
     def n_entities(self) -> int:
@@ -304,263 +308,281 @@ def build_random_effect_dataset(
     if len(ent) != n:
         raise ValueError("entity ids and sample count mismatch")
 
-    # ---- group samples by entity (the one-time 'shuffle') -----------------------
-    order = np.argsort(ent, kind="mergesort")
-    sorted_ent = ent[order]
-    boundaries = np.flatnonzero(sorted_ent[1:] != sorted_ent[:-1]) + 1
-    if n:
-        starts = np.concatenate([[0], boundaries])
-        stops = np.concatenate([boundaries, [n]])
-    else:  # empty input (e.g. an empty validation split): no groups at all
-        starts = stops = boundaries
+    with span("ingest.re_index", re_type=re_type):
+        # ---- group samples by entity (the one-time 'shuffle') -----------------------
+        order = np.argsort(ent, kind="mergesort")
+        sorted_ent = ent[order]
+        boundaries = np.flatnonzero(sorted_ent[1:] != sorted_ent[:-1]) + 1
+        if n:
+            starts = np.concatenate([[0], boundaries])
+            stops = np.concatenate([boundaries, [n]])
+        else:  # empty input (e.g. an empty validation split): no groups at all
+            starts = stops = boundaries
 
-    active_rows: dict = {}
-    weights_scale: dict = {}
-    passive_count = 0
-    for a, b in zip(starts, stops):
-        e_id = sorted_ent[a]
-        rows = order[a:b]
-        count = len(rows)
-        if active_data_upper_bound is not None and count > active_data_upper_bound:
-            rng = np.random.default_rng(_entity_seed(str(e_id), seed))
-            keys = rng.random(count)
-            keep = rows[np.argsort(keys, kind="mergesort")[: active_data_upper_bound]]
-            active_rows[e_id] = np.sort(keep)
-            weights_scale[e_id] = count / active_data_upper_bound
-            passive_count += count - active_data_upper_bound
+        active_rows: dict = {}
+        weights_scale: dict = {}
+        passive_count = 0
+        for a, b in zip(starts, stops):
+            e_id = sorted_ent[a]
+            rows = order[a:b]
+            count = len(rows)
+            if active_data_upper_bound is not None and count > active_data_upper_bound:
+                rng = np.random.default_rng(_entity_seed(str(e_id), seed))
+                keys = rng.random(count)
+                keep = rows[np.argsort(keys, kind="mergesort")[: active_data_upper_bound]]
+                active_rows[e_id] = np.sort(keep)
+                weights_scale[e_id] = count / active_data_upper_bound
+                passive_count += count - active_data_upper_bound
+            else:
+                active_rows[e_id] = np.sort(rows)
+                weights_scale[e_id] = 1.0
+
+        # lower-bound filter: entities below the threshold train no model
+        entities = [e for e, rows in active_rows.items() if len(rows) >= active_data_lower_bound]
+        if exclude_entities:
+            entities = [e for e in entities if e not in exclude_entities]
+        if entity_order is not None:
+            # stable growth: known entities keep the caller's row order, unseen
+            # ones append sorted at the tail (continuous-training alignment)
+            present = set(entities)
+            known = [e for e in entity_order if e in present]
+            known_set = set(known)
+            entities = known + sorted(e for e in entities if e not in known_set)
         else:
-            active_rows[e_id] = np.sort(rows)
-            weights_scale[e_id] = 1.0
+            entities.sort()
+        row_of_entity = {e: i for i, e in enumerate(entities)}
+        n_ent = len(entities)
+        labels_arr = None if labels is None else np.asarray(labels, dtype=np.float64)
 
-    # lower-bound filter: entities below the threshold train no model
-    entities = [e for e, rows in active_rows.items() if len(rows) >= active_data_lower_bound]
-    if exclude_entities:
-        entities = [e for e in entities if e not in exclude_entities]
-    if entity_order is not None:
-        # stable growth: known entities keep the caller's row order, unseen
-        # ones append sorted at the tail (continuous-training alignment)
-        present = set(entities)
-        known = [e for e in entity_order if e in present]
-        known_set = set(known)
-        entities = known + sorted(e for e in entities if e not in known_set)
-    else:
-        entities.sort()
-    row_of_entity = {e: i for i, e in enumerate(entities)}
-    n_ent = len(entities)
-    labels_arr = None if labels is None else np.asarray(labels, dtype=np.float64)
-
-    # Flat active-sample machinery shared by the (vectorized) observed-column
-    # computation and the bucket fill: one concatenated row list replaces the
-    # per-entity scipy CSR slicing that dominated build time at 100k+ entities.
-    lens = np.asarray([len(active_rows[e]) for e in entities], dtype=np.int64)
-    act_concat = (
-        np.concatenate([active_rows[e] for e in entities])
-        if n_ent
-        else np.zeros(0, dtype=np.int64)
-    )
-    ent_row_per_act = np.repeat(np.arange(n_ent, dtype=np.int64), lens)
-    act_starts = np.concatenate([[0], np.cumsum(lens)[:-1]]) if n_ent else lens
-    s_local_per_act = np.arange(len(act_concat)) - np.repeat(act_starts, lens)
-    # active nnz: global nnz positions of every active sample's entries
-    counts_all = np.diff(X.indptr)
-    c_act = counts_all[act_concat]
-    total_act_nnz = int(c_act.sum())
-    nnz_cum = np.concatenate([[0], np.cumsum(c_act)[:-1]]) if len(c_act) else c_act
-    act_nnz_idx = (
-        np.repeat(X.indptr[act_concat], c_act)
-        + (np.arange(total_act_nnz) - np.repeat(nnz_cum, c_act))
-    ).astype(np.int64)
-    ent_of_act_nnz = np.repeat(ent_row_per_act, c_act)
-    s_local_of_act_nnz = np.repeat(s_local_per_act, c_act)
-
-    # ---- per-entity projection (+ optional Pearson selection) -------------------
-    # col_of[i]: sorted global col ids observed in entity i's ACTIVE rows.
-    if n_ent == 0:
-        col_of = []
-    elif features_max is None:
-        keys = ent_of_act_nnz * d + X.indices[act_nnz_idx].astype(np.int64)
-        uniq_keys = np.unique(keys)
-        ent_of_obs = uniq_keys // d
-        obs_counts = np.bincount(ent_of_obs, minlength=n_ent)
-        col_of = np.split(
-            (uniq_keys % d).astype(np.int32), np.cumsum(obs_counts)[:-1]
+        # Flat active-sample machinery shared by the (vectorized) observed-column
+        # computation and the bucket fill: one concatenated row list replaces the
+        # per-entity scipy CSR slicing that dominated build time at 100k+ entities.
+        lens = np.asarray([len(active_rows[e]) for e in entities], dtype=np.int64)
+        act_concat = (
+            np.concatenate([active_rows[e] for e in entities])
+            if n_ent
+            else np.zeros(0, dtype=np.int64)
         )
-    else:
-        # Pearson feature selection needs per-entity column/label statistics —
-        # the per-entity loop stays on this opt-in path only.
-        col_of = []
-        for e in entities:
-            rows = active_rows[e]
-            sub = X[rows]  # csr [s, d]
-            observed = np.unique(sub.indices) if sub.nnz else np.array([], dtype=np.int32)
-            if len(observed) > features_max:
-                if labels_arr is None:
-                    raise ValueError("features_max (Pearson selection) requires labels")
-                scores = _pearson_scores(sub, observed, labels_arr[rows])
-                keep_order = np.argsort(-scores, kind="mergesort")
-                kept = set(observed[keep_order[:features_max]].tolist())
-                if intercept_index is not None:
-                    kept.add(intercept_index)
-                observed = np.asarray(sorted(kept), dtype=observed.dtype)
-            col_of.append(observed.astype(np.int32))
+        ent_row_per_act = np.repeat(np.arange(n_ent, dtype=np.int64), lens)
+        act_starts = np.concatenate([[0], np.cumsum(lens)[:-1]]) if n_ent else lens
+        s_local_per_act = np.arange(len(act_concat)) - np.repeat(act_starts, lens)
+        # active nnz: global nnz positions of every active sample's entries
+        counts_all = np.diff(X.indptr)
+        c_act = counts_all[act_concat]
+        total_act_nnz = int(c_act.sum())
+        nnz_cum = np.concatenate([[0], np.cumsum(c_act)[:-1]]) if len(c_act) else c_act
+        act_nnz_idx = (
+            np.repeat(X.indptr[act_concat], c_act)
+            + (np.arange(total_act_nnz) - np.repeat(nnz_cum, c_act))
+        ).astype(np.int64)
+        ent_of_act_nnz = np.repeat(ent_row_per_act, c_act)
+        s_local_of_act_nnz = np.repeat(s_local_per_act, c_act)
 
-    # ---- global nnz -> entity-local column mapping ------------------------------
-    # local col = position of the global col in the entity's projection row.
-    # Vectorized over all nnz: a dense [E, D] lookup when it fits, else per-entity
-    # dict fallback (huge-D regimes). Used by BOTH the bucket fill (through
-    # act_nnz_idx) and the per-sample scoring view.
-    # map each sample's entity to its row id (vectorized: entities is sorted)
-    s_ent_rows = np.full(n, -1, dtype=np.int32)
-    uniq = np.asarray(entities)
-    if len(uniq):
-        # entity_order may leave `uniq` unsorted: search through a sorter so
-        # the lookup stays vectorized either way (identity when sorted)
-        sorter = np.argsort(uniq, kind="mergesort")
-        pos = np.searchsorted(uniq, ent, sorter=sorter)
-        rows = sorter[np.clip(pos, 0, len(uniq) - 1)]
-        hit = uniq[rows] == ent
-        s_ent_rows = np.where(hit, rows, -1).astype(np.int32)
-
-    local = np.full(X.nnz, -1, dtype=np.int32)
-    if n and X.nnz:
-        rows_per_nnz = np.repeat(np.arange(n), counts_all)
-        slot_per_nnz = np.arange(X.nnz) - np.repeat(X.indptr[:-1], counts_all)
-        ent_per_nnz = s_ent_rows[rows_per_nnz]
-        valid = ent_per_nnz >= 0
-        if n_ent * d <= 50_000_000:
-            lookup = np.full((max(n_ent, 1), d), -1, dtype=np.int32)
-            for i, cols in enumerate(col_of):
-                lookup[i, cols] = np.arange(len(cols), dtype=np.int32)
-            local[valid] = lookup[ent_per_nnz[valid], X.indices[valid]]
-        else:
-            local_of = [{int(c): k for k, c in enumerate(cols)} for cols in col_of]
-            idx_valid = np.flatnonzero(valid)
-            for t in idx_valid:
-                local[t] = local_of[ent_per_nnz[t]].get(int(X.indices[t]), -1)
-
-    # ---- bucketing by (padded sample count, padded feature count) ---------------
-    norm_factors = None if normalization is None or normalization.factors is None else np.asarray(normalization.factors)
-    norm_shifts = None if normalization is None or normalization.shifts is None else np.asarray(normalization.shifts)
-
-    k_counts = np.asarray([len(c) for c in col_of], dtype=np.int64)
-    bucket_members: dict[tuple[int, int], np.ndarray] = {}
-    if n_ent:
-        s_pads = np.asarray([_next_pow2(int(c), min_samples_pad) for c in lens])
-        k_pads = np.asarray(
-            [_next_pow2(max(int(k), 1), min_features_pad) for k in k_counts]
-        )
-        pad_keys = s_pads * (2 ** 32) + k_pads
-        for key in np.unique(pad_keys):
-            members = np.flatnonzero(pad_keys == key)
-            bucket_members[(int(key >> 32), int(key & (2 ** 32 - 1)))] = members
-        if not scoring_only:  # scoring datasets discard the buckets entirely
-            bucket_members = _consolidate_buckets(
-                bucket_members, n_ent, _resolve_merge_fraction(bucket_merge_fraction)
+        # ---- per-entity projection (+ optional Pearson selection) -------------------
+        # col_of[i]: sorted global col ids observed in entity i's ACTIVE rows.
+        if n_ent == 0:
+            col_of = []
+        elif features_max is None:
+            keys = ent_of_act_nnz * d + X.indices[act_nnz_idx].astype(np.int64)
+            uniq_keys = np.unique(keys)
+            ent_of_obs = uniq_keys // d
+            obs_counts = np.bincount(ent_of_obs, minlength=n_ent)
+            col_of = np.split(
+                (uniq_keys % d).astype(np.int32), np.cumsum(obs_counts)[:-1]
             )
+        else:
+            # Pearson feature selection needs per-entity column/label statistics —
+            # the per-entity loop stays on this opt-in path only.
+            col_of = []
+            for e in entities:
+                rows = active_rows[e]
+                sub = X[rows]  # csr [s, d]
+                observed = np.unique(sub.indices) if sub.nnz else np.array([], dtype=np.int32)
+                if len(observed) > features_max:
+                    if labels_arr is None:
+                        raise ValueError("features_max (Pearson selection) requires labels")
+                    scores = _pearson_scores(sub, observed, labels_arr[rows])
+                    keep_order = np.argsort(-scores, kind="mergesort")
+                    kept = set(observed[keep_order[:features_max]].tolist())
+                    if intercept_index is not None:
+                        kept.add(intercept_index)
+                    observed = np.asarray(sorted(kept), dtype=observed.dtype)
+                col_of.append(observed.astype(np.int32))
 
-    # Dataset-wide projection table is as wide as the widest PADDED bucket so that
-    # bucket slices coeffs_global[:, :K_bucket] always fit.
-    max_k_all = max((k for _, k in bucket_members), default=min_features_pad)
-    proj_table = np.full((n_ent, max_k_all), -1, dtype=np.int32)
-    for i, cols in enumerate(col_of):
-        proj_table[i, : len(cols)] = cols
+        # ---- global nnz -> entity-local column mapping ------------------------------
+        # local col = position of the global col in the entity's projection row.
+        # Vectorized over all nnz: a dense [E, D] lookup when it fits, else per-entity
+        # dict fallback (huge-D regimes). Used by BOTH the bucket fill (through
+        # act_nnz_idx) and the per-sample scoring view.
+        # map each sample's entity to its row id (vectorized: entities is sorted)
+        s_ent_rows = np.full(n, -1, dtype=np.int32)
+        uniq = np.asarray(entities)
+        if len(uniq):
+            # entity_order may leave `uniq` unsorted: search through a sorter so
+            # the lookup stays vectorized either way (identity when sorted)
+            sorter = np.argsort(uniq, kind="mergesort")
+            pos = np.searchsorted(uniq, ent, sorter=sorter)
+            rows = sorter[np.clip(pos, 0, len(uniq) - 1)]
+            hit = uniq[rows] == ent
+            s_ent_rows = np.where(hit, rows, -1).astype(np.int32)
 
-    buckets: list[EntityBucket] = []
-    if scoring_only:
-        bucket_members = {}
-    scale_arr = np.asarray([weights_scale[e] for e in entities], dtype=np.float64)
-    local_of_act_nnz = local[act_nnz_idx] if total_act_nnz else local[:0]
+        local = np.full(X.nnz, -1, dtype=np.int32)
+        if n and X.nnz:
+            rows_per_nnz = np.repeat(np.arange(n), counts_all)
+            slot_per_nnz = np.arange(X.nnz) - np.repeat(X.indptr[:-1], counts_all)
+            ent_per_nnz = s_ent_rows[rows_per_nnz]
+            valid = ent_per_nnz >= 0
+            if n_ent * d <= 50_000_000:
+                lookup = np.full((max(n_ent, 1), d), -1, dtype=np.int32)
+                for i, cols in enumerate(col_of):
+                    lookup[i, cols] = np.arange(len(cols), dtype=np.int32)
+                local[valid] = lookup[ent_per_nnz[valid], X.indices[valid]]
+            else:
+                local_of = [{int(c): k for k, c in enumerate(cols)} for cols in col_of]
+                idx_valid = np.flatnonzero(valid)
+                for t in idx_valid:
+                    local[t] = local_of[ent_per_nnz[t]].get(int(X.indices[t]), -1)
 
-    # One stable sort groups the flat sample/nnz arrays by bucket, so each
-    # bucket gets a contiguous slice instead of re-scanning everything
-    # (O(total_nnz) overall, not O(buckets x total_nnz)).
-    sorted_keys = sorted(bucket_members.items())
-    n_buckets = len(sorted_keys)
-    bucket_id = np.full(max(n_ent, 1), -1, dtype=np.int64)
-    e_local_all = np.zeros(max(n_ent, 1), dtype=np.int64)
-    for b, (_, members) in enumerate(sorted_keys):
-        bucket_id[members] = b
-        e_local_all[members] = np.arange(len(members))
-    act_order = np.argsort(bucket_id[ent_row_per_act], kind="stable") if n_ent else ent_row_per_act
-    act_bounds = np.searchsorted(
-        bucket_id[ent_row_per_act][act_order], np.arange(n_buckets + 1)
-    )
-    nnz_bucket = bucket_id[ent_of_act_nnz] if total_act_nnz else ent_of_act_nnz
-    nnz_valid_local = local_of_act_nnz >= 0
-    nnz_order = np.argsort(np.where(nnz_valid_local, nnz_bucket, -1), kind="stable")
-    nnz_bounds = np.searchsorted(
-        np.where(nnz_valid_local, nnz_bucket, -1)[nnz_order], np.arange(n_buckets + 1)
-    )
-    for b, ((s_pad, k_pad), members) in enumerate(sorted_keys):
-        eb = len(members)
-        Xb = np.zeros((eb, s_pad, k_pad), dtype=np.float64)
-        yb = np.zeros((eb, s_pad), dtype=np.float64)
-        wb = np.zeros((eb, s_pad), dtype=np.float64)
-        sb = np.full((eb, s_pad), -1, dtype=np.int32)
-        # sample-level fills (contiguous bucket slice)
-        ai = act_order[act_bounds[b] : act_bounds[b + 1]]
-        el_s, sl_s, rows_s = e_local_all[ent_row_per_act[ai]], s_local_per_act[ai], act_concat[ai]
-        if labels_arr is not None:
-            yb[el_s, sl_s] = labels_arr[rows_s]
-        wb[el_s, sl_s] = base_weights[rows_s] * scale_arr[ent_row_per_act[ai]]
-        sb[el_s, sl_s] = rows_s
-        # nnz-level X fill (duplicate (row, col) entries sum, as toarray does;
-        # bincount over raveled indices = vectorized scatter-add)
-        ni = nnz_order[nnz_bounds[b] : nnz_bounds[b + 1]]
-        gv = X.data[act_nnz_idx[ni]].astype(np.float64)
-        gc = X.indices[act_nnz_idx[ni]]
-        if norm_factors is not None:
-            gv = gv * norm_factors[gc]
-        flat = np.ravel_multi_index(
-            (e_local_all[ent_of_act_nnz[ni]], s_local_of_act_nnz[ni], local_of_act_nnz[ni]),
-            Xb.shape,
+        # ---- per-sample scoring view over the FULL sample axis ----------------------
+        nnz_max = max(int(counts_all.max()) if n else 1, 1)
+        s_cols = np.full((n, nnz_max), -1, dtype=np.int32)
+        s_vals = np.zeros((n, nnz_max), dtype=np.float64)
+        if n and X.nnz:
+            keep = local >= 0
+            s_cols[rows_per_nnz[keep], slot_per_nnz[keep]] = local[keep]
+            s_vals[rows_per_nnz[keep], slot_per_nnz[keep]] = X.data[keep]
+
+    with span("ingest.re_buckets", re_type=re_type):
+        # ---- bucketing by (padded sample count, padded feature count) ---------------
+        norm_factors = None if normalization is None or normalization.factors is None else np.asarray(normalization.factors)
+        norm_shifts = None if normalization is None or normalization.shifts is None else np.asarray(normalization.shifts)
+
+        k_counts = np.asarray([len(c) for c in col_of], dtype=np.int64)
+        bucket_members: dict[tuple[int, int], np.ndarray] = {}
+        if n_ent:
+            s_pads = np.asarray([_next_pow2(int(c), min_samples_pad) for c in lens])
+            k_pads = np.asarray(
+                [_next_pow2(max(int(k), 1), min_features_pad) for k in k_counts]
+            )
+            pad_keys = s_pads * (2 ** 32) + k_pads
+            for key in np.unique(pad_keys):
+                members = np.flatnonzero(pad_keys == key)
+                bucket_members[(int(key >> 32), int(key & (2 ** 32 - 1)))] = members
+            if not scoring_only:  # scoring datasets discard the buckets entirely
+                bucket_members = _consolidate_buckets(
+                    bucket_members, n_ent, _resolve_merge_fraction(bucket_merge_fraction)
+                )
+
+        # Dataset-wide projection table is as wide as the widest PADDED bucket so that
+        # bucket slices coeffs_global[:, :K_bucket] always fit.
+        max_k_all = max((k for _, k in bucket_members), default=min_features_pad)
+        proj_table = np.full((n_ent, max_k_all), -1, dtype=np.int32)
+        for i, cols in enumerate(col_of):
+            proj_table[i, : len(cols)] = cols
+
+        # padded blocks as HOST arrays: placed together in the ingest.h2d span below
+        host_buckets: list[tuple] = []
+        if scoring_only:
+            bucket_members = {}
+        scale_arr = np.asarray([weights_scale[e] for e in entities], dtype=np.float64)
+        local_of_act_nnz = local[act_nnz_idx] if total_act_nnz else local[:0]
+
+        # One stable sort groups the flat sample/nnz arrays by bucket, so each
+        # bucket gets a contiguous slice instead of re-scanning everything
+        # (O(total_nnz) overall, not O(buckets x total_nnz)).
+        sorted_keys = sorted(bucket_members.items())
+        n_buckets = len(sorted_keys)
+        bucket_id = np.full(max(n_ent, 1), -1, dtype=np.int64)
+        e_local_all = np.zeros(max(n_ent, 1), dtype=np.int64)
+        for b, (_, members) in enumerate(sorted_keys):
+            bucket_id[members] = b
+            e_local_all[members] = np.arange(len(members))
+        act_order = np.argsort(bucket_id[ent_row_per_act], kind="stable") if n_ent else ent_row_per_act
+        act_bounds = np.searchsorted(
+            bucket_id[ent_row_per_act][act_order], np.arange(n_buckets + 1)
         )
-        Xb += np.bincount(flat, weights=gv, minlength=Xb.size).reshape(Xb.shape)
-        if norm_shifts is not None:
-            # x' = (x - shift) * factor = x*factor - shift*factor: the shift term
-            # applies to every VALID (sample, observed-col) cell, zeros included.
-            base = np.zeros((eb, k_pad))
-            for bi, i in enumerate(members):
-                cols = col_of[i]
-                sh = -norm_shifts[cols]
-                if norm_factors is not None:
-                    sh = sh * norm_factors[cols]
-                base[bi, : len(cols)] = sh
-            row_valid = np.arange(s_pad)[None, :] < lens[members][:, None]
-            Xb += base[:, None, :] * row_valid[:, :, None]
-        buckets.append(
+        nnz_bucket = bucket_id[ent_of_act_nnz] if total_act_nnz else ent_of_act_nnz
+        nnz_valid_local = local_of_act_nnz >= 0
+        nnz_order = np.argsort(np.where(nnz_valid_local, nnz_bucket, -1), kind="stable")
+        nnz_bounds = np.searchsorted(
+            np.where(nnz_valid_local, nnz_bucket, -1)[nnz_order], np.arange(n_buckets + 1)
+        )
+        for b, ((s_pad, k_pad), members) in enumerate(sorted_keys):
+            eb = len(members)
+            Xb = np.zeros((eb, s_pad, k_pad), dtype=np.float64)
+            yb = np.zeros((eb, s_pad), dtype=np.float64)
+            wb = np.zeros((eb, s_pad), dtype=np.float64)
+            sb = np.full((eb, s_pad), -1, dtype=np.int32)
+            # sample-level fills (contiguous bucket slice)
+            ai = act_order[act_bounds[b] : act_bounds[b + 1]]
+            el_s, sl_s, rows_s = e_local_all[ent_row_per_act[ai]], s_local_per_act[ai], act_concat[ai]
+            if labels_arr is not None:
+                yb[el_s, sl_s] = labels_arr[rows_s]
+            wb[el_s, sl_s] = base_weights[rows_s] * scale_arr[ent_row_per_act[ai]]
+            sb[el_s, sl_s] = rows_s
+            # nnz-level X fill (duplicate (row, col) entries sum, as toarray does;
+            # bincount over raveled indices = vectorized scatter-add)
+            ni = nnz_order[nnz_bounds[b] : nnz_bounds[b + 1]]
+            gv = X.data[act_nnz_idx[ni]].astype(np.float64)
+            gc = X.indices[act_nnz_idx[ni]]
+            if norm_factors is not None:
+                gv = gv * norm_factors[gc]
+            flat = np.ravel_multi_index(
+                (e_local_all[ent_of_act_nnz[ni]], s_local_of_act_nnz[ni], local_of_act_nnz[ni]),
+                Xb.shape,
+            )
+            Xb += np.bincount(flat, weights=gv, minlength=Xb.size).reshape(Xb.shape)
+            if norm_shifts is not None:
+                # x' = (x - shift) * factor = x*factor - shift*factor: the shift term
+                # applies to every VALID (sample, observed-col) cell, zeros included.
+                base = np.zeros((eb, k_pad))
+                for bi, i in enumerate(members):
+                    cols = col_of[i]
+                    sh = -norm_shifts[cols]
+                    if norm_factors is not None:
+                        sh = sh * norm_factors[cols]
+                    base[bi, : len(cols)] = sh
+                row_valid = np.arange(s_pad)[None, :] < lens[members][:, None]
+                Xb += base[:, None, :] * row_valid[:, :, None]
+            host_buckets.append((members.astype(np.int32), Xb, yb, wb, sb))
+
+    n_active = sum(len(active_rows[e]) for e in entities)
+    padded_rows = sum(Xb.shape[0] * Xb.shape[1] for _rows, Xb, *_rest in host_buckets)
+    # device placement, synced: the one span that waits for the device (set-up
+    # only), so that host index building and H2D are separate numbers
+    with span("ingest.h2d", re_type=re_type):
+        buckets = [
             EntityBucket(
-                entity_rows=jnp.asarray(members.astype(np.int32)),
+                entity_rows=jnp.asarray(rows_b),
                 X=jnp.asarray(Xb, dtype=dtype),
                 labels=jnp.asarray(yb, dtype=dtype),
                 weights=jnp.asarray(wb, dtype=dtype),
                 sample_ids=jnp.asarray(sb),
             )
+            for rows_b, Xb, yb, wb, sb in host_buckets
+        ]
+        del host_buckets
+        placed = (
+            jnp.asarray(proj_table),
+            jnp.asarray(s_ent_rows),
+            jnp.asarray(s_cols),
+            jnp.asarray(s_vals, dtype=dtype),
         )
-
-    # ---- per-sample scoring view over the FULL sample axis ----------------------
-    nnz_max = max(int(counts_all.max()) if n else 1, 1)
-    s_cols = np.full((n, nnz_max), -1, dtype=np.int32)
-    s_vals = np.zeros((n, nnz_max), dtype=np.float64)
-    if n and X.nnz:
-        keep = local >= 0
-        s_cols[rows_per_nnz[keep], slot_per_nnz[keep]] = local[keep]
-        s_vals[rows_per_nnz[keep], slot_per_nnz[keep]] = X.data[keep]
-
-    n_active = sum(len(active_rows[e]) for e in entities)
+        jax.block_until_ready((buckets, placed))
     return RandomEffectDataset(
         re_type=re_type,
         feature_shard_id=feature_shard_id,
         entity_ids=tuple(entities),
         buckets=buckets,
-        proj_indices=jnp.asarray(proj_table),
-        sample_entity_rows=jnp.asarray(s_ent_rows),
-        sample_local_cols=jnp.asarray(s_cols),
-        sample_vals=jnp.asarray(s_vals, dtype=dtype),
+        proj_indices=placed[0],
+        sample_entity_rows=placed[1],
+        sample_local_cols=placed[2],
+        sample_vals=placed[3],
         n_samples=n,
         n_active_samples=n_active,
         n_passive_samples=passive_count,
         projector=projector,
+        padding_waste=1.0 - n_active / padded_rows if padded_rows else 0.0,
     )
 
 

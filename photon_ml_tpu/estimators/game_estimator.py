@@ -21,6 +21,7 @@ import logging
 import os
 from typing import Mapping, Optional, Sequence
 
+import jax
 import jax.numpy as jnp
 import numpy as np
 
@@ -63,6 +64,7 @@ from photon_ml_tpu.normalization import NO_NORMALIZATION, NormalizationContext
 from photon_ml_tpu.optimization.config import GLMOptimizationConfiguration
 from photon_ml_tpu.sampling.down_sampler import down_sampler_for_task
 from photon_ml_tpu.types import TaskType, VarianceComputationType
+from photon_ml_tpu.util.timed import count as timed_count, span
 
 logger = logging.getLogger(__name__)
 
@@ -290,21 +292,25 @@ class GameEstimator:
             if isinstance(dc, FixedEffectDataConfiguration):
                 from photon_ml_tpu.data.matrix import as_design_matrix_with_storage
 
-                X = as_design_matrix_with_storage(
-                    data.shard(dc.feature_shard_id),
-                    self.fe_storage_dtype,
-                    self.dtype,
-                )
-                datasets[cid] = FixedEffectDataset(
-                    LabeledData.build(
-                        X,
-                        data.labels,
-                        offsets=data.offsets,
-                        weights=data.weights,
-                        dtype=self.dtype,
-                    ),
-                    feature_shard_id=dc.feature_shard_id,
-                )
+                # the fixed effect's placement, synced like the random
+                # effects' (data/random_effect.py): set-up's H2D is a number
+                with span("ingest.h2d", cid=cid):
+                    X = as_design_matrix_with_storage(
+                        data.shard(dc.feature_shard_id),
+                        self.fe_storage_dtype,
+                        self.dtype,
+                    )
+                    datasets[cid] = FixedEffectDataset(
+                        LabeledData.build(
+                            X,
+                            data.labels,
+                            offsets=data.offsets,
+                            weights=data.weights,
+                            dtype=self.dtype,
+                        ),
+                        feature_shard_id=dc.feature_shard_id,
+                    )
+                    jax.block_until_ready(datasets[cid].data)
             elif isinstance(dc, RandomEffectDataConfiguration):
                 norm = self._normalization_for(dc.feature_shard_id)
                 X = as_csr(data.shard(dc.feature_shard_id))
@@ -332,6 +338,10 @@ class GameEstimator:
                     exclude_entities=(
                         None if exclude_entities is None else exclude_entities.get(cid)
                     ),
+                )
+                timed_count(
+                    "ingest.padding_waste", datasets[cid].padding_waste,
+                    cid=cid, rows=datasets[cid].n_active_samples,
                 )
             else:
                 raise TypeError(f"Unknown data configuration {type(dc).__name__}")
@@ -472,118 +482,123 @@ class GameEstimator:
     ) -> list[GameResult]:
         """Train one GAME model per expanded optimization configuration, chaining
         warm starts (GameEstimator.fit:299-380). Returns results in sweep order."""
-        locked = set(self.partial_retrain_locked_coordinates)
-        if locked and initial_model is None:
-            raise ValueError("partial retrain requires initial_model")
+        with span("fit"):
+            locked = set(self.partial_retrain_locked_coordinates)
+            if locked and initial_model is None:
+                raise ValueError("partial retrain requires initial_model")
 
-        datasets = self.prepare_training_datasets(data)
-        if self.fused_pass:
-            return self._fit_fused(datasets, validation_data, initial_model)
-        base_offsets = jnp.asarray(np.asarray(data.offsets), dtype=self.dtype)
-        if self.mesh is not None:
-            from photon_ml_tpu.parallel.placement import (
-                pad_and_shard_vector,
-                place_game_datasets,
-            )
-
-            datasets = place_game_datasets(datasets, self.mesh)
-            base_offsets = pad_and_shard_vector(
-                np.asarray(data.offsets), self.mesh, dtype=self.dtype
-            )
-
-        validation_datasets = None
-        suite = None
-        if validation_data is not None:
-            validation_datasets = self.prepare_scoring_datasets(validation_data)
-            if self.mesh is not None:
-                from photon_ml_tpu.parallel.placement import place_game_datasets
-
-                validation_datasets = place_game_datasets(validation_datasets, self.mesh)
-            suite = self.prepare_evaluation_suite(validation_data)
-
-        sweep = expand_game_configurations(self.coordinate_configurations)
-        logger.info(
-            "GAME sweep: %d configurations x %d coordinates",
-            len(sweep),
-            len(self.coordinate_configurations),
-        )
-
-        results: list[GameResult] = []
-        warm: Optional[GameModel] = initial_model
-        for i, opt_configs in enumerate(sweep):
-            coordinates: dict[str, Coordinate] = {}
-            init_models: dict[str, object] = {}
-            for cid in self.coordinate_configurations:
-                init = warm.get_model(cid) if warm is not None else None
-                coordinates[cid] = self.build_coordinate(
-                    cid, datasets[cid], opt_configs[cid], base_offsets, initial_model=init
-                )
-                if init is not None:
-                    init_models[cid] = (
-                        init.aligned_to(datasets[cid])
-                        if isinstance(datasets[cid], RandomEffectDataset)
-                        and hasattr(init, "aligned_to")
-                        else init
+            with span("fit.prepare"):
+                datasets = self.prepare_training_datasets(data)
+            if self.fused_pass:
+                return self._fit_fused(datasets, validation_data, initial_model)
+            with span("fit.build"):  # what every configuration of the sweep shares
+                base_offsets = jnp.asarray(np.asarray(data.offsets), dtype=self.dtype)
+                if self.mesh is not None:
+                    from photon_ml_tpu.parallel.placement import (
+                        pad_and_shard_vector,
+                        place_game_datasets,
                     )
-            checkpointer = None
-            if self.checkpoint_directory is not None:
-                from photon_ml_tpu.io.checkpoint import CoordinateDescentCheckpointer
 
-                # fingerprint ties the checkpoint to (task, this config, data
-                # size): a rerun with changed hyperparameters or data rejects
-                # the stale checkpoint instead of silently resuming from it
-                fp_parts = [
-                    str(TaskType(self.task).value),
-                    str(data.n),
-                    # validation identity: best_metric restored from a
-                    # checkpoint must be comparable to metrics of this run.
-                    # Spec NAMES, not str(): Evaluator dataclasses render
-                    # their fn field as a per-process function address, which
-                    # made a cross-PROCESS rerun reject its own checkpoint
-                    f"val={validation_data.n if validation_data is not None else 0}",
-                    f"evals={[evaluator_spec_name(e) for e in self.validation_evaluators]}",
-                    # solver identity: resuming an lbfgs-trained checkpoint
-                    # into a direct-solver run (or vice versa) would produce
-                    # a model that is neither path's contract
-                    f"re_solver={self.re_solver}",
-                    # storage-precision identity, same stale-restore class: a
-                    # bf16-trained checkpoint must not warm-start an f32 run
-                    # (or vice versa) pretending nothing changed
-                    f"re_precision={self.re_precision.name}",
-                ]
-                for cid in sorted(self.coordinate_configurations):
-                    fp_parts.append(f"{cid}={opt_configs[cid]!r}")
-                checkpointer = CoordinateDescentCheckpointer(
-                    os.path.join(self.checkpoint_directory, f"config_{i}"),
-                    interval=self.checkpoint_interval,
-                    dtype=self.dtype,
-                    fingerprint="|".join(fp_parts),
-                    keep_generations=self.checkpoint_keep_generations,
-                )
-            descent = run_coordinate_descent(
-                coordinates,
-                n_iterations=self.n_iterations,
-                initial_models=init_models or None,
-                validation_datasets=validation_datasets,
-                evaluation_suite=suite,
-                checkpointer=checkpointer,
+                    datasets = place_game_datasets(datasets, self.mesh)
+                    base_offsets = pad_and_shard_vector(
+                        np.asarray(data.offsets), self.mesh, dtype=self.dtype
+                    )
+
+            validation_datasets = None
+            suite = None
+            if validation_data is not None:
+                with span("fit.prepare"):
+                    validation_datasets = self.prepare_scoring_datasets(validation_data)
+                    if self.mesh is not None:
+                        from photon_ml_tpu.parallel.placement import place_game_datasets
+
+                        validation_datasets = place_game_datasets(validation_datasets, self.mesh)
+                    suite = self.prepare_evaluation_suite(validation_data)
+
+            sweep = expand_game_configurations(self.coordinate_configurations)
+            logger.info(
+                "GAME sweep: %d configurations x %d coordinates",
+                len(sweep),
+                len(self.coordinate_configurations),
             )
-            evaluations = None
-            if suite is not None and (descent.metrics_history or descent.best_metrics):
-                # metrics of the best snapshot = the history row that set best_metric
-                evaluations = _metrics_of_best(descent)
-            results.append(
-                GameResult(
-                    model=descent.model,
-                    best_model=descent.best_model,
-                    configuration=opt_configs,
-                    evaluations=evaluations,
-                    best_metric=descent.best_metric,
-                    descent=descent,
+
+            results: list[GameResult] = []
+            warm: Optional[GameModel] = initial_model
+            for i, opt_configs in enumerate(sweep):
+                with span("fit.build", configuration=i):
+                    coordinates: dict[str, Coordinate] = {}
+                    init_models: dict[str, object] = {}
+                    for cid in self.coordinate_configurations:
+                        init = warm.get_model(cid) if warm is not None else None
+                        coordinates[cid] = self.build_coordinate(
+                            cid, datasets[cid], opt_configs[cid], base_offsets, initial_model=init
+                        )
+                        if init is not None:
+                            init_models[cid] = (
+                                init.aligned_to(datasets[cid])
+                                if isinstance(datasets[cid], RandomEffectDataset)
+                                and hasattr(init, "aligned_to")
+                                else init
+                            )
+                    checkpointer = None
+                    if self.checkpoint_directory is not None:
+                        from photon_ml_tpu.io.checkpoint import CoordinateDescentCheckpointer
+
+                        # fingerprint ties the checkpoint to (task, this config, data
+                        # size): a rerun with changed hyperparameters or data rejects
+                        # the stale checkpoint instead of silently resuming from it
+                        fp_parts = [
+                            str(TaskType(self.task).value),
+                            str(data.n),
+                            # validation identity: best_metric restored from a
+                            # checkpoint must be comparable to metrics of this run.
+                            # Spec NAMES, not str(): Evaluator dataclasses render
+                            # their fn field as a per-process function address, which
+                            # made a cross-PROCESS rerun reject its own checkpoint
+                            f"val={validation_data.n if validation_data is not None else 0}",
+                            f"evals={[evaluator_spec_name(e) for e in self.validation_evaluators]}",
+                            # solver identity: resuming an lbfgs-trained checkpoint
+                            # into a direct-solver run (or vice versa) would produce
+                            # a model that is neither path's contract
+                            f"re_solver={self.re_solver}",
+                            # storage-precision identity, same stale-restore class: a
+                            # bf16-trained checkpoint must not warm-start an f32 run
+                            # (or vice versa) pretending nothing changed
+                            f"re_precision={self.re_precision.name}",
+                        ]
+                        for cid in sorted(self.coordinate_configurations):
+                            fp_parts.append(f"{cid}={opt_configs[cid]!r}")
+                        checkpointer = CoordinateDescentCheckpointer(
+                            os.path.join(self.checkpoint_directory, f"config_{i}"),
+                            interval=self.checkpoint_interval,
+                            dtype=self.dtype,
+                            fingerprint="|".join(fp_parts),
+                            keep_generations=self.checkpoint_keep_generations,
+                        )
+                descent = run_coordinate_descent(
+                    coordinates,
+                    n_iterations=self.n_iterations,
+                    initial_models=init_models or None,
+                    validation_datasets=validation_datasets,
+                    evaluation_suite=suite,
+                    checkpointer=checkpointer,
                 )
-            )
-            warm = descent.best_model  # chain warm starts across the sweep
-        return results
+                evaluations = None
+                if suite is not None and (descent.metrics_history or descent.best_metrics):
+                    # metrics of the best snapshot = the history row that set best_metric
+                    evaluations = _metrics_of_best(descent)
+                results.append(
+                    GameResult(
+                        model=descent.model,
+                        best_model=descent.best_model,
+                        configuration=opt_configs,
+                        evaluations=evaluations,
+                        best_metric=descent.best_metric,
+                        descent=descent,
+                    )
+                )
+                warm = descent.best_model  # chain warm starts across the sweep
+            return results
 
     def _fit_fused(
         self,

@@ -48,14 +48,15 @@ def random_effect_view_score(
     parity gates honest (jit-in-jit callers simply inline the same
     subgraph, which XLA fuses the same way — asserted by the update-program
     parity tests and the serving bench gate)."""
-    has_model = entity_rows >= 0
-    safe_rows = jnp.maximum(entity_rows, 0)
-    w = coeffs[safe_rows]  # [N, K]
-    safe_cols = jnp.maximum(local_cols, 0)
-    gathered = jnp.take_along_axis(w, safe_cols, axis=1)  # [N, nnz]
-    gathered = jnp.where(local_cols >= 0, gathered, 0.0)
-    scores = jnp.sum(gathered * vals, axis=1)
-    return jnp.where(has_model, scores, 0.0)
+    with jax.named_scope("re.view_score"):
+        has_model = entity_rows >= 0
+        safe_rows = jnp.maximum(entity_rows, 0)
+        w = coeffs[safe_rows]  # [N, K]
+        safe_cols = jnp.maximum(local_cols, 0)
+        gathered = jnp.take_along_axis(w, safe_cols, axis=1)  # [N, nnz]
+        gathered = jnp.where(local_cols >= 0, gathered, 0.0)
+        scores = jnp.sum(gathered * vals, axis=1)
+        return jnp.where(has_model, scores, 0.0)
 
 
 def _projectors_compatible(a, b) -> bool:

@@ -73,6 +73,10 @@ class OptResult(NamedTuple):
     convergence_reason: Array  # int – ConvergenceReason code
     tracked_values: Optional[Array] = None  # [max_iter+1] objective values (nan-padded)
     tracked_grad_norms: Optional[Array] = None
+    # int – value-and-gradient evaluations of the solve (the initial one, every
+    # line-search trial, a post-projection re-evaluation); None from minimisers
+    # that do not count them
+    evaluations: Optional[Array] = None
 
     @property
     def converged(self) -> Array:

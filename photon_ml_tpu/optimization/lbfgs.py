@@ -42,6 +42,7 @@ class _LBFGSState(NamedTuple):
     Y: Array  # [m, d] gradient-difference history, newest first
     rho: Array  # [m] 1 / (s.y), newest first
     k: Array  # iteration counter
+    evals: Array  # value-and-gradient evaluations so far (int32)
     n_written: Array  # total (s, y) pairs ever stored (min(n_written, m) valid)
     reason: Array
     tracked_values: Optional[Array]
@@ -157,6 +158,7 @@ def minimize_lbfgs(
         Y=jnp.zeros((m, d), dtype),
         rho=jnp.zeros((m,), dtype),
         k=jnp.asarray(0, jnp.int32),
+        evals=jnp.asarray(1, jnp.int32),  # the initial evaluation at x0
         n_written=jnp.asarray(0, jnp.int32),
         reason=reason0,
         tracked_values=tv,
@@ -167,12 +169,13 @@ def minimize_lbfgs(
         return st.reason == ConvergenceReason.NOT_CONVERGED
 
     def body(st: _LBFGSState):
-        direction = two_loop_direction(st.g, st.S, st.Y, st.rho, st.n_written)
-        dphi0 = jnp.dot(st.g, direction)
-        # Safeguard: fall back to steepest descent if not a descent direction.
-        bad = dphi0 >= 0
-        direction = jnp.where(bad, -st.g, direction)
-        dphi0 = jnp.where(bad, -jnp.dot(st.g, st.g), dphi0)
+        with jax.named_scope("lbfgs.direction"):
+            direction = two_loop_direction(st.g, st.S, st.Y, st.rho, st.n_written)
+            dphi0 = jnp.dot(st.g, direction)
+            # Safeguard: fall back to steepest descent if not a descent direction.
+            bad = dphi0 >= 0
+            direction = jnp.where(bad, -st.g, direction)
+            dphi0 = jnp.where(bad, -jnp.dot(st.g, st.g), dphi0)
 
         def phi(a):
             xt = st.x + a * direction
@@ -183,14 +186,16 @@ def minimize_lbfgs(
         init_alpha = jnp.where(
             st.k == 0, jnp.minimum(1.0, 1.0 / jnp.where(gnorm > 0, gnorm, 1.0)), 1.0
         ).astype(dtype)
-        ls = linesearch.strong_wolfe(
-            phi, st.f, st.g, dphi0, init_alpha,
-            max_iters=max_line_search_iterations,
-            # a batched outer loop freezes converged lanes' carries but still
-            # computes their bodies: without this mask a converged lane's
-            # stale-state search sets the inner trip count every iteration
-            active=st.reason == ConvergenceReason.NOT_CONVERGED,
-        )
+        with jax.named_scope("lbfgs.linesearch"):
+            ls = linesearch.strong_wolfe(
+                phi, st.f, st.g, dphi0, init_alpha,
+                max_iters=max_line_search_iterations,
+                # a batched outer loop freezes converged lanes' carries but still
+                # computes their bodies: without this mask a converged lane's
+                # stale-state search sets the inner trip count every iteration
+                active=st.reason == ConvergenceReason.NOT_CONVERGED,
+            )
+        evals = st.evals + ls.evals
 
         step = ls.alpha * direction
         x_new = project(st.x + step)
@@ -199,6 +204,7 @@ def minimize_lbfgs(
         # x_new; recompute only when a projection is active (static decision).
         if lower_bounds is not None or upper_bounds is not None:
             f_new, g_new = value_and_grad(x_new)
+            evals = evals + 1
         else:
             f_new, g_new = ls.value, ls.grad
 
@@ -227,7 +233,7 @@ def minimize_lbfgs(
         g_new = jnp.where(ls.success, g_new, st.g)
 
         tv, tg = record_tracking(st.tracked_values, st.tracked_gnorms, k_new, f_new, jnp.linalg.norm(g_new))
-        return _LBFGSState(x_new, f_new, g_new, S, Y, rho, k_new, n_written, reason, tv, tg)
+        return _LBFGSState(x_new, f_new, g_new, S, Y, rho, k_new, evals, n_written, reason, tv, tg)
 
     final = lax.while_loop(cond, body, init)
     return OptResult(
@@ -238,4 +244,5 @@ def minimize_lbfgs(
         convergence_reason=final.reason,
         tracked_values=final.tracked_values,
         tracked_grad_norms=final.tracked_gnorms,
+        evaluations=final.evals,
     )

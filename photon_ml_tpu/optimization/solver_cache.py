@@ -83,7 +83,10 @@ def glm_solver(
     use_hess = OptimizerType(opt_config.optimizer_type) == OptimizerType.NEWTON
     variance = VarianceComputationType(variance)
 
-    def solve(data, x0, l2, l1, lower, upper, norm):
+    # the inner functions of this module are NAMED for what they are: XLA
+    # calls the compiled module jit_<name>, and that is how a profiler trace
+    # (and the benchmark's breakdown) tells one program from another
+    def fe_solve(data, x0, l2, l1, lower, upper, norm):
         obj = GLMObjective(loss, norm, allow_fused=allow_fused)
 
         def vg(w):
@@ -100,13 +103,14 @@ def glm_solver(
             kwargs["lower_bounds"] = lower
         if has_upper:
             kwargs["upper_bounds"] = upper
-        result = minimize(vg, x0, **kwargs)
+        with jax.named_scope("fe.solve"):
+            result = minimize(vg, x0, **kwargs)
         variances = compute_variances(
             obj, data, result.coefficients, l2, variance, x0.dtype
         )
         return result, variances
 
-    return jax.jit(solve)
+    return jax.jit(fe_solve)
 
 
 def _masked_value_and_grad(vg, active):
@@ -153,7 +157,12 @@ def _re_bucket_solve_fn(
     solve signature (the population early-exit path): inactive lanes see a
     masked stationary objective and solve in zero iterations, and report
     zero iterations. Default False keeps the existing program signatures
-    untouched."""
+    untouched.
+
+    Returns per lane ``(coefficients, reason, iterations, evaluations,
+    variances)``; ``evaluations`` is the minimiser's own count of
+    value-and-gradient evaluations (``OptResult.evaluations``), None from a
+    minimiser that does not count them."""
     task = TaskType(task)
     loss = loss_for_task(task)
     minimize = build_minimizer(opt_config)
@@ -165,7 +174,15 @@ def _re_bucket_solve_fn(
     from photon_ml_tpu.data.dataset import LabeledData
     from photon_ml_tpu.data.matrix import DenseDesignMatrix
 
-    def solve_one(Xe, ye, we, oe, w0, l2, l1, active=None):
+    def lane_counts(res, active):
+        iters, evals = res.iterations, res.evaluations
+        if active is not None:
+            iters = jnp.where(active, iters, jnp.zeros_like(iters))
+            if evals is not None:
+                evals = jnp.where(active, evals, jnp.zeros_like(evals))
+        return iters, evals
+
+    def re_bucket_solve(Xe, ye, we, oe, w0, l2, l1, active=None):
         data = LabeledData(X=DenseDesignMatrix(Xe), labels=ye, offsets=oe, weights=we)
         obj = GLMObjective(loss, allow_fused=False)  # vmapped: no pallas path
 
@@ -189,10 +206,8 @@ def _re_bucket_solve_fn(
                 active=active,
             )
             var = compute_variances(obj, data, res.coefficients, l2, variance, w0.dtype)
-            iters = res.iterations
-            if active is not None:
-                iters = jnp.where(active, iters, jnp.zeros_like(iters))
-            return res.coefficients, res.convergence_reason, iters, var
+            iters, evals = lane_counts(res, active)
+            return res.coefficients, res.convergence_reason, iters, evals, var
 
         def vg(w):
             return obj.value_and_gradient(data, w, l2)
@@ -213,14 +228,12 @@ def _re_bucket_solve_fn(
             )
         res = minimize(vg, w0, **kwargs)
         var = compute_variances(obj, data, res.coefficients, l2, variance, w0.dtype)
-        iters = res.iterations
-        if active is not None:
-            iters = jnp.where(active, iters, jnp.zeros_like(iters))
-        return res.coefficients, res.convergence_reason, iters, var
+        iters, evals = lane_counts(res, active)
+        return res.coefficients, res.convergence_reason, iters, evals, var
 
     if with_active:
-        return jax.vmap(solve_one, in_axes=(0, 0, 0, 0, 0, 0, None, None))
-    return jax.vmap(solve_one, in_axes=(0, 0, 0, 0, 0, 0, None))
+        return jax.vmap(re_bucket_solve, in_axes=(0, 0, 0, 0, 0, 0, None, None))
+    return jax.vmap(re_bucket_solve, in_axes=(0, 0, 0, 0, 0, 0, None))
 
 
 @functools.lru_cache(maxsize=None)
@@ -232,7 +245,8 @@ def re_bucket_solver(
     re_solver: str = "lbfgs",
 ):
     """Jitted vmapped per-entity bucket solve:
-    ``solve(X, y, w, offsets, w0, l2, l1) -> (coefs, reasons, iters, variances)``
+    ``solve(X, y, w, offsets, w0, l2, l1) -> (coefs, reasons, iters, evals,
+    variances)``
     with X [E, S, K], l2 a PER-ENTITY [E] vector (the reference only envisioned
     per-entity regularization weights, RandomEffectOptimizationProblem.scala:
     34-37 — here each entity's solve traces its own weight) and l1 broadcast —
@@ -312,14 +326,15 @@ def _re_coordinate_update_fn(
                 f"per-bucket re_solver plan covers {len(solve_plan)} buckets, "
                 f"update traces {len(buckets)}"
             )
-        reasons, iters = [], []
+        reasons, iters, evals = [], [], []
         for b_i, (bucket, norm_tbl) in enumerate(zip(buckets, norm_tables)):
             solve_b = solve_plan[b_i] if solve_plan is not None else solve
             S, K = bucket.shape
-            off_b = jnp.take(
-                offsets_plus_scores, jnp.maximum(bucket.sample_ids, 0), axis=0
-            )
-            off_b = jnp.where(bucket.sample_ids >= 0, off_b, 0.0).astype(solve_dtype)
+            with jax.named_scope("re.offsets_gather"):
+                off_b = jnp.take(
+                    offsets_plus_scores, jnp.maximum(bucket.sample_ids, 0), axis=0
+                )
+                off_b = jnp.where(bucket.sample_ids >= 0, off_b, 0.0).astype(solve_dtype)
             init_b = coeffs[bucket.entity_rows, :K]
             if reduced:
                 init_b = init_b.astype(solve_dtype)
@@ -337,7 +352,8 @@ def _re_coordinate_update_fn(
             )
             if with_active:
                 solve_args = solve_args + (active,)
-            w_b, reasons_b, iters_b, var_b = solve_b(*solve_args)
+            with jax.named_scope("re.bucket_solve"):
+                w_b, reasons_b, iters_b, evals_b, var_b = solve_b(*solve_args)
             if norm_tbl is not None:
                 w_b = _to_original(w_b, factors, shifts, icpt_mask)
                 if variances is not None and factors is not None:
@@ -348,11 +364,13 @@ def _re_coordinate_update_fn(
                 w_b = w_b.astype(coeffs.dtype)
                 if variances is not None:
                     var_b = var_b.astype(variances.dtype)
-            coeffs = coeffs.at[bucket.entity_rows, :K].set(w_b)
-            if variances is not None:
-                variances = variances.at[bucket.entity_rows, :K].set(var_b)
+            with jax.named_scope("re.table_scatter"):
+                coeffs = coeffs.at[bucket.entity_rows, :K].set(w_b)
+                if variances is not None:
+                    variances = variances.at[bucket.entity_rows, :K].set(var_b)
             reasons.append(reasons_b)
             iters.append(iters_b)
+            evals.append(evals_b)
         if coeffs.shape[0] > n_entities:
             # padded table heights keep every padding row identically zero
             coeffs = coeffs.at[n_entities:].set(0.0)
@@ -372,19 +390,23 @@ def _re_coordinate_update_fn(
         # Device-side divergence guard: variances are deliberately excluded
         # (algorithm/coordinate.coefficient_arrays — a singular-Hessian
         # variance failure must not discard a converged mean update).
-        ok = jnp.isfinite(coeffs).all()
-        keep = ok if active is None else jnp.logical_and(ok, active)
-        coeffs_out = jnp.where(keep, coeffs, coeffs_prev)
-        score_out = jnp.where(keep, score, score_prev)
-        var_out = None if variances is None else jnp.where(keep, variances, var_prev)
-        if active is not None:
-            # a frozen lane carrying its committed state is not a reject
-            ok = jnp.logical_or(ok, jnp.logical_not(active))
-        return coeffs_out, score_out, var_out, ok, tuple(reasons), tuple(iters)
+        with jax.named_scope("re.guard"):
+            ok = jnp.isfinite(coeffs).all()
+            keep = ok if active is None else jnp.logical_and(ok, active)
+            coeffs_out = jnp.where(keep, coeffs, coeffs_prev)
+            score_out = jnp.where(keep, score, score_prev)
+            var_out = None if variances is None else jnp.where(keep, variances, var_prev)
+            if active is not None:
+                # a frozen lane carrying its committed state is not a reject
+                ok = jnp.logical_or(ok, jnp.logical_not(active))
+        return (
+            coeffs_out, score_out, var_out, ok,
+            tuple(reasons), tuple(iters), tuple(evals),
+        )
 
     if with_active:
 
-        def update(
+        def re_coordinate_update(
             coeffs_prev, score_prev, var_prev, offsets_plus_scores, l2_rows,
             l1, active, buckets, norm_tables, view,
         ):
@@ -393,9 +415,9 @@ def _re_coordinate_update_fn(
                 l2_rows, l1, buckets, norm_tables, view, active,
             )
 
-        return update
+        return re_coordinate_update
 
-    def update(
+    def re_coordinate_update(
         coeffs_prev, score_prev, var_prev, offsets_plus_scores, l2_rows, l1,
         buckets, norm_tables, view,
     ):
@@ -404,7 +426,7 @@ def _re_coordinate_update_fn(
             l1, buckets, norm_tables, view,
         )
 
-    return update
+    return re_coordinate_update
 
 
 @functools.lru_cache(maxsize=None)
@@ -427,7 +449,7 @@ def re_coordinate_update_program(
 
     ``update(coeffs_prev, score_prev, var_prev, offsets_plus_scores, l2_rows,
     l1, buckets, norm_tables, view) -> (coeffs, score, variances, ok,
-    reasons_per_bucket, iters_per_bucket)``
+    reasons_per_bucket, iters_per_bucket, evals_per_bucket)``
 
     - ``coeffs_prev`` ``[E, K_max]`` / ``score_prev`` ``[N]`` / ``var_prev``
       (``[E, K_max]`` or None) are DONATED: the hot loop stops copying the
@@ -467,15 +489,17 @@ def re_coordinate_update_program(
         table_sharding, score_sharding = shardings
         inner_update = update
 
-        def update(coeffs_prev, score_prev, var_prev, *rest):
-            coeffs, score, var, ok, reasons, iters = inner_update(
+        def re_coordinate_update(coeffs_prev, score_prev, var_prev, *rest):
+            coeffs, score, var, *counts = inner_update(
                 coeffs_prev, score_prev, var_prev, *rest
             )
             coeffs = jax.lax.with_sharding_constraint(coeffs, table_sharding)
             score = jax.lax.with_sharding_constraint(score, score_sharding)
             if var is not None:
                 var = jax.lax.with_sharding_constraint(var, table_sharding)
-            return coeffs, score, var, ok, reasons, iters
+            return (coeffs, score, var, *counts)
+
+        update = re_coordinate_update
 
     return jax.jit(update, donate_argnums=(0, 1, 2))
 
@@ -527,7 +551,7 @@ def re_chunk_update_program(
     solve = _re_bucket_solve_fn(task, opt_config, has_l1, variance, re_solver)
     variance_on = VarianceComputationType(variance) != VarianceComputationType.NONE
 
-    def update(
+    def re_chunk_update(
         init_chunk, score_partial, X, y, w, sample_ids, l2, l1, norm_rows,
         offsets_plus_scores, view_cols, view_vals,
     ):
@@ -541,7 +565,7 @@ def re_chunk_update_program(
         if norm_rows is not None:
             factors, shifts, icpt_mask = norm_rows
             init = _to_transformed(init, factors, shifts, icpt_mask)
-        w_out, reasons, iters, var_out = solve(X, y, w, off, init, l2, l1)
+        w_out, reasons, iters, _evals, var_out = solve(X, y, w, off, init, l2, l1)
         if norm_rows is not None:
             w_out = _to_original(w_out, factors, shifts, icpt_mask)
             if variance_on and factors is not None:
@@ -577,7 +601,7 @@ def re_chunk_update_program(
             iters,
         )
 
-    return jax.jit(update, donate_argnums=(0, 1))
+    return jax.jit(re_chunk_update, donate_argnums=(0, 1))
 
 
 @functools.lru_cache(maxsize=None)
@@ -592,7 +616,7 @@ def re_chunk_score_program():
     score_partial`` with ``score_partial`` ``[N]`` DONATED (threaded through
     every chunk of the pass)."""
 
-    def score_chunk(score_partial, w_rows, sample_ids, view_cols, view_vals):
+    def re_chunk_score(score_partial, w_rows, sample_ids, view_cols, view_vals):
         from photon_ml_tpu.models.game import random_effect_view_score
 
         C = w_rows.shape[0]
@@ -615,7 +639,7 @@ def re_chunk_score_program():
             contrib.astype(score_partial.dtype), mode="drop"
         )
 
-    return jax.jit(score_chunk, donate_argnums=(0,))
+    return jax.jit(re_chunk_score, donate_argnums=(0,))
 
 
 @functools.lru_cache(maxsize=None)
@@ -665,10 +689,14 @@ def re_population_update_program(
         if with_active
         else (0, 0, 0, 0, 0, 0, None, None, None)
     )
-    return jax.jit(
-        jax.vmap(update, in_axes=in_axes),
-        donate_argnums=(0, 1, 2),
-    )
+    population = jax.vmap(update, in_axes=in_axes)
+
+    def re_population_update(*args):
+        # the sweep keeps no tracker: the per-lane evaluation counts are
+        # dropped here (and die in XLA), the six outputs stay what they were
+        return population(*args)[:6]
+
+    return jax.jit(re_population_update, donate_argnums=(0, 1, 2))
 
 
 def _fe_population_update_fn(
@@ -756,7 +784,7 @@ def _fe_population_update_fn(
             solve_one, in_axes=(0, 0, 0, 0, 0, 0, None, 0, None, None)
         )
 
-        def update(
+        def fe_population_update(
             coeffs_prev, score_prev, offsets_pop, l2, l1, rates, keep_u,
             active, data, norm,
         ):
@@ -765,19 +793,19 @@ def _fe_population_update_fn(
                 active, data, norm,
             )
 
-        return update
+        return fe_population_update
 
     vmapped = jax.vmap(
         solve_one, in_axes=(0, 0, 0, 0, 0, 0, None, None, None, None)
     )
 
-    def update(coeffs_prev, score_prev, offsets_pop, l2, l1, rates, keep_u, data, norm):
+    def fe_population_update(coeffs_prev, score_prev, offsets_pop, l2, l1, rates, keep_u, data, norm):
         return vmapped(
             coeffs_prev, score_prev, offsets_pop, l2, l1, rates, keep_u, None,
             data, norm,
         )
 
-    return update
+    return fe_population_update
 
 
 @functools.lru_cache(maxsize=None)
@@ -871,7 +899,7 @@ def fe_coordinate_update_program(
     use_hvp = OptimizerType(opt_config.optimizer_type) == OptimizerType.TRON
     use_hess = OptimizerType(opt_config.optimizer_type) == OptimizerType.NEWTON
 
-    def update(coeffs_prev, score_prev, offsets_plus_scores, l2, l1, data, norm):
+    def fe_coordinate_update(coeffs_prev, score_prev, offsets_plus_scores, l2, l1, data, norm):
         d2 = data.with_offsets(offsets_plus_scores)
         obj = GLMObjective(loss, norm, allow_fused=allow_fused)
         x0 = norm.to_transformed_space_device(coeffs_prev)
@@ -904,7 +932,7 @@ def fe_coordinate_update_program(
             res.value, res.iterations, res.convergence_reason,
         )
 
-    return jax.jit(update, donate_argnums=(0, 1))
+    return jax.jit(fe_coordinate_update, donate_argnums=(0, 1))
 
 
 @functools.lru_cache(maxsize=None)
@@ -929,7 +957,7 @@ def sharded_glm_solver(
     use_hvp = OptimizerType(opt_config.optimizer_type) == OptimizerType.TRON
     use_hess = OptimizerType(opt_config.optimizer_type) == OptimizerType.NEWTON
 
-    def solve(data, x0, l2, l1, lower, upper, norm):
+    def fe_sharded_solve(data, x0, l2, l1, lower, upper, norm):
         # Multi-device mesh path: GSPMD cannot partition an opaque pallas_call,
         # so the fused kernel stays off here regardless of the global switch.
         obj = GLMObjective(loss, norm, allow_fused=False)
@@ -950,7 +978,7 @@ def sharded_glm_solver(
             kwargs["upper_bounds"] = upper
         return minimize(vg, x0, **kwargs)
 
-    return jax.jit(solve, out_shardings=replicated_sharding(mesh))
+    return jax.jit(fe_sharded_solve, out_shardings=replicated_sharding(mesh))
 
 
 @functools.lru_cache(maxsize=None)
@@ -1008,7 +1036,7 @@ def shard_mapped_glm_solver(
             tree,
         )
 
-    def solve(data, x0, l2, l1):
+    def fe_shard_mapped_solve(data, x0, l2, l1):
         from photon_ml_tpu.data.matrix import DenseDesignMatrix
 
         if not isinstance(data.X, DenseDesignMatrix):
@@ -1032,7 +1060,7 @@ def shard_mapped_glm_solver(
         )
         return mapped(data, x0, l2, l1)
 
-    return jax.jit(solve)
+    return jax.jit(fe_shard_mapped_solve)
 
 
 _extra_caches: list = []

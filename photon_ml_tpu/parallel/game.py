@@ -317,7 +317,7 @@ def game_train_step(
             off_b = jnp.take(offsets_plus, jnp.maximum(b.sample_ids, 0), axis=0)
             off_b = jnp.where(b.sample_ids >= 0, off_b, 0.0)
             w0_b = coeffs[b.entity_rows, :K]
-            w_b, _, it_b, _ = solve(
+            w_b, _, it_b, _, _ = solve(
                 b.X,
                 b.labels,
                 b.weights,
@@ -542,7 +542,7 @@ def population_sweep_fn(
                 partial = total - st["score"]
                 offsets_pop = base_offsets[None, :] + partial
                 if spec.kind == "re":
-                    coeffs, score, _var, ok, _reasons, iters = bodies[cid](
+                    coeffs, score, _var, ok, _reasons, iters, _evals = bodies[cid](
                         st["coeffs"], st["score"], None, offsets_pop,
                         lane["l2_rows"], lane["l1"], active,
                         data["buckets"], data["norm_tables"], data["view"],
