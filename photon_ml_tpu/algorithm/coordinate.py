@@ -147,6 +147,16 @@ class Coordinate:
     def initialize_model(self):
         raise NotImplementedError
 
+    def zero_model_score(self) -> Optional[Array]:
+        """The [N] score ``self.score(self.initialize_model())`` would return
+        — same shape, dtype, placement and bits — WITHOUT computing it, or
+        None when this coordinate cannot promise that its initial model
+        scores zero (the descent loop then scores it). Only the coordinate
+        knows what ``initialize_model`` hands out: a locked coordinate's
+        initial model is its trained one. A NEW array every call: the fused
+        update protocol may consume (donate) its score input."""
+        return None
+
     def prepare_initial_model(self, model):
         """Adapt an externally supplied warm-start model to this coordinate's
         (possibly mesh-placed) dataset. Default: unchanged."""
@@ -606,6 +616,21 @@ class RandomEffectCoordinate(Coordinate):
             projector=self.dataset.projector,
         )
 
+    def zero_model_score(self) -> Array:
+        # initialize_model() is a zero table by construction, and the view
+        # kernel's sum of 0 * v over finite values is +0.0: the [N] zeros it
+        # would return, without its [N, K] gather. Shape, dtype and (on a
+        # mesh) sharding are the kernel's own output's, so the first
+        # update_and_score resolves the program it resolves after a
+        # kernel-made score.
+        ds = self.dataset
+        shardings = self._state_shardings()
+        return jnp.zeros(
+            (int(ds.sample_entity_rows.shape[0]),),
+            dtype=ds.sample_vals.dtype,
+            device=None if shardings is None else shardings[1],
+        )
+
     def prepare_initial_model(self, model: RandomEffectModel) -> RandomEffectModel:
         # re-align entity rows to this dataset (warm start across rebuilt or
         # differently ordered datasets), then adopt the dataset's TABLE
@@ -990,6 +1015,23 @@ class RandomEffectCoordinate(Coordinate):
             )
         return self._fused_static
 
+    def _state_shardings(self):
+        """``(table, score)`` shardings of the state a mesh-placed dataset's
+        updates donate, None off a mesh: the table (and variances)
+        entity-sharded, the [N] score sample-sharded — the explicit
+        out-constraints in solver_cache pin them so no resharding ever lands
+        between updates, and ``zero_model_score`` places the first score
+        under the same one."""
+        sharding = getattr(self.dataset, "coeffs_sharding", None)
+        if sharding is None:
+            return None
+        from jax.sharding import NamedSharding, PartitionSpec
+
+        return (
+            sharding,
+            NamedSharding(sharding.mesh, PartitionSpec(sharding.spec[0])),
+        )
+
     def _resolve_update_program(self):
         """``(program, table_dtype, table_rows, table_sharding, shardings)``
         — the cached update program at this coordinate's static
@@ -1015,18 +1057,7 @@ class RandomEffectCoordinate(Coordinate):
         # mesh placement pads the table height to a device multiple (rows
         # >= n_entities are always-zero padding the program re-zeroes)
         rows = getattr(ds, "coeffs_rows", None) or ds.n_entities
-        shardings = None
-        if sharding is not None:
-            from jax.sharding import NamedSharding, PartitionSpec
-
-            # donated state keeps these across iterations: the table (and
-            # variances) entity-sharded, the [N] score sample-sharded — the
-            # explicit out-constraints in solver_cache pin them so no
-            # resharding ever lands between updates
-            shardings = (
-                sharding,
-                NamedSharding(sharding.mesh, PartitionSpec(sharding.spec[0])),
-            )
+        shardings = self._state_shardings()
         program = re_coordinate_update_program(
             self.task,
             self.configuration.optimizer_config,
@@ -1280,7 +1311,7 @@ class RandomEffectCoordinate(Coordinate):
         always lowers exactly the program training dispatches."""
         ds = self.dataset
         st = self._fused_update_static()
-        program, dtype, rows, sharding, shardings = self._resolve_update_program()
+        program, dtype, rows, sharding, _ = self._resolve_update_program()
         K_all = ds.max_k
         variance_on = (
             VarianceComputationType(self.variance_computation)
@@ -1288,15 +1319,11 @@ class RandomEffectCoordinate(Coordinate):
         )
         coeffs = jnp.zeros((rows, K_all), dtype=dtype)
         var = jnp.zeros((rows, K_all), dtype=dtype) if variance_on else None
-        score = jnp.zeros(
-            int(ds.sample_entity_rows.shape[0]), dtype=st["dtype"]
-        )
-        if shardings is not None:
-            table_sharding, score_sharding = shardings
-            coeffs = jax.device_put(coeffs, table_sharding)
+        score = self.zero_model_score()
+        if sharding is not None:
+            coeffs = jax.device_put(coeffs, sharding)
             if var is not None:
-                var = jax.device_put(var, table_sharding)
-            score = jax.device_put(score, score_sharding)
+                var = jax.device_put(var, sharding)
         lowered = program.lower(
             coeffs,
             score,
@@ -1319,10 +1346,11 @@ class RandomEffectCoordinate(Coordinate):
         ds = self.dataset
         coeffs = np.asarray(model.coeffs)
         if not coeffs.any():
-            # an all-zero table scores zero everywhere (the descent loop's
-            # initial score) — bitwise-equal to the full-table kernel,
-            # without streaming a pass
-            return jnp.zeros((ds.n_samples,), dtype=ds.sample_vals.dtype)
+            # a warm table that happens to be all zero scores zero
+            # everywhere — bitwise-equal to the full-table kernel, without
+            # streaming a pass (a fresh fit never gets here: the descent
+            # loop asks zero_model_score)
+            return self.zero_model_score()
         return ws.score_streamed(
             re_chunk_score_program(),
             coeffs,

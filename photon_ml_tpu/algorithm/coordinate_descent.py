@@ -398,9 +398,20 @@ def run_coordinate_descent(
                 init = coord.prepare_initial_model(init)
             model = init if init is not None else coord.initialize_model()
             models[cid] = model
-            # scoring the INITIAL model: of a fresh fit, the zero model
-            with span("descent.init_score", cid=cid, kind=_model_kind(model)):
-                train_scores[cid] = coord.score(model)
+            # the INITIAL model's training score. Of a fresh fit that is the
+            # zero model, and a coordinate that vouches for it answers without
+            # the scoring kernel (duck-typed coordinates may predate the
+            # method). Never inferred here: a locked coordinate's initial
+            # model is its trained one, a given or restored one is warm.
+            zero_model_score = (
+                getattr(coord, "zero_model_score", None) if init is None else None
+            )
+            answered = zero_model_score() if zero_model_score is not None else None
+            with span(
+                "descent.init_score", cid=cid, kind=_model_kind(model),
+                scored=answered is None,
+            ):
+                train_scores[cid] = coord.score(model) if answered is None else answered
                 if validate:
                     val_scores[cid] = score_model_on_dataset(model, validation_datasets[cid])
 

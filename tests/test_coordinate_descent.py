@@ -202,6 +202,135 @@ def test_residual_trick_consistency(rng):
     np.testing.assert_allclose(np.asarray(total), np.asarray(recomputed), rtol=1e-6)
 
 
+# ------------------------------------------------- the initial training score
+
+
+class _ScoreSpy:
+    """Duck-typed coordinate: everything is the wrapped coordinate's. Counts
+    the ``score`` calls and the ``zero_model_score`` calls that were answered
+    (not None); with ``hide`` it has no ``zero_model_score`` at all, like a
+    coordinate that predates the method, so the loop has to score."""
+
+    def __init__(self, inner, hide):
+        self._inner = inner
+        self._hide = hide
+        self.scored = 0
+        self.answered = 0
+
+    def __getattr__(self, attr):
+        if attr != "zero_model_score":
+            return getattr(self._inner, attr)
+        if self._hide:
+            raise AttributeError(attr)
+        return self._zero_model_score
+
+    def score(self, model):
+        self.scored += 1
+        return self._inner.score(model)
+
+    def _zero_model_score(self):
+        out = self._inner.zero_model_score()
+        self.answered += out is not None
+        return out
+
+
+def _table(model):
+    return np.asarray(model.coeffs if hasattr(model, "coeffs") else model.model.coefficients.means)
+
+
+def _bits(a):
+    a = np.asarray(a)
+    return a.dtype, a.shape, a.tobytes()  # +0.0 and -0.0 differ here
+
+
+def _descent_bits(result):
+    out = {"history": result.metrics_history, "best": result.best_metric}
+    for cid, model in result.model.models.items():
+        out[f"{cid}.model"] = _bits(_table(model))
+        out[f"{cid}.score"] = _bits(result.training_scores[cid])
+        out[f"{cid}.iterations"] = [
+            (t.iterations_mean, t.iterations_max) if hasattr(t, "iterations_max") else t.iterations
+            for t in result.trackers[cid]
+        ]
+    return out
+
+
+# scenario -> the coordinates whose initial training score the coordinate may
+# answer itself: the random effect of a fresh fit and nothing else
+INITIAL_SCORE_SCENARIOS = {
+    "fresh": {"per-user"},
+    "warm": set(),
+    "resumed": set(),
+    "locked-fe": {"per-user"},
+    "locked-re": set(),
+}
+
+
+@pytest.mark.parametrize("scenario", sorted(INITIAL_SCORE_SCENARIOS))
+def test_initial_score_answered_only_for_a_fresh_zero_model(rng, tmp_path, scenario):
+    """A fresh fit's random effect answers its initial score without the
+    scoring kernel, and the run is bit for bit the run that scored it; a warm
+    start, a checkpoint resume and a locked coordinate are scored as ever —
+    a non-zero model must never be answered with zeros."""
+    from photon_ml_tpu.io.checkpoint import CoordinateDescentCheckpointer
+
+    X, X_re, users, y = glmix_data(rng, n=400)
+    tr, va = slice(0, 300), slice(300, 400)
+    val_ds = {
+        "fixed": FixedEffectDataset(LabeledData.build(X[va], y[va]), feature_shard_id="global"),
+        "per-user": build_random_effect_dataset(
+            X_re[va], users[va], "userId", feature_shard_id="per-user", scoring_only=True
+        ),
+    }
+    suite = EvaluationSuite(
+        evaluators=[evaluator_for_type(EvaluatorType.AUC)],
+        labels=y[va], offsets=np.zeros(100), weights=np.ones(100),
+    )
+
+    def build():
+        return build_coordinates(X[tr], X_re[tr], users[tr], y[tr])
+
+    def descend(coords, n_iterations, **kwargs):
+        return run_coordinate_descent(
+            coords, n_iterations=n_iterations,
+            validation_datasets=val_ds, evaluation_suite=suite, **kwargs,
+        )
+
+    trained = descend(build()[0], 1).model.models
+    assert all(np.abs(_table(m)).max() > 1e-3 for m in trained.values())
+
+    def run(hide):
+        coords, fe_ds, re_ds = build()
+        kwargs = {}
+        if scenario == "warm":
+            kwargs["initial_models"] = trained
+        elif scenario == "resumed":
+            directory = str(tmp_path / f"ckpt-{hide}")
+            descend(build()[0], 1, checkpointer=CoordinateDescentCheckpointer(directory, dtype=None))
+            kwargs["checkpointer"] = CoordinateDescentCheckpointer(directory, dtype=None)
+        elif scenario == "locked-fe":
+            coords["fixed"] = ModelCoordinate("fixed", fe_ds, trained["fixed"])
+        elif scenario == "locked-re":
+            coords["per-user"] = ModelCoordinate("per-user", re_ds, trained["per-user"])
+        spies = {cid: _ScoreSpy(coord, hide) for cid, coord in coords.items()}
+        return descend(spies, 3, **kwargs), spies
+
+    answered, spies = run(hide=False)
+    scored, forced = run(hide=True)
+    assert _descent_bits(answered) == _descent_bits(scored)
+    assert len(answered.metrics_history) > 0
+
+    expected = INITIAL_SCORE_SCENARIOS[scenario]
+    for cid in spies:
+        assert forced[cid].answered == 0
+        assert forced[cid].scored >= 1, cid
+        assert spies[cid].answered == (cid in expected), cid
+        assert forced[cid].scored - spies[cid].scored == (cid in expected), cid
+    locked = {"locked-fe": "fixed", "locked-re": "per-user"}.get(scenario)
+    if locked is not None:
+        assert np.abs(np.asarray(answered.training_scores[locked])).max() > 1e-3
+
+
 # ------------------------------------------------------------- down-sampling
 
 

@@ -217,6 +217,24 @@ def test_update_spans_carry_coordinate_kind_and_iteration(fit):
     assert [r.attrs["cid"] for r in _spans(fit, "descent.init_score")] == list(COORDINATES)
 
 
+@pytest.mark.parametrize("start", ["fresh", "warm"])
+def test_init_score_spans_say_whether_the_kernel_scored(fit, start):
+    """A fresh fit's random effects answer their initial score themselves
+    (``scored`` False); the fixed effect, and every coordinate of a warm fit,
+    is scored."""
+    spans = _spans(fit, "descent.init_score")
+    want = {"fixed": True, "per-user": False, "per-item": False}
+    if start == "warm":
+        t0 = time.time_ns()
+        fit["est"].fit(
+            fit["train"], validation_data=fit["val"], initial_model=fit["results"][0].model
+        )
+        spans = records(since_ns=t0, name="descent.init_score")
+        want = dict.fromkeys(COORDINATES, True)
+    assert {r.attrs["cid"]: r.attrs["scored"] for r in spans} == want
+    assert len(spans) == len(COORDINATES)
+
+
 @pytest.mark.parametrize("child", sorted(PARENT))
 def test_children_lie_inside_their_parents(fit, child):
     parents = _spans(fit, PARENT[child])

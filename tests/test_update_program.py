@@ -72,6 +72,7 @@ def build_coords(
     normalization=None,
     per_entity=None,
     variance=VarianceComputationType.NONE,
+    precision=None,
 ):
     X, X_re, users, y, norm = workload
     fe_ds = FixedEffectDataset(LabeledData.build(X, y), feature_shard_id="global")
@@ -94,6 +95,7 @@ def build_coords(
             variance_computation=variance,
             per_entity_reg_weights=per_entity,
             use_update_program=use_program,
+            precision=precision,
         ),
     }
 
@@ -912,6 +914,55 @@ def test_mesh_zero_retraces_across_descent_iterations(rng, eight_devices):
     assert np.isfinite(
         np.asarray(result.model.get_model("per-user").coeffs)
     ).all()
+
+
+def assert_same_array(got, want):
+    """Shape, dtype, placement and bits (+0.0 and -0.0 differ)."""
+    assert (got.shape, got.dtype) == (want.shape, want.dtype)
+    assert got.sharding == want.sharding
+    assert got.committed == want.committed
+    assert np.asarray(got).tobytes() == np.asarray(want).tobytes()
+
+
+@pytest.mark.parametrize("precision", [None, "bf16"], ids=["f32", "bf16"])
+@pytest.mark.parametrize("placement", ["one-device", "mesh"])
+def test_zero_model_score_is_the_kernels_score_of_the_initial_model(
+    rng, eight_devices, placement, precision
+):
+    """``zero_model_score()`` is ``score(initialize_model())`` in shape,
+    dtype, sharding and bits without the kernel, a new array every call, and
+    the first ``update_and_score`` after it runs the program it runs after a
+    kernel-made score: no second cached program, no second compile."""
+    from photon_ml_tpu.optimization.solver_cache import re_coordinate_update_program
+
+    workload = make_workload(rng)
+    if placement == "mesh":
+        coord, _, _ = build_mesh_coord(workload, precision=precision)
+    else:
+        coord = build_coords(workload, use_program=True, precision=precision)["per-user"]
+    model = coord.initialize_model()
+    kernel_made = coord.score(model)
+    answered = coord.zero_model_score()
+    assert_same_array(answered, kernel_made)
+    assert coord.zero_model_score() is not answered
+    if placement == "mesh":
+        assert answered.sharding == coord._resolve_update_program()[4][1]
+
+    partial = jnp.zeros_like(coord.base_offsets)  # keeps the mesh placement
+    m1, s1, _ = coord.update_and_score(model, partial, kernel_made)
+    program = coord._resolve_update_program()[0]
+    programs = re_coordinate_update_program.cache_info().currsize
+    compiled = program._cache_size()
+    with no_retrace(what="the first update after zero_model_score"):
+        m2, s2, _ = coord.update_and_score(model, partial, coord.zero_model_score())
+    assert re_coordinate_update_program.cache_info().currsize == programs
+    assert program._cache_size() == compiled  # other tests share the cached program
+    assert_same_array(s2, s1)
+    assert_same_array(m2.coeffs, m1.coeffs)
+    if precision is not None:
+        assert m2.coeffs.dtype == jnp.bfloat16 != answered.dtype
+    # update_and_score copies a score it does not own: the caller's survives
+    assert not kernel_made.is_deleted() and not answered.is_deleted()
 
 
 def test_per_bucket_fallback_logs_structured_reason_once(rng, caplog):

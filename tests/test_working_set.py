@@ -387,6 +387,28 @@ def test_streamed_foreign_warm_start_and_score(rng):
     assert np.isfinite(np.asarray(warm_model.coeffs)).all()
 
 
+def test_streamed_zero_model_score_is_the_score_of_the_initial_model(rng):
+    """The working-set coordinate answers its initial score like the
+    all-resident one: the host-tier zero table is never read, and the array
+    is what ``score(initialize_model())`` hands out, bit for bit."""
+    workload = make_skewed_workload(rng)
+    streamed = build_coordinate(workload, 17)
+    resident = build_coordinate(workload, None)
+    model = streamed.initialize_model()
+    assert streamed._working_set() is not None and isinstance(model.coeffs, np.ndarray)
+    answered = streamed.zero_model_score()
+    for want in (streamed.score(model), resident.score(resident.initialize_model())):
+        assert (answered.shape, answered.dtype) == (want.shape, want.dtype)
+        assert answered.sharding == want.sharding
+        assert np.asarray(answered).tobytes() == np.asarray(want).tobytes()
+    assert streamed.zero_model_score() is not answered
+    # the first streamed pass from it == from the scored one
+    s_state = state_of(*run_passes(streamed, 1, model=model, score=answered))
+    r_state = state_of(*run_passes(build_coordinate(workload, 17), 1))
+    np.testing.assert_array_equal(s_state["coeffs"], r_state["coeffs"])
+    np.testing.assert_array_equal(s_state["score"], r_state["score"])
+
+
 # ----------------------------------------------------------- logged demotions
 
 
