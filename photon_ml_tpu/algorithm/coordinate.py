@@ -924,11 +924,13 @@ class RandomEffectCoordinate(Coordinate):
     def _fused_update_static(self):
         """Descent-iteration-invariant inputs of the update program, built
         once per coordinate: validations, the per-entity L2 table, the
-        per-bucket normalization gathers, the bucket tuple and scoring view."""
+        per-bucket normalization gathers, the bucket tuple, the scoring view
+        and the slot index (None where the program scores through the view)."""
         if self._fused_static is None:
             from photon_ml_tpu.algorithm.random_effect import (
                 build_l2_rows,
                 precompute_norm_tables,
+                update_program_data,
             )
             from photon_ml_tpu.function.losses import loss_for_task
             from photon_ml_tpu.types import OptimizerType
@@ -939,21 +941,9 @@ class RandomEffectCoordinate(Coordinate):
             if opt_type in (OptimizerType.TRON, OptimizerType.NEWTON) and not loss.has_hessian:
                 raise ValueError(f"{opt_type.value} requires a twice-differentiable loss")
             dtype = ds.sample_vals.dtype
-            buckets = tuple(ds.buckets)
-            view = (ds.sample_entity_rows, ds.sample_local_cols, ds.sample_vals)
-            if not self.precision.is_reference:
-                # FEATURE storage at the reduced dtype: the update program
-                # reads these arrays (bucket blocks + the scoring view's
-                # values) every iteration — storage-width bytes are the HBM
-                # traffic the policy halves. Cast once per coordinate; solves
-                # and scores upcast in-register (solver_cache). On a mesh the
-                # casts keep the placed arrays' shardings (computation
-                # follows data) — storage width and placement are orthogonal.
-                buckets = tuple(
-                    dataclasses.replace(b, X=self.precision.to_storage(b.X))
-                    for b in buckets
-                )
-                view = (view[0], view[1], self.precision.to_storage(view[2]))
+            buckets, view, sample_slots = update_program_data(
+                ds, self.precision, self.normalization
+            )
             sharding = getattr(ds, "coeffs_sharding", None)
             table_rows = getattr(ds, "coeffs_rows", None) or ds.n_entities
             l2_rows = build_l2_rows(
@@ -1009,11 +999,33 @@ class RandomEffectCoordinate(Coordinate):
                 norm_tables=norm_tables,
                 buckets=buckets,
                 view=view,
+                sample_slots=sample_slots,
                 tracker_masks=tracker_masks,
                 # padded rows per lane of each bucket: the tracker's lane waste
                 lane_rows=tuple(b.shape[0] for b in buckets),
             )
         return self._fused_static
+
+    @property
+    def score_path(self) -> str:
+        """How ``update_and_score`` computes the ``[N]`` training score, the
+        ``score_path`` attribute of this coordinate's ``descent.update``
+        spans: ``"bucket"`` where the single program scores from the blocks
+        it solved, through the dataset's ``sample_slots``; ``"view"`` where
+        it (or the streamed chunks, or the per-bucket loop's ``score``) goes
+        through ``random_effect_view_score``. Read from the dataset and the
+        policy by the rule the program's inputs are built with
+        (``bucket_score_slots``); it builds none of them, so the descent loop
+        may ask before the update's span opens."""
+        from photon_ml_tpu.algorithm.random_effect import bucket_score_slots
+
+        if (
+            not self.use_update_program
+            or bucket_score_slots(self.dataset, self.precision, self.normalization) is None
+            or self._working_set() is not None
+        ):
+            return "view"
+        return "bucket"
 
     def _state_shardings(self):
         """``(table, score)`` shardings of the state a mesh-placed dataset's
@@ -1175,6 +1187,7 @@ class RandomEffectCoordinate(Coordinate):
             st["buckets"],
             st["norm_tables"],
             st["view"],
+            st["sample_slots"],
         )
         self._owned = {"coeffs": coeffs_out, "score": score_out, "var": var_out}
         model = RandomEffectModel(
@@ -1200,10 +1213,14 @@ class RandomEffectCoordinate(Coordinate):
         """Streamed working-set update: the host tier stays authoritative,
         the device never holds more table rows than the configured budget,
         and every chunk runs through ``re_chunk_update_program`` — the same
-        vmapped bucket solve and view-score kernel as the all-resident
-        program, so lbfgs-family results are bitwise identical
+        vmapped bucket solve as the all-resident program, so lbfgs-family
+        coefficients and variances are bitwise identical
         (tests/test_working_set.py; the direct solver's Gram accumulation is
-        batch-shape-sensitive at the last ulp and is tolerance-gated).
+        batch-shape-sensitive at the last ulp and is tolerance-gated). The
+        chunks score through the view kernel; the all-resident program scores
+        from its bucket blocks where ``bucket_score_slots`` gives it the
+        dataset's ``sample_slots`` (raw float32 blocks), bitwise the same
+        score there (gated), and through the view kernel otherwise.
 
         The fused protocol is preserved: a divergence reject returns the
         PREVIOUS model/score (the staged host commit is discarded) and the
@@ -1309,6 +1326,12 @@ class RandomEffectCoordinate(Coordinate):
         collectives around them. Program resolution shares ONE owner with
         ``update_and_score`` (``_resolve_update_program``), so this audit
         always lowers exactly the program training dispatches."""
+        return self.lowered_update_program().compile().as_text()
+
+    def lowered_update_program(self):
+        """The update program lowered (not compiled) at a fresh fit's
+        arguments: ``.as_text()`` is the program as traced, before any
+        backend rewrites it (tests count its gathers by result shape)."""
         ds = self.dataset
         st = self._fused_update_static()
         program, dtype, rows, sharding, _ = self._resolve_update_program()
@@ -1324,7 +1347,7 @@ class RandomEffectCoordinate(Coordinate):
             coeffs = jax.device_put(coeffs, sharding)
             if var is not None:
                 var = jax.device_put(var, sharding)
-        lowered = program.lower(
+        return program.lower(
             coeffs,
             score,
             var,
@@ -1334,8 +1357,8 @@ class RandomEffectCoordinate(Coordinate):
             st["buckets"],
             st["norm_tables"],
             st["view"],
+            st["sample_slots"],
         )
-        return lowered.compile().as_text()
 
     def score(self, model: RandomEffectModel) -> Array:
         ws = self._working_set()

@@ -52,6 +52,16 @@ def _model_kind(model) -> str:
     return "re" if isinstance(model, RandomEffectModel) else type(model).__name__
 
 
+def _score_path(coord, fused_path: bool) -> dict:
+    """The ``score_path`` attribute of a ``descent.update`` span, for the
+    coordinates that name one (random effects: ``bucket`` | ``view``). Off the
+    fused protocol the loop scores the model itself, through the view."""
+    path = getattr(coord, "score_path", None)
+    if path is None:
+        return {}
+    return {"score_path": path if fused_path else "view"}
+
+
 def _device_guard(model, tracker) -> tuple:
     """The divergence guard's inputs as DEVICE scalars — no host sync here.
 
@@ -447,29 +457,34 @@ def run_coordinate_descent(
         # Recompute (not accumulate) the total at each iteration boundary: the
         # state is then a pure function of the models dict, which makes a
         # checkpoint-resumed run BIT-identical to an uninterrupted one (resume
-        # restores models and recomputes scores the same way).
+        # restores models and recomputes scores the same way). The other half
+        # of that promise is the coordinates': a fused update must hand back
+        # the bits its ``score(model)`` gives (the random-effect program
+        # scores from its bucket blocks only where it does:
+        # algorithm/random_effect.bucket_score_slots).
         full_train_score = sum(train_scores.values())
         pending: list[_PendingGuard] = []
         for cid in updatable:
             coord = coordinates[cid]
             faultpoint(f"{FP_COORD_UPDATE}.{cid}")
             prev_model = models[cid]
+            active = None if active_sets is None else active_sets.get(cid)
+            # duck-typed coordinates (test wrappers, external impls) may
+            # predate the fused protocol — treat a missing method as "no
+            # fused path". Active-set updates always take the generic path:
+            # the delta program gathers/scatters host-chosen lane sets, which
+            # the donated fused program cannot express.
+            update_and_score = (
+                getattr(coord, "update_and_score", None) if active is None else None
+            )
             with span(
-                "descent.update", cid=cid, kind=_model_kind(prev_model), iteration=iteration
+                "descent.update", cid=cid, kind=_model_kind(prev_model), iteration=iteration,
+                **_score_path(coord, update_and_score is not None),
             ) as update_span:
                 # Residual trick (CoordinateDescent.scala:197-204)
                 partial = full_train_score - train_scores[cid]
                 prev_score = train_scores[cid]
                 prev_had_var = _has_variances(prev_model)
-                active = None if active_sets is None else active_sets.get(cid)
-                # duck-typed coordinates (test wrappers, external impls) may
-                # predate the fused protocol — treat a missing method as "no
-                # fused path". Active-set updates always take the generic path:
-                # the delta program gathers/scatters host-chosen lane sets, which
-                # the donated fused program cannot express.
-                update_and_score = (
-                    getattr(coord, "update_and_score", None) if active is None else None
-                )
                 fused = (
                     update_and_score(prev_model, partial, prev_score, donate=cid in donating)
                     if update_and_score is not None

@@ -22,7 +22,7 @@ from __future__ import annotations
 
 import dataclasses
 import functools
-from typing import Optional, Sequence
+from typing import NamedTuple, Optional, Sequence
 
 import jax
 import jax.numpy as jnp
@@ -293,6 +293,74 @@ def precompute_norm_tables(
         proj_b = dataset.proj_indices[bucket.entity_rows, :K]
         out.append(_gather_norm_vectors(normalization, proj_b, dtype))
     return tuple(out)
+
+
+def bucket_score_slots(
+    dataset: RandomEffectDataset,
+    precision,
+    normalization: Optional[NormalizationContext],
+) -> Optional[Array]:
+    """The dataset's ``sample_slots`` where an all-resident update program
+    may score from the bucket blocks it has just solved, else None (it scores
+    through the view). THE rule, for the coordinate, its ``score_path`` and
+    the population trainer. The bucket score is taken only where it is the
+    score a resumed or warm-started fit recomputes from the stored table
+    (``coord.score(model)``: ``random_effect_view_score`` in original space),
+    so that resume stays bit-identical:
+
+    - a reduced ``precision`` stores the ROUNDED table, and the bucket score
+      would use the solve's unrounded coefficients: view;
+    - a NORMALIZED coordinate's blocks hold ``x' = (x - shift) * factor``, so
+      their score ``w' . x'`` is the same margin rounded elsewhere (ulps apart
+      from the original-space ``w . x``): view;
+    - a dataset without slots (passive rows, mesh placement, scoring-only)
+      has no complete way back from its blocks: view.
+
+    What is left is raw float32 blocks: the same eight products a sample as
+    the view kernel's, in two differently fused programs. Their bit-equality
+    is OBSERVED (XLA:CPU, and the v5e at the benchmark cell's shapes), not
+    built in; the resume and cross-path gates hold it
+    (tests/test_bucket_score.py, test_working_set.py, test_update_program.py)."""
+    if not precision.is_reference:
+        return None
+    if normalization is not None and not normalization.is_identity:
+        return None
+    return dataset.sample_slots
+
+
+class UpdateProgramData(NamedTuple):
+    """The dataset's arrays as the update programs read them."""
+
+    buckets: tuple
+    view: tuple  # (entity_rows [N], local_cols [N, nnz], vals [N, nnz])
+    sample_slots: Optional[Array]  # [N] int32, or None: score through ``view``
+
+
+def update_program_data(
+    dataset: RandomEffectDataset,
+    precision,
+    normalization: Optional[NormalizationContext],
+) -> UpdateProgramData:
+    """Buckets, scoring view and (``bucket_score_slots``) slot index for the
+    update programs (``solver_cache._re_coordinate_update_fn``), built by
+    ONE function for the coordinate and the population trainer, so a sweep
+    lane and a single fit of the same setting keep one arithmetic.
+
+    Under a reduced policy the feature arrays the programs read every solver
+    iteration (bucket blocks, the view's values) come back cast ONCE to the
+    storage dtype: storage-width bytes are the HBM traffic the policy
+    halves, and solves and scores upcast in-register. A cast keeps a placed
+    array's sharding (computation follows data)."""
+    buckets = tuple(dataset.buckets)
+    view = dataset.scoring_view()
+    if not precision.is_reference:
+        buckets = tuple(
+            dataclasses.replace(b, X=precision.to_storage(b.X)) for b in buckets
+        )
+        view = (view[0], view[1], precision.to_storage(view[2]))
+    return UpdateProgramData(
+        buckets, view, bucket_score_slots(dataset, precision, normalization)
+    )
 
 
 def build_l2_rows(

@@ -64,8 +64,11 @@ class EntityBucket:
     """One padded block of entities with similar shapes.
 
     X is [E, S, K] in each entity's local (projected) space; sample_ids are global
-    sample-axis positions (-1 padding) used to gather offsets/partial scores and to
-    scatter this coordinate's scores back.
+    sample-axis positions (-1 padding) used to gather offsets/partial scores into
+    the block. The way back, from the block slots to the sample axis, is the
+    dataset's ``sample_slots`` (the inverse of every bucket's ``sample_ids``): the
+    single-program update gathers its ``[N]`` score through it; the streamed
+    working-set chunks scatter theirs by ``sample_ids``.
     """
 
     entity_rows: Array  # [E] int32 — row into the dataset-wide entity table
@@ -114,6 +117,17 @@ class RandomEffectDataset:
     # share of the padded bucket rows that hold no sample: 1 - active samples /
     # sum over buckets of entities x padded rows per entity (0 without buckets)
     padding_waste: float = 0.0
+    # [N] int32, the inverse of the buckets' sample_ids: each sample's position
+    # in the concatenation of the bucket blocks, in bucket order, row-major over
+    # [E_b, S_b] (base_b + e * S_b + s); a sample that sits in no bucket (its
+    # entity trains no model) points at ONE appended zero slot, index
+    # sum_b E_b * S_b. The update program scores from its bucket blocks through
+    # it (solver_cache._re_coordinate_update_fn) where
+    # algorithm/random_effect.bucket_score_slots hands it over. None where the
+    # index would be wrong or incomplete — scoring-only datasets, passive rows
+    # (scored, but in no bucket), mesh placement (padded entity axes) — and
+    # the view scores.
+    sample_slots: Optional[Array] = None
 
     @property
     def n_entities(self) -> int:
@@ -507,6 +521,10 @@ def build_random_effect_dataset(
         nnz_bounds = np.searchsorted(
             np.where(nnz_valid_local, nnz_bucket, -1)[nnz_order], np.arange(n_buckets + 1)
         )
+        # sample_slots (see RandomEffectDataset): filled bucket by bucket below,
+        # samples of no bucket then sent to the zero slot one past the blocks
+        slots = np.full(n, -1, dtype=np.int64)
+        slot_base = 0
         for b, ((s_pad, k_pad), members) in enumerate(sorted_keys):
             eb = len(members)
             Xb = np.zeros((eb, s_pad, k_pad), dtype=np.float64)
@@ -520,6 +538,8 @@ def build_random_effect_dataset(
                 yb[el_s, sl_s] = labels_arr[rows_s]
             wb[el_s, sl_s] = base_weights[rows_s] * scale_arr[ent_row_per_act[ai]]
             sb[el_s, sl_s] = rows_s
+            slots[rows_s] = slot_base + el_s * s_pad + sl_s
+            slot_base += eb * s_pad
             # nnz-level X fill (duplicate (row, col) entries sum, as toarray does;
             # bincount over raveled indices = vectorized scatter-add)
             ni = nnz_order[nnz_bounds[b] : nnz_bounds[b + 1]]
@@ -545,6 +565,10 @@ def build_random_effect_dataset(
                 row_valid = np.arange(s_pad)[None, :] < lens[members][:, None]
                 Xb += base[:, None, :] * row_valid[:, :, None]
             host_buckets.append((members.astype(np.int32), Xb, yb, wb, sb))
+        if scoring_only or passive_count or slot_base >= np.iinfo(np.int32).max:
+            slots = None
+        else:
+            slots = np.where(slots >= 0, slots, slot_base).astype(np.int32)
 
     n_active = sum(len(active_rows[e]) for e in entities)
     padded_rows = sum(Xb.shape[0] * Xb.shape[1] for _rows, Xb, *_rest in host_buckets)
@@ -567,6 +591,7 @@ def build_random_effect_dataset(
             jnp.asarray(s_ent_rows),
             jnp.asarray(s_cols),
             jnp.asarray(s_vals, dtype=dtype),
+            None if slots is None else jnp.asarray(slots),
         )
         jax.block_until_ready((buckets, placed))
     return RandomEffectDataset(
@@ -583,6 +608,7 @@ def build_random_effect_dataset(
         n_passive_samples=passive_count,
         projector=projector,
         padding_waste=1.0 - n_active / padded_rows if padded_rows else 0.0,
+        sample_slots=placed[4],
     )
 
 

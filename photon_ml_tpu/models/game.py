@@ -36,10 +36,19 @@ def random_effect_view_score(
     sum_k coeffs[entity_rows[i], local_cols[i, k]] * vals[i, k], with -1
     entity rows (no model) and -1 column slots (padding / columns the model
     never saw) contributing exactly 0. ONE shared implementation for the
-    eager ``RandomEffectModel.score_dataset``, the fused serving engine
-    (serving/engine.py) and the single-program coordinate update
-    (solver_cache.re_coordinate_update_program), so every path executes
-    identical jnp ops and stays numerically interchangeable.
+    eager ``RandomEffectModel.score_dataset`` (validation scores, the initial
+    score of a warm-started or resumed fit), the fused serving engine
+    (serving/engine.py), the streamed working-set chunk programs and the
+    update programs' view path (solver_cache: datasets without
+    ``sample_slots`` — passive rows, mesh placement —, normalization and
+    reduced precision), so every one of them executes identical jnp ops and
+    stays numerically interchangeable. On raw float32 blocks the all-resident
+    update program no longer calls it: it scores from the bucket blocks it
+    has just solved (``solver_cache._re_coordinate_update_fn``, scopes
+    ``re.bucket_score`` / ``re.score_gather``; the rule is
+    ``algorithm/random_effect.bucket_score_slots``) without the ``[N, K]``
+    intermediate below, and is held to this kernel's bits
+    (tests/test_bucket_score.py).
 
     Jitted at module level ON PURPOSE: XLA contracts the multiply into the
     reduction (FMA) when this subgraph sits inside one fusion, so an

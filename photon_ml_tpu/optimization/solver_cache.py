@@ -271,11 +271,27 @@ def _re_coordinate_update_fn(
     it) — one body, so the two programs stay semantically interchangeable
     per lane.
 
+    ``sample_slots`` (the last argument of the returned body, default None)
+    says what the ``[N]`` training score is computed from, a trace-time fact
+    of the call that ``algorithm/random_effect.bucket_score_slots`` decides
+    for the coordinate and the population trainer. None: the body calls
+    ``random_effect_view_score(table, *view)`` on the updated table, whose
+    ``[N, K]`` intermediate was two thirds of the update on a v5e (PERF.md).
+    The dataset's ``[N]`` slot index: the body scores each bucket right after
+    its solve, ``sum_k X_b[e, s, k] * w_b[e, k]`` (scope ``re.bucket_score``:
+    contiguous reads of the block the solve has just streamed, a broadcast
+    where the view gathers table rows, no column gather), and brings the
+    block slots back to the sample axis with ONE gather of ``N`` scalars
+    (scope ``re.score_gather``); ``view`` is then not read. Slots are given
+    only for raw float32 blocks, where the bucket score is the view score of
+    the returned table (what a resume recomputes); the body refuses them
+    under a reduced policy or with normalization tables.
+
     ``precision`` (optimization/precision.py) splits STORAGE from
     ACCUMULATION dtypes: under a reduced policy the donated coefficient/
     variance tables and the bucket/view feature arrays live in bf16/f16 HBM
     (the caller supplies them pre-cast — see
-    ``RandomEffectCoordinate._fused_update_static``) while every solve,
+    ``algorithm/random_effect.update_program_data``) while every solve,
     normalization conversion and score upcasts to f32 in-register (XLA fuses
     the converts into the consuming gathers/contractions, so only
     storage-width bytes cross HBM). The reference f32 policy makes every
@@ -311,13 +327,24 @@ def _re_coordinate_update_fn(
 
     def update_core(
         coeffs_prev, score_prev, var_prev, offsets_plus_scores, l2_rows, l1,
-        buckets, norm_tables, view, active=None,
+        buckets, norm_tables, view, sample_slots=None, active=None,
     ):
         from photon_ml_tpu.algorithm.random_effect import _to_original, _to_transformed
         from photon_ml_tpu.models.game import random_effect_view_score
 
         coeffs = coeffs_prev
         variances = var_prev
+        if sample_slots is not None and (
+            reduced or any(tbl is not None for tbl in norm_tables)
+        ):
+            # algorithm/random_effect.bucket_score_slots: either would score
+            # something else than the stored table's view score
+            raise ValueError(
+                "sample_slots: the bucket score is for raw float32 blocks; "
+                "reduced precision and normalized coordinates score the "
+                "stored table through the view"
+            )
+        bucket_scores = []
         # the dtype every solve runs at: the table dtype itself on the
         # reference path (bitwise status quo), f32 under a reduced policy
         solve_dtype = precision.accum_dtype if reduced else coeffs.dtype
@@ -354,6 +381,14 @@ def _re_coordinate_update_fn(
                 solve_args = solve_args + (active,)
             with jax.named_scope("re.bucket_solve"):
                 w_b, reasons_b, iters_b, evals_b, var_b = solve_b(*solve_args)
+            if sample_slots is not None:
+                # the margin of the block's own rows. Multiply-and-sum, not
+                # a dot: at default precision a dot runs bfloat16 passes on
+                # the TPU's MXU.
+                with jax.named_scope("re.bucket_score"):
+                    bucket_scores.append(
+                        jnp.sum(bucket.X * w_b[:, None, :], axis=-1).reshape(-1)
+                    )
             if norm_tbl is not None:
                 w_b = _to_original(w_b, factors, shifts, icpt_mask)
                 if variances is not None and factors is not None:
@@ -376,8 +411,16 @@ def _re_coordinate_update_fn(
             coeffs = coeffs.at[n_entities:].set(0.0)
             if variances is not None:
                 variances = variances.at[n_entities:].set(0.0)
-        entity_rows, local_cols, vals = view
-        if reduced:
+        if sample_slots is not None:
+            # N scalars through the inverse of the buckets' sample_ids; a
+            # sample of no bucket reads the one zero slot past the blocks
+            with jax.named_scope("re.score_gather"):
+                zero_slot = jnp.zeros((1,), dtype=score_prev.dtype)
+                score = jnp.take(
+                    jnp.concatenate([*bucket_scores, zero_slot]), sample_slots
+                ).astype(score_prev.dtype)
+        elif reduced:
+            entity_rows, local_cols, vals = view
             # storage-width bytes cross HBM; the multiply-accumulate runs f32
             score = random_effect_view_score(
                 coeffs.astype(solve_dtype),
@@ -386,7 +429,7 @@ def _re_coordinate_update_fn(
                 vals.astype(solve_dtype),
             )
         else:
-            score = random_effect_view_score(coeffs, entity_rows, local_cols, vals)
+            score = random_effect_view_score(coeffs, *view)
         # Device-side divergence guard: variances are deliberately excluded
         # (algorithm/coordinate.coefficient_arrays — a singular-Hessian
         # variance failure must not discard a converged mean update).
@@ -408,22 +451,22 @@ def _re_coordinate_update_fn(
 
         def re_coordinate_update(
             coeffs_prev, score_prev, var_prev, offsets_plus_scores, l2_rows,
-            l1, active, buckets, norm_tables, view,
+            l1, active, buckets, norm_tables, view, sample_slots=None,
         ):
             return update_core(
                 coeffs_prev, score_prev, var_prev, offsets_plus_scores,
-                l2_rows, l1, buckets, norm_tables, view, active,
+                l2_rows, l1, buckets, norm_tables, view, sample_slots, active,
             )
 
         return re_coordinate_update
 
     def re_coordinate_update(
         coeffs_prev, score_prev, var_prev, offsets_plus_scores, l2_rows, l1,
-        buckets, norm_tables, view,
+        buckets, norm_tables, view, sample_slots=None,
     ):
         return update_core(
             coeffs_prev, score_prev, var_prev, offsets_plus_scores, l2_rows,
-            l1, buckets, norm_tables, view,
+            l1, buckets, norm_tables, view, sample_slots,
         )
 
     return re_coordinate_update
@@ -448,8 +491,8 @@ def re_coordinate_update_program(
     ``train_random_effect`` collapsed into one dispatch per update.
 
     ``update(coeffs_prev, score_prev, var_prev, offsets_plus_scores, l2_rows,
-    l1, buckets, norm_tables, view) -> (coeffs, score, variances, ok,
-    reasons_per_bucket, iters_per_bucket, evals_per_bucket)``
+    l1, buckets, norm_tables, view, sample_slots=None) -> (coeffs, score,
+    variances, ok, reasons_per_bucket, iters_per_bucket, evals_per_bucket)``
 
     - ``coeffs_prev`` ``[E, K_max]`` / ``score_prev`` ``[N]`` / ``var_prev``
       (``[E, K_max]`` or None) are DONATED: the hot loop stops copying the
@@ -463,8 +506,13 @@ def re_coordinate_update_program(
       intercept-mask) triple from ``precompute_norm_tables`` — gathered ONCE
       per (dataset, normalization), not per update per bucket.
     - ``view``: the dataset's per-sample scoring view (entity rows, local
-      cols, vals) — the score uses the same ``random_effect_view_score``
-      kernel as the eager path.
+      cols, vals) — without ``sample_slots`` the score uses the same
+      ``random_effect_view_score`` kernel as the eager path (mesh-placed
+      datasets, passive rows, normalization, reduced precision).
+    - ``sample_slots``: None, or the dataset's ``[N]`` slot index — the score
+      then comes from the bucket blocks just solved and one ``[N]`` gather
+      (``_re_coordinate_update_fn``; raw float32 blocks on one device) and
+      ``view`` is not read.
     - ``re_solver`` / ``precision``: the direct-solve and storage-precision
       levers (normal_equations.py / precision.py); the defaults reproduce
       the bitwise-gated status quo. ``re_solver`` also accepts a per-bucket
@@ -528,7 +576,8 @@ def re_chunk_update_program(
       the previous pass's output straight back in) and the score partial is
       threaded through the whole pass without a copy per chunk.
     - The score contribution routes the chunk's samples through the SAME
-      ``random_effect_view_score`` kernel as the all-resident path, with the
+      ``random_effect_view_score`` kernel as the full-table score (the
+      all-resident program's view path), with the
       chunk's lanes standing in as a C-row table — per-sample gather/
       multiply/add order is identical, so per-chunk scatter assembly is
       bitwise-equal to the full-table score. Padding lanes carry
@@ -661,11 +710,13 @@ def re_population_update_program(
 
     ``update(coeffs_prev [P,E,K], score_prev [P,N], var_prev ([P,E,K] or
     None), offsets_plus_scores [P,N], l2_rows [P,rows], l1 [P], buckets,
-    norm_tables, view) -> (coeffs [P,E,K], score [P,N], variances, ok [P],
-    reasons, iters)``
+    norm_tables, view, sample_slots=None) -> (coeffs [P,E,K], score [P,N],
+    variances, ok [P], reasons, iters)``
 
     The per-lane body is EXACTLY ``_re_coordinate_update_fn`` — bucket data,
-    normalization tables and the scoring view broadcast across the population
+    normalization tables, the scoring view and the slot index (given where
+    ``bucket_score_slots`` gives it to a single fit of the same setting)
+    broadcast across the population
     (read from HBM once per update for all P settings); coefficient tables,
     scores, regularization rows and the L1 weight carry the population axis.
     Population state is donated exactly like the single-model program. The
@@ -684,17 +735,13 @@ def re_population_update_program(
         task, opt_config, has_l1, variance, n_entities, re_solver, precision,
         with_active,
     )
-    in_axes = (
-        (0, 0, 0, 0, 0, 0, 0, None, None, None)
-        if with_active
-        else (0, 0, 0, 0, 0, 0, None, None, None)
-    )
-    population = jax.vmap(update, in_axes=in_axes)
+    lanes = (0,) * (7 if with_active else 6)
+    population = jax.vmap(update, in_axes=lanes + (None, None, None, None))
 
-    def re_population_update(*args):
+    def re_population_update(*args, sample_slots=None):
         # the sweep keeps no tracker: the per-lane evaluation counts are
         # dropped here (and die in XLA), the six outputs stay what they were
-        return population(*args)[:6]
+        return population(*args, sample_slots)[:6]
 
     return jax.jit(re_population_update, donate_argnums=(0, 1, 2))
 

@@ -120,6 +120,10 @@ def place_random_effect_dataset(ds: RandomEffectDataset, mesh) -> RandomEffectDa
         # table gets always-zero padding rows; row E (the bucket-padding target)
         # falls in this range and is re-zeroed after every update
         coeffs_rows=-(-max(E, 1) // m) * m,
+        # the buckets' entity axes were padded above: the ingest-time slot
+        # index no longer names these blocks' slots, so a placed dataset
+        # scores through its view
+        sample_slots=None,
     )
 
 

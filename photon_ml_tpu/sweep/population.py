@@ -60,7 +60,11 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
-from photon_ml_tpu.algorithm.random_effect import build_l2_rows, precompute_norm_tables
+from photon_ml_tpu.algorithm.random_effect import (
+    build_l2_rows,
+    precompute_norm_tables,
+    update_program_data,
+)
 from photon_ml_tpu.data.dataset import FixedEffectDataset
 from photon_ml_tpu.data.random_effect import RandomEffectDataset, _next_pow2
 from photon_ml_tpu.estimators.config import RandomEffectDataConfiguration
@@ -123,6 +127,10 @@ class _CoordStatic:
     buckets: Optional[tuple] = None
     norm_tables: Optional[tuple] = None
     view: Optional[tuple] = None
+    # the dataset's [N] slot index where the per-update program scores from
+    # its bucket blocks (algorithm/random_effect.bucket_score_slots), else
+    # None; the fused sweep always scores through ``view``
+    sample_slots: Optional[object] = None
     per_entity: Optional[object] = None  # None | [E] array | {entity_id: l2} dict
     # FE only
     down_sampling: bool = False
@@ -220,19 +228,9 @@ class PopulationTrainer:
                     )
                 norm = estimator._normalization_for(cfg.data_config.feature_shard_id)
                 norm = None if norm.is_identity or ds.projector is not None else norm
-                buckets = tuple(ds.buckets)
-                view = (ds.sample_entity_rows, ds.sample_local_cols, ds.sample_vals)
-                if not self.precision.is_reference:
-                    # feature storage at the reduced dtype, cast once per
-                    # trainer (the update bodies read these arrays every
-                    # solver iteration — storage-width bytes are the HBM
-                    # traffic the policy halves; solves and scores upcast
-                    # in-register, solver_cache)
-                    buckets = tuple(
-                        dataclasses.replace(b, X=self.precision.to_storage(b.X))
-                        for b in buckets
-                    )
-                    view = (view[0], view[1], self.precision.to_storage(view[2]))
+                buckets, view, sample_slots = update_program_data(
+                    ds, self.precision, norm
+                )
                 self._static[cid] = _CoordStatic(
                     cid=cid,
                     kind="re",
@@ -243,6 +241,7 @@ class PopulationTrainer:
                     buckets=buckets,
                     norm_tables=precompute_norm_tables(ds, norm, self.dtype),
                     view=view,
+                    sample_slots=sample_slots,
                     per_entity=cfg.per_entity_reg_weights,
                 )
             else:
@@ -437,6 +436,7 @@ class PopulationTrainer:
                 st.buckets,
                 st.norm_tables,
                 st.view,
+                sample_slots=st.sample_slots,
             )
             lane_iters = functools.reduce(
                 operator.add,
