@@ -1,11 +1,10 @@
 """Host-loop benchmark: featureful coordinate-descent pass throughput.
 
 Metric: ``glmix_host_cd_pass_samples_per_sec`` — samples x passes / wall-clock
-through ``run_coordinate_descent`` on the HOST backend with a configuration the
-fused single-jit pass rejects (normalization + per-entity L2 + coefficient
-variances — see estimators/fused_backend.fused_pass_ineligibilities). This is
-the production-featureful regime the single-program random-effect coordinate
-update (optimization/solver_cache.re_coordinate_update_program) exists for:
+through ``run_coordinate_descent`` with a featureful configuration
+(normalization + per-entity L2 + coefficient variances). This is the regime
+the single-program random-effect coordinate update
+(optimization/solver_cache.re_coordinate_update_program) exists for:
 one donated XLA dispatch per coordinate update instead of one program per
 bucket with eager glue, per-bucket normalization gathers, and blocking
 divergence-guard/tracker reads between updates.
@@ -41,14 +40,14 @@ headline on the identical workload —
   ``BF16_HELDOUT_LOGLOSS_TOL`` (an explicit tolerance gate — reduced
   precision is NEVER bitwise-compared against f32), plus zero retraces.
 
-Each variant carries modeled roofline columns, machine-readable for the
-BENCH_r* trajectory: ``achieved_gb_per_sec`` and ``flops_per_byte``, computed
+Each variant carries modeled roofline columns, ``achieved_gb_per_sec`` and
+``flops_per_byte``, computed
 from the MEASURED per-entity solver iteration counts and the design-matrix
 byte/flop model documented in docs/PERFORMANCE.md (bytes = design-block reads
 per evaluation x evaluations; a model, not a hardware counter — its value is
 the TREND: direct cuts evaluations, bf16 halves bytes per evaluation, and the
 flop/byte column shows the loop climbing away from the ~0.5 flop/byte
-bandwidth wall BENCH_r04/r05 measured).
+bandwidth wall).
 
 ``--min-direct-speedup R`` gates ``best_direct_vs_lbfgs`` — the best DIRECT
 variant's ratio over the LBFGS/f32 headline (the CI smoke shape leaves it
@@ -458,7 +457,7 @@ def run(
         # exposes memory_stats(); live buffer nbytes otherwise) — never modeled
         "peak_device_table_bytes": int(peak_bytes),
         "device_memory_source": peak_source,
-        # roofline trajectory, machine-readable for future BENCH_r* files
+        # modeled roofline columns
         "achieved_gb_per_sec": lbfgs_roof["achieved_gb_per_sec"],
         "flops_per_byte": lbfgs_roof["flops_per_byte"],
         "passes": passes,

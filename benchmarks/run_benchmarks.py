@@ -337,7 +337,7 @@ def config3_glmix_movielens_like(scale=1.0):
     results = est.fit(train, validation_data=val)
     best = est.select_best_model(results)
     wall = time.perf_counter() - t0
-    rec = {
+    return {
         "metric": "glmix_movielens_like_wall_clock_to_auc",
         "value": round(wall, 3),
         "unit": "seconds",
@@ -345,24 +345,6 @@ def config3_glmix_movielens_like(scale=1.0):
         "samples": n,
         "samples_per_sec": round(2 * n / wall, 1),
     }
-
-    # Same configuration through the fused single-jit pass (the program
-    # bench.py measures, exposed via GameEstimator(fused_pass=True)): one
-    # dispatch per CD pass instead of one per coordinate update. Reported
-    # alongside — `value` stays the host loop for baseline comparability.
-    import dataclasses as _dc
-
-    fused_est = _dc.replace(est, fused_pass=True)
-    fused_est.fit(train, validation_data=val)  # untimed compile warm-up
-    t0 = time.perf_counter()
-    fused_best = fused_est.select_best_model(
-        fused_est.fit(train, validation_data=val)
-    )
-    fused_wall = time.perf_counter() - t0
-    rec["fused_wall_clock"] = round(fused_wall, 3)
-    rec["fused_auc"] = round(float(fused_best.best_metric), 5)
-    rec["fused_samples_per_sec"] = round(2 * n / fused_wall, 1)
-    return rec
 
 
 def config4_svm_warm_start():

@@ -201,9 +201,8 @@ def _flush_guards(pending: list, incidents: list, models: dict) -> None:
 def _snapshot_models(models: dict, donating: set) -> dict:
     """Copy coefficient arrays out of models owned by donating coordinates:
     the next fused update CONSUMES its input table (donate_argnums), so a
-    best-model snapshot aliasing the live array would be invalidated
-    (fused_backend._params_to_model makes the same copy for the same
-    reason). Non-donating coordinates keep zero-copy snapshots."""
+    best-model snapshot aliasing the live array would be invalidated.
+    Non-donating coordinates keep zero-copy snapshots."""
     out = dict(models)
     for cid in donating:
         m = out.get(cid)

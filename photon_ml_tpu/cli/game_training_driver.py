@@ -115,16 +115,12 @@ def build_arg_parser() -> argparse.ArgumentParser:
                         "maps; wildcard '*' in term (or name+term) supported. "
                         "Applies to fixed-effect coordinates.")
     p.add_argument("--compute-backend", default="host",
-                   choices=["host", "mesh", "fused"],
+                   choices=["host", "mesh"],
                    help="'mesh' places datasets/models over a jax.sharding.Mesh "
                         "so the coordinate-descent pass runs as sharded SPMD "
-                        "programs (the reference's distributed path); 'fused' "
-                        "runs each coordinate-descent pass as ONE jitted SPMD "
-                        "program (eligible configurations only — L2, no "
-                        "normalization/constraints/down-sampling; validation "
-                        "tracked per pass), optionally over --mesh-devices")
+                        "programs (the reference's distributed path)")
     p.add_argument("--mesh-devices", type=int, default=None,
-                   help="Device count for --compute-backend=mesh/fused "
+                   help="Device count for --compute-backend=mesh "
                         "(default: all)")
     from photon_ml_tpu.cli.runtime import add_distributed_arguments, add_ingest_arguments
 
@@ -155,10 +151,6 @@ def build_arg_parser() -> argparse.ArgumentParser:
                    help="Store dense fixed-effect features in bfloat16 (half "
                         "the HBM traffic; f32 accumulation on the MXU). "
                         "Validate metric parity for your workload first")
-    p.add_argument("--re-storage-dtype", default=None, choices=["bf16"],
-                   help="Store random-effect bucket blocks + scoring values "
-                        "in bfloat16 on the fused pass (the profiled hot "
-                        "loops; coefficients and accumulation stay f32)")
     p.add_argument("--profile-output-directory", default=None,
                    help="Capture an XLA/TPU profiler trace of the training "
                         "phase (open with TensorBoard or xprof) — the "
@@ -265,16 +257,6 @@ def _save_result(out_dir: str, result, index_maps_by_coord, coord_configs,
 def run(args: argparse.Namespace, emitter: Optional[EventEmitter] = None) -> dict:
     """Full training pipeline (GameTrainingDriver.run:346-482). Returns a summary
     dict {"results": [...], "best_index": i, "output_directory": ...}."""
-    # Cross-flag validation BEFORE any expensive work (ingest, model load):
-    # only the fused pass consumes the RE storage dtype.
-    if (
-        getattr(args, "re_storage_dtype", None)
-        and getattr(args, "compute_backend", "host") != "fused"
-    ):
-        raise SystemExit(
-            "--re-storage-dtype requires --compute-backend fused "
-            "(the host/mesh paths do not consume it)"
-        )
     # Multi-host init must precede EVERY other JAX touch (model loading,
     # data placement): jax.distributed.initialize after backend init either
     # errors or silently leaves the "global" mesh host-local.
@@ -474,16 +456,11 @@ def run(args: argparse.Namespace, emitter: Optional[EventEmitter] = None) -> dic
             else []
         )
 
-
-        fe_storage_dtype = re_storage_dtype = None
+        fe_storage_dtype = None
         if getattr(args, "fe_storage_dtype", None) == "bf16":
             import jax.numpy as jnp
 
             fe_storage_dtype = jnp.bfloat16
-        if getattr(args, "re_storage_dtype", None) == "bf16":
-            import jax.numpy as jnp
-
-            re_storage_dtype = jnp.bfloat16
 
         mesh = None
         backend = getattr(args, "compute_backend", "host")
@@ -506,24 +483,6 @@ def run(args: argparse.Namespace, emitter: Optional[EventEmitter] = None) -> dic
 
                 mesh = make_mesh(args.mesh_devices)
 
-        if backend == "fused":
-            n_model = getattr(args, "mesh_model_devices", 1) or 1
-            if n_model > 1:
-                # build the 2-D mesh so the fused eligibility check rejects it
-                # with its own reason instead of silently dropping the
-                # feature-axis sharding
-                import jax
-
-                from photon_ml_tpu.parallel import make_mesh2
-
-                total = args.mesh_devices or len(jax.devices())
-                mesh = make_mesh2(total // n_model, n_model)
-            else:
-                from photon_ml_tpu.parallel.mesh import make_mesh
-
-                # default all devices, same as --compute-backend=mesh
-                mesh = make_mesh(args.mesh_devices)
-
         estimator = GameEstimator(
             task=task,
             coordinate_configurations=coord_configs,
@@ -539,8 +498,6 @@ def run(args: argparse.Namespace, emitter: Optional[EventEmitter] = None) -> dic
                 args, "checkpoint_keep_generations", 3
             ),
             fe_storage_dtype=fe_storage_dtype,
-            re_storage_dtype=re_storage_dtype,
-            fused_pass=backend == "fused",
         )
 
         emitter.send_event(Event("TrainingStartEvent"))

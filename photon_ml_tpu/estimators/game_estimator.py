@@ -134,26 +134,12 @@ class GameEstimator:
     # (DenseDesignMatrix._mxu_dot). Validate quality before relying on it —
     # bench.py gates its bf16 variant on 1% objective parity.
     fe_storage_dtype: Optional[object] = None
-    # Same for the random-effect bucket blocks + per-sample scoring values on
-    # the fused pass (the hot loops of the 2026-07-31 on-chip trace,
-    # ROADMAP.md S2) — the configuration bench.py's bf16 variant measures sets
-    # BOTH storage dtypes.
-    re_storage_dtype: Optional[object] = None
-    # Run each coordinate-descent pass as ONE jitted SPMD program
-    # (parallel/game.py — the program bench.py measures) instead of the host
-    # loop's one-dispatch-per-coordinate-update. Eligible configurations only
-    # (estimators/fused_backend.py lists the conditions and raises with
-    # reasons otherwise); validation/best-model tracking happens per PASS,
-    # not per coordinate update.
-    fused_pass: bool = False
-    # Host-loop random-effect updates as ONE donated XLA program per
-    # coordinate update (optimization/solver_cache.re_coordinate_update_
-    # program) instead of one program per bucket — the featureful
-    # configurations the fused pass rejects (normalization, per-entity L2,
-    # variances, checkpointing, ...) keep their semantics but lose the
-    # per-bucket dispatch + host-sync overhead. False restores the per-bucket
-    # loop. Mesh-sharded datasets compile the same program as ONE SPMD
-    # module (entity-sharded solves, sample-sharded scores).
+    # Random-effect updates as ONE donated XLA program per coordinate update
+    # (optimization/solver_cache.re_coordinate_update_program) instead of one
+    # program per bucket: no per-bucket dispatch, no host sync between
+    # buckets. False restores the per-bucket loop. Mesh-sharded datasets
+    # compile the same program as ONE SPMD module (entity-sharded solves,
+    # sample-sharded scores).
     re_update_program: bool = True
     # Random-effect inner bucket solver (optimization/normal_equations.py):
     # "lbfgs" runs the configured optimizer (bitwise status quo), "direct"
@@ -197,15 +183,6 @@ class GameEstimator:
                     "re_precision requires re_update_program=True (reduced "
                     "storage rides the single-program update path)"
                 )
-            if self.fused_pass:
-                # the fused whole-pass backend has its own storage knobs
-                # (fe_storage_dtype / re_storage_dtype); accepting
-                # re_precision there would be a silent no-op
-                raise ValueError(
-                    "re_precision applies to the host loop's update program; "
-                    "the fused pass uses fe_storage_dtype/re_storage_dtype "
-                    "(set fused_pass=False or use those knobs)"
-                )
             # a mesh is fine: storage dtype is orthogonal to placement — the
             # sharded update program stores its entity-sharded tables/blocks
             # reduced exactly like the host path does. Checkpointing is fine
@@ -213,12 +190,6 @@ class GameEstimator:
             # patterns with self-describing markers, so a bf16 deployment's
             # generations round-trip bit-exactly across restart.
         if self.re_working_set_rows is not None:
-            if self.fused_pass:
-                raise ValueError(
-                    "re_working_set_rows streams through the host loop's "
-                    "update program; the fused whole-pass backend assumes "
-                    "fully device-resident tables (set fused_pass=False)"
-                )
             if not self.re_update_program:
                 raise ValueError(
                     "re_working_set_rows requires re_update_program=True "
@@ -230,13 +201,6 @@ class GameEstimator:
                     "reference precision; combine with re_precision is not "
                     "supported"
                 )
-        if self.re_storage_dtype is not None and not self.fused_pass:
-            # only the fused pass consumes it (build_sharded_game_data);
-            # accepting it elsewhere would be a silent no-op
-            raise ValueError(
-                "re_storage_dtype requires fused_pass=True (the host/mesh "
-                "paths do not consume it)"
-            )
         locked = set(self.partial_retrain_locked_coordinates)
         unknown = locked - set(self.coordinate_configurations)
         if unknown:
@@ -489,8 +453,6 @@ class GameEstimator:
 
             with span("fit.prepare"):
                 datasets = self.prepare_training_datasets(data)
-            if self.fused_pass:
-                return self._fit_fused(datasets, validation_data, initial_model)
             with span("fit.build"):  # what every configuration of the sweep shares
                 base_offsets = jnp.asarray(np.asarray(data.offsets), dtype=self.dtype)
                 if self.mesh is not None:
@@ -599,89 +561,6 @@ class GameEstimator:
                 )
                 warm = descent.best_model  # chain warm starts across the sweep
             return results
-
-    def _fit_fused(
-        self,
-        datasets: dict[str, object],
-        validation_data: Optional[GameInput],
-        initial_model: Optional[GameModel],
-    ) -> list[GameResult]:
-        """Sweep through the single-jit fused pass (estimators/fused_backend.py).
-
-        Warm starts chain across sweep configurations as device params (the
-        datasets are identical across configurations, so the previous
-        configuration's final parameters are the next one's starting point —
-        the same strong-to-weak regularization chaining as the host loop)."""
-        from photon_ml_tpu.estimators.fused_backend import (
-            fused_pass_ineligibilities,
-            run_fused_game_descent,
-        )
-
-        if initial_model is not None:
-            raise ValueError(
-                "fused_pass does not support initial_model; use the host backend"
-            )
-        sweep = expand_game_configurations(self.coordinate_configurations)
-        for opt_configs in sweep:
-            reasons = fused_pass_ineligibilities(self, opt_configs)
-            if reasons:
-                raise ValueError(
-                    "configuration not eligible for the fused pass: "
-                    + "; ".join(reasons)
-                    + " (set fused_pass=False for the host backend)"
-                )
-
-        validation_datasets = None
-        suite = None
-        if validation_data is not None:
-            validation_datasets = self.prepare_scoring_datasets(validation_data)
-            suite = self.prepare_evaluation_suite(validation_data)
-
-        # the ShardedGameData is identical across sweep configurations: pad
-        # and device-transfer it ONCE, not once per configuration
-        from photon_ml_tpu.parallel import build_sharded_game_data, make_mesh
-
-        coord_ids = list(self.coordinate_configurations)
-        fe_ds = datasets[coord_ids[0]]
-        mesh = self.mesh if self.mesh is not None else make_mesh(1)
-        sharded = build_sharded_game_data(
-            fe_ds.data.X,
-            np.asarray(fe_ds.data.labels),
-            [datasets[c] for c in coord_ids[1:]],
-            mesh,
-            offsets=np.asarray(fe_ds.data.offsets),
-            weights=np.asarray(fe_ds.data.weights),
-            dtype=self.dtype,
-            fe_storage_dtype=self.fe_storage_dtype,
-            re_storage_dtype=self.re_storage_dtype,
-        )
-
-        logger.info(
-            "GAME fused-pass sweep: %d configurations x %d coordinates",
-            len(sweep),
-            len(self.coordinate_configurations),
-        )
-        results: list[GameResult] = []
-        warm_params = None
-        for opt_configs in sweep:
-            descent, warm_params = run_fused_game_descent(
-                self, datasets, opt_configs, validation_datasets, suite,
-                sharded, mesh, warm_params,
-            )
-            evaluations = None
-            if suite is not None and (descent.metrics_history or descent.best_metrics):
-                evaluations = _metrics_of_best(descent)
-            results.append(
-                GameResult(
-                    model=descent.model,
-                    best_model=descent.best_model,
-                    configuration=opt_configs,
-                    evaluations=evaluations,
-                    best_metric=descent.best_metric,
-                    descent=descent,
-                )
-            )
-        return results
 
     def select_best_model(self, results: Sequence[GameResult]) -> GameResult:
         """Best result by primary validation metric (GameTrainingDriver

@@ -1,6 +1,6 @@
 """Batched direct solves for the small dense random-effect buckets.
 
-BENCH_r05 measured 7-9 L-BFGS iterations per random-effect bucket, each
+A cold fit runs 7-9 L-BFGS iterations per random-effect bucket, each
 iteration re-reading the whole [E, S, K] block from HBM for its line-searched
 value/gradient evaluations — on a loop the roofline already shows is
 bandwidth-bound (~0.5 flop/byte), those passes over the data ARE the cost.

@@ -1,6 +1,6 @@
 """Precision policy: storage vs accumulation dtypes, and the host dtype boundary.
 
-BENCH_r04/r05 rooflines put the hot coordinate-descent loop at ~0.5 flop/byte —
+The hot coordinate-descent loop does ~0.5 flop/byte (counted from shapes) —
 memory-bandwidth-bound, so bytes ARE the budget. ``PrecisionPolicy`` names the
 one lever that halves them: store the big arrays (per-entity coefficient
 tables, bucket feature blocks, per-sample scoring views, serving coefficient

@@ -369,7 +369,11 @@ def _re_coordinate_update_fn(
                 factors, shifts, icpt_mask = norm_tbl
                 init_b = _to_transformed(init_b, factors, shifts, icpt_mask)
             solve_args = (
-                bucket.X,
+                # a reduced block upcasts into the solve like every other
+                # operand here: left bf16, DenseDesignMatrix's dot rounds the
+                # coefficients to bf16, and the population axis makes it a
+                # BF16 x BF16 = F32 matrix product that XLA:CPU does not have
+                bucket.X.astype(solve_dtype) if reduced else bucket.X,
                 bucket.labels,
                 bucket.weights,
                 off_b,

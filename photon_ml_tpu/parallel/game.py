@@ -216,9 +216,8 @@ def game_train_step(
 
     ``fe_l2``/``re_l2`` (scalar / sequence of scalars) override the configs'
     L2 weights as TRACED values: a caller sweeping regularization weights can
-    then reuse one compiled program across the whole sweep
-    (estimators/fused_backend.py) instead of baking each weight in as a
-    trace-time constant.
+    then reuse one compiled program across the whole sweep instead of
+    baking each weight in as a trace-time constant.
 
     ``re_solver`` selects the random-effect inner bucket solver
     (optimization/normal_equations.py — "lbfgs" | "direct" | "auto"); the
@@ -686,9 +685,7 @@ def make_jitted_game_step(
     dense HLO constants (~0.5 KB of module text per f32 design-matrix row at
     D=64), which makes the compile and its persistent-cache entry scale with
     the dataset and stops working near the 2 GB module limit. As an argument,
-    the ShardedGameData pytree's shardings bind the partitioning and the
-    program is the one GameEstimator(fused_pass=True) runs
-    (estimators/fused_backend._fused_step)."""
+    the ShardedGameData pytree's shardings bind the partitioning."""
 
     fuse_fe = mesh.devices.size == 1
     shard_mesh = mesh if mesh.devices.size > 1 else None

@@ -109,8 +109,6 @@ class SweepSpec:
                 "mesh-sharded estimators are not supported (the population "
                 "programs do not re-place sharded tables)"
             )
-        if getattr(estimator, "fused_pass", False):
-            reasons.append("fused_pass estimators take their own sweep path")
         if (
             VarianceComputationType(estimator.variance_computation)
             != VarianceComputationType.NONE

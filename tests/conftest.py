@@ -79,8 +79,8 @@ def _drop_compiled_programs_between_modules():
     On this installation (JAX 0.9.0, XLA:CPU, 8 emulated devices) the suite
     otherwise dies of a segmentation fault inside an XLA:CPU compile once a few
     hundred tests' executables have accumulated in one process — at the seed
-    always at test 289 of ~1040 (tests/test_fused_backend.py), with a cold or a
-    warm persistent cache, while every module passes on its own. Modules share
+    always at test 289 of ~1040, with a cold or a warm persistent cache, while
+    every module passes on its own. Modules share
     no compiled state by design, so dropping it costs only re-tracing."""
     yield
     jax.clear_caches()

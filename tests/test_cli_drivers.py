@@ -671,25 +671,25 @@ def test_training_driver_profiler_trace(rng, tmp_path):
     assert traces, "no profiler trace files written"
 
 
-def test_re_storage_dtype_rejected_outside_fused_backend(tmp_path):
-    """--re-storage-dtype with a non-fused backend fails fast BEFORE ingest."""
-    import argparse
-
+def test_compute_backend_fused_is_refused_by_argparse(tmp_path, capsys):
+    """The fused whole-pass backend is gone: its choice is argparse's own
+    error (exit code 2), before any ingest."""
     from photon_ml_tpu.cli import game_training_driver as d
 
-    args = d.build_arg_parser().parse_args([
-        "--input-data-directories", str(tmp_path / "none"),
-        "--root-output-directory", str(tmp_path / "out"),
-        "--training-task", "LOGISTIC_REGRESSION",
-        "--feature-shard-configurations", "name=global,feature.bags=features",
-        "--coordinate-configurations",
-        "name=global,feature.shard=global,optimizer=LBFGS,max.iter=5,"
-        "tolerance=1e-6,regularization=L2,reg.weights=1.0",
-        "--coordinate-update-sequence", "global",
-        "--re-storage-dtype", "bf16",
-    ])
-    with pytest.raises(SystemExit, match="compute-backend fused"):
-        d.run(args)
+    with pytest.raises(SystemExit) as refused:
+        d.build_arg_parser().parse_args([
+            "--input-data-directories", str(tmp_path / "none"),
+            "--root-output-directory", str(tmp_path / "out"),
+            "--training-task", "LOGISTIC_REGRESSION",
+            "--feature-shard-configurations", "name=global,feature.bags=features",
+            "--coordinate-configurations",
+            "name=global,feature.shard=global,optimizer=LBFGS,max.iter=5,"
+            "tolerance=1e-6,regularization=L2,reg.weights=1.0",
+            "--coordinate-update-sequence", "global",
+            "--compute-backend", "fused",
+        ])
+    assert refused.value.code == 2
+    assert "invalid choice: 'fused'" in capsys.readouterr().err
 
 
 # ----------------------------------------------------------- sweep driver

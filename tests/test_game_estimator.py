@@ -227,39 +227,6 @@ def test_fe_storage_dtype_bf16_close_to_f32(rng):
     assert bf16.best_metric == pytest.approx(f32.best_metric, abs=0.01)
 
 
-def test_re_storage_dtype_requires_fused_pass():
-    """re_storage_dtype is only consumed by the fused pass's
-    build_sharded_game_data; accepting it elsewhere would be a silent no-op."""
-    import jax.numpy as jnp
-    import pytest as _pytest
-
-    from photon_ml_tpu.estimators import (
-        CoordinateConfiguration,
-        FixedEffectDataConfiguration,
-        GameEstimator,
-    )
-
-    cfgs = {
-        "g": CoordinateConfiguration(
-            data_config=FixedEffectDataConfiguration("g"),
-            optimization_config=OPT,
-        )
-    }
-    with _pytest.raises(ValueError, match="fused_pass"):
-        GameEstimator(
-            task=TaskType.LOGISTIC_REGRESSION,
-            coordinate_configurations=cfgs,
-            re_storage_dtype=jnp.bfloat16,
-        )
-    est = GameEstimator(
-        task=TaskType.LOGISTIC_REGRESSION,
-        coordinate_configurations=cfgs,
-        re_storage_dtype=jnp.bfloat16,
-        fused_pass=True,
-    )
-    assert est.re_storage_dtype == jnp.bfloat16
-
-
 # -------------------------------------------------- GLM family matrix
 
 

@@ -992,10 +992,20 @@ def test_two_process_game_training_wide_sparse_re_shard(tmp_path):
             f.close()
 
     got = load(tmp_path / "out")
+    # the in-process reference runs under the suite's x64 config, the workers
+    # at f32, so the gap is f32 block-CD drift over 8 passes, which moves with
+    # any last-bit change of the arithmetic and not with the run: 6.52e-5
+    # (fixed) and 1.96e-4 (per entity) in seven of seven readings at PR 31,
+    # whatever the cache state or the core count; 2.1e-4 (fixed) at PR 21's
+    # tree, against the 2e-4 this held then. 2e-3 is this file's bound for
+    # such pairs (test_two_process_game_training_matches_single_process):
+    # 9.5x the largest reading on record, far under an exchange fault (the
+    # coefficients are O(0.1..1))
+    WIDE_SHARD_ATOL = 2e-3
     np.testing.assert_allclose(
         np.asarray(got.get_model("global").model.coefficients.means),
         np.asarray(ref.get_model("global").model.coefficients.means),
-        atol=2e-4,
+        atol=WIDE_SHARD_ATOL,
     )
     re_ref, re_got = ref.get_model("per-user"), got.get_model("per-user")
     assert set(re_got.entity_ids) == set(re_ref.entity_ids)
@@ -1004,7 +1014,7 @@ def test_two_process_game_training_wide_sparse_re_shard(tmp_path):
         b = _entity_coeff_map(re_got, eid)
         assert set(a) == set(b), eid  # same feature columns per entity
         for col in a:
-            assert abs(a[col] - b[col]) < 5e-4, (eid, col, a[col], b[col])
+            assert abs(a[col] - b[col]) < WIDE_SHARD_ATOL, (eid, col, a[col], b[col])
 
 
 def test_two_process_game_validation_selects_best_lambda(tmp_path):

@@ -151,7 +151,7 @@ def test_direct_variances_match_lbfgs():
 def test_warm_start_collapses_iterations():
     """The roofline claim's mechanism: a warm-started direct pass converges
     in far fewer Newton steps than the cold LBFGS pass takes quasi-Newton
-    iterations (BENCH_r05's 7-9 -> 1-2 solves)."""
+    iterations (7-9 -> 1-2 solves)."""
     X, ents, labels, _ = make_problem(seed=11)
     ds = build_random_effect_dataset(X, ents, "e", labels=labels[TaskType.LOGISTIC_REGRESSION])
     off = jnp.zeros(N, dtype=jnp.float32)

@@ -38,6 +38,7 @@ from photon_ml_tpu.types import (
     TaskType,
     VarianceComputationType,
 )
+from tests import parity_tiers
 
 CFG = GLMOptimizationConfiguration(
     optimizer_config=OptimizerConfig(max_iterations=50, tolerance=1e-9),
@@ -73,6 +74,7 @@ def build_coords(
     per_entity=None,
     variance=VarianceComputationType.NONE,
     precision=None,
+    dtype=jnp.float32,
 ):
     X, X_re, users, y, norm = workload
     fe_ds = FixedEffectDataset(LabeledData.build(X, y), feature_shard_id="global")
@@ -80,6 +82,7 @@ def build_coords(
         X_re, users, "userId", feature_shard_id="per-user", labels=y,
         normalization=normalization,
         intercept_index=0 if normalization is not None else None,
+        dtype=dtype,
     )
     assert len(re_ds.buckets) >= 2
     return {
@@ -117,6 +120,12 @@ def descent_state(result):
 # --------------------------------------------------------------- parity matrix
 
 
+@pytest.fixture(scope="module")
+def order_exact():
+    """parity_tiers' probe of this backend, once a module."""
+    return parity_tiers.order_exact()
+
+
 @pytest.mark.parametrize("with_norm", [False, True], ids=["raw", "norm"])
 @pytest.mark.parametrize("with_per_entity", [False, True], ids=["uniform", "per-entity-l2"])
 @pytest.mark.parametrize(
@@ -124,10 +133,11 @@ def descent_state(result):
     [VarianceComputationType.NONE, VarianceComputationType.SIMPLE],
     ids=["novar", "simplevar"],
 )
-def test_update_program_parity(rng, with_norm, with_per_entity, variance):
-    """Bitwise-equal coefficients, variances and [N] scores vs the per-bucket
-    loop across the featureful configuration matrix, over multiple descent
-    iterations (score feedback would amplify any single-ulp divergence)."""
+def test_update_program_parity(rng, order_exact, with_norm, with_per_entity, variance):
+    """The same coefficients, variances and [N] scores as the per-bucket loop
+    across the featureful configuration matrix, over multiple descent
+    iterations: bit for bit where the backend is order-exact, else by
+    parity_tiers' two bounds."""
     workload = make_workload(rng)
     norm = workload[-1] if with_norm else None
     per_entity = (
@@ -136,21 +146,69 @@ def test_update_program_parity(rng, with_norm, with_per_entity, variance):
         else None
     )
 
-    def descend(use_program):
+    def descend(use_program, n_iterations, dtype):
         coords = build_coords(
             workload, use_program=use_program, normalization=norm,
-            per_entity=per_entity, variance=variance,
+            per_entity=per_entity, variance=variance, dtype=dtype,
         )
-        return run_coordinate_descent(
-            coords, n_iterations=3, defer_guard=use_program
+        return descent_state(
+            run_coordinate_descent(
+                coords, n_iterations=n_iterations, defer_guard=use_program
+            )
         )
 
-    s_new = descent_state(descend(True))
-    s_old = descent_state(descend(False))
-    assert set(s_new) == set(s_old)
-    for key in sorted(s_old):
-        assert s_new[key].dtype == s_old[key].dtype, key
-        np.testing.assert_array_equal(s_new[key], s_old[key], err_msg=key)
+    parity_tiers.assert_program_matches_loop(descend, order_exact)
+
+
+def _stub_descent(drift):
+    """A descent whose program side is ``drift(dtype)`` (relative) off its
+    loop side in every update."""
+
+    def descend(use_program, n_iterations, dtype):
+        w = np.linspace(0.5, 1.0, 12).astype(dtype)
+        return {"coeffs": w * dtype(1.0 + drift(dtype)) if use_program else w}
+
+    return descend
+
+
+def _one_ulp(dtype):
+    return float(np.finfo(dtype).eps)
+
+
+@pytest.mark.parametrize(
+    "exact, drift, tier",
+    [
+        (True, lambda dtype: 0.0, "bitwise"),
+        (True, _one_ulp, AssertionError),  # one ulp is too much there
+        (False, _one_ulp, "tolerance"),  # and reassociation passes here
+        (False, lambda dtype: 1e-3, AssertionError),  # where a 1e-3 fault does not
+    ],
+    ids=["exact", "exact-ulp", "inexact-ulp", "inexact-fault"],
+)
+def test_parity_tier_follows_the_probe(exact, drift, tier):
+    """The probe's answer selects the tier, and each tier refuses what it
+    should."""
+    descend = _stub_descent(drift)
+    if tier is AssertionError:
+        with pytest.raises(AssertionError):
+            parity_tiers.assert_program_matches_loop(descend, exact)
+    else:
+        assert parity_tiers.assert_program_matches_loop(descend, exact) == tier
+
+
+def test_parity_probe_reads_a_stubbed_backend_both_ways():
+    """Order-exact where fusion leaves the bits alone; not where the fused
+    form rounds once (an FMA) and the separate one twice."""
+
+    def rounds_twice(a, b, c):
+        return np.asarray(a) * np.asarray(b) + np.asarray(c)
+
+    def rounds_once(a, b, c):
+        wide = np.asarray(a, np.float64) * np.asarray(b, np.float64)
+        return (wide + np.asarray(c, np.float64)).astype(np.float32)
+
+    assert parity_tiers.order_exact(rounds_twice, rounds_twice)
+    assert not parity_tiers.order_exact(rounds_once, rounds_twice)
 
 
 # ------------------------------------------------------------- donation safety
@@ -609,6 +667,7 @@ def build_mesh_coord(
     per_entity=None,
     variance=VarianceComputationType.NONE,
     precision=None,
+    dtype=jnp.float32,
 ):
     from photon_ml_tpu.parallel.mesh import make_mesh
     from photon_ml_tpu.parallel.placement import (
@@ -621,6 +680,7 @@ def build_mesh_coord(
         X_re, users, "userId", feature_shard_id="per-user", labels=y,
         normalization=normalization,
         intercept_index=0 if normalization is not None else None,
+        dtype=dtype,
     )
     mesh = make_mesh(8)
     ds_m = place_random_effect_dataset(re_ds, mesh)
@@ -638,11 +698,14 @@ def build_mesh_coord(
     return coord, ds_m, mesh
 
 
-def test_mesh_update_program_bitwise_parity_vs_per_bucket(rng, eight_devices):
+def test_mesh_update_program_bitwise_parity_vs_per_bucket(
+    rng, eight_devices, order_exact
+):
     """The sharded single-program update must train the SAME model as the
-    sharded per-bucket loop — bitwise coefficients, variances and scores over
+    sharded per-bucket loop — coefficients, variances and scores over
     multiple iterations, in the featureful configuration (normalization +
-    per-entity L2 + SIMPLE variances)."""
+    per-entity L2 + SIMPLE variances): bit for bit where the backend is
+    order-exact, else by parity_tiers' two bounds."""
     workload = make_workload(rng)
     norm = workload[-1]
     per_entity = {
@@ -650,21 +713,20 @@ def test_mesh_update_program_bitwise_parity_vs_per_bucket(rng, eight_devices):
         for e, v in enumerate(rng.uniform(0.4, 2.5, size=N_USERS))
     }
 
-    def descend(use_program):
+    def descend(use_program, n_iterations, dtype):
         coord, _, _ = build_mesh_coord(
             workload, use_program=use_program, normalization=norm,
             per_entity=per_entity, variance=VarianceComputationType.SIMPLE,
+            dtype=dtype,
         )
-        return run_coordinate_descent(
-            {"per-user": coord}, n_iterations=3, defer_guard=use_program
+        return descent_state(
+            run_coordinate_descent(
+                {"per-user": coord}, n_iterations=n_iterations,
+                defer_guard=use_program,
+            )
         )
 
-    s_new = descent_state(descend(True))
-    s_old = descent_state(descend(False))
-    assert set(s_new) == set(s_old)
-    for key in sorted(s_old):
-        assert s_new[key].dtype == s_old[key].dtype, key
-        np.testing.assert_array_equal(s_new[key], s_old[key], err_msg=key)
+    parity_tiers.assert_program_matches_loop(descend, order_exact)
 
 
 def test_mesh_donated_updates_keep_sharding_and_consume_buffers(rng, eight_devices):

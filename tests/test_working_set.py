@@ -536,8 +536,6 @@ def test_estimator_fit_parity(rng):
 
 
 def test_estimator_knob_validation():
-    with pytest.raises(ValueError, match="fused_pass"):
-        make_estimator(17, fused_pass=True)
     with pytest.raises(ValueError, match="re_update_program"):
         make_estimator(17, re_update_program=False)
     with pytest.raises(ValueError, match="reference precision"):
