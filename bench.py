@@ -378,31 +378,18 @@ def _build_workload_device(fe_storage_dtype=None):
             [jnp.zeros(1, jnp.int32), jnp.cumsum(counts)[:-1].astype(jnp.int32)]
         )
         counts_h = np.asarray(counts)  # the one device->host hop, [E] int
-        # pow2 shape classes as in data/random_effect._next_pow2(min=8)
-        s_pad = np.maximum(
-            8, 2 ** np.ceil(np.log2(np.maximum(counts_h, 1))).astype(np.int64)
-        )
-        live = counts_h >= 1  # lower-bound filter: empty entities train no model
-        classes, class_of = np.unique(s_pad, return_inverse=True)
-        # rare-class fold, governed by the PRODUCTION consolidation policy
-        # (auto fraction + PHOTON_BUCKET_MERGE override) so the bench workload
-        # tracks the ingest path's bucketing decisions
-        from photon_ml_tpu.data.random_effect import _resolve_merge_fraction
+        # the ingest path's own layout rule and bucket cost (data/random_effect.py),
+        # so the bench workload tracks its bucketing decisions
+        from photon_ml_tpu.data.random_effect import _bucket_policy, bucket_layout
 
-        merge_fraction = _resolve_merge_fraction(None)
-        if merge_fraction > 0 and len(classes) > 1:
-            # classes under the fraction merge into the next larger one
-            n_live = int(live.sum())
-            sizes = np.bincount(class_of[live], minlength=len(classes))
-            for ci in range(len(classes) - 1):
-                if sizes[ci] and sizes[ci] < merge_fraction * n_live:
-                    class_of[class_of == ci] = ci + 1
-                    sizes[ci + 1] += sizes[ci]
-                    sizes[ci] = 0
+        live = np.flatnonzero(counts_h >= 1)  # empty entities train no model
+        cost, pow2_heights = _bucket_policy(None)
+        layout = bucket_layout(
+            counts_h[live], np.full(len(live), K), cost, pow2_heights=pow2_heights
+        )
         buckets = []
-        for ci in np.unique(class_of[live]):
-            members = np.flatnonzero(live & (class_of == ci))
-            S = int(classes[ci])  # folds only move entities to LARGER classes
+        for (S, _k), inside in sorted(layout.items()):
+            members = live[inside]
             ents_d = jnp.asarray(members.astype(np.int32))
             idx = starts[ents_d][:, None] + jnp.arange(S, dtype=jnp.int32)[None, :]
             valid = jnp.arange(S)[None, :] < counts[ents_d][:, None]

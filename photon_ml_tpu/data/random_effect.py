@@ -28,7 +28,6 @@ from __future__ import annotations
 
 import dataclasses
 import hashlib
-import os
 from typing import Optional, Sequence
 
 import jax
@@ -143,98 +142,110 @@ class RandomEffectDataset:
         return self.sample_entity_rows, self.sample_local_cols, self.sample_vals
 
 
-def _resolve_merge_fraction(bucket_merge_fraction: Optional[float]) -> float:
-    """Resolve the auto (None) bucket-merge policy by backend.
-
-    Consolidating rare bucket shapes trades padded FLOPs for fewer sequential
-    solver programs per pass. On an accelerator the programs are pure dispatch
-    latency, so the trade wins; on CPU the extra padded FLOPs are real compute
-    on a latency-cheap backend and consolidation measured ~25% slower on the
-    flagship bench (186k -> 141k samples/s). Auto therefore consolidates only
-    when the default JAX backend is not the CPU. Pass an explicit fraction
-    (0 disables) to override per-dataset.
-    """
-    if bucket_merge_fraction is not None:
-        return bucket_merge_fraction
-    env = os.environ.get("PHOTON_BUCKET_MERGE", "").strip()
-    if env:
-        # experimentation override (e.g. bench sweeps: 0 = off, 1.0 = merge
-        # every sub-threshold shape class, still under the padding budget)
-        try:
-            return float(env)
-        except ValueError:
-            raise ValueError(
-                f"PHOTON_BUCKET_MERGE must be a number, got {env!r}"
-            ) from None
-    return 0.05 if jax.default_backend() != "cpu" else 0.0
+# What one more bucket costs a training process off the CPU, in units of what
+# one more padded cell (one element of an [E_b, S_b, K_b] block) costs it: 4e6
+# cells = 0.5M padded rows at K = 8. Read on a TPU v5e at the benchmark cell's
+# size (PERF.md section 6, PR 32): on the device a bucket is nearly free
+# (0.24 ms a call-pair against 16 ns a padded row a call: 1e5 cells), in a
+# fit unit it costs the host 1.1 ms (3e5 cells), and at every process start
+# 2.3-3.1 s of tracing against 0.3 us of block fill a padded row. 4e6 is where
+# set-up breaks even on that cell; the steady state alone would take 3e5.
+C_BUCKET_CELLS = 4e6
 
 
-def _consolidate_buckets(
-    bucket_members: dict, n_ent: int, merge_fraction: float
-) -> dict:
-    """Merge rare bucket shape classes into nearby larger shapes.
+def _bucket_policy(bucket_cost: Optional[float]) -> tuple[float, bool]:
+    """``(cost of one more bucket in padded cells, heights are powers of
+    two)``: the caller's cost with heights any multiple of the row pad, else
+    what the backend says. On XLA:CPU a bucket costs a fit nothing, so
+    nothing but the allowed heights bounds their number: powers of two, every
+    occupied one a bucket. Elsewhere a bucket costs ``C_BUCKET_CELLS`` and
+    the cost bounds them."""
+    if bucket_cost is not None:
+        return float(bucket_cost), False
+    if jax.default_backend() == "cpu":
+        return 0.0, True
+    return C_BUCKET_CELLS, False
 
-    Every bucket is a separate sequential vmapped-solver program per
-    coordinate-descent pass — on TPU that is pure latency, so shape classes
-    holding fewer than ``merge_fraction`` of the entities are folded into the
-    partner bucket that wastes the fewest padded cells. Padding is inert by
-    construction (weight-0 rows; zero columns keep their coefficients at 0
-    under L2), so only shapes change, never results. A merge is only taken
-    when its added padding stays below the current total cell count, which
-    blocks pathological merges (e.g. one huge entity inflating everyone's
-    sample axis).
-    """
-    if merge_fraction <= 0 or len(bucket_members) <= 1:
-        return bucket_members
-    merged = dict(bucket_members)
-    # Cumulative padding growth is capped against the PRE-consolidation total
-    # (a per-step budget would ratchet: each merge inflates the base the next
-    # merge is judged against). At 1.0x the padded cell count can at most
-    # double — a deliberate memory-for-latency trade: every removed bucket is
-    # one fewer sequential solver program per coordinate-descent pass, and the
-    # blocks are small relative to HBM.
-    budget = 1.0 * sum(len(m) * s * k for (s, k), m in merged.items())
-    added_total = 0.0
-    skip: set = set()  # shapes whose every merge exceeds the budget
-    while True:
-        candidates = sorted(
-            (len(m), key) for key, m in merged.items() if key not in skip
-        )
-        progressed = False
-        for cnt, (s1, k1) in candidates:
-            if cnt >= merge_fraction * n_ent:
-                break  # candidates are sorted: nothing rarer remains
-            m1 = merged[(s1, k1)]
-            best = None
-            for (s2, k2), m2 in merged.items():
-                if (s2, k2) == (s1, k1):
-                    continue
-                S, K = max(s1, s2), max(k1, k2)
-                added = (
-                    (len(m1) + len(m2)) * S * K
-                    - len(m1) * s1 * k1
-                    - len(m2) * s2 * k2
-                )
-                if added_total + added <= budget and (best is None or added < best[0]):
-                    best = (added, (s2, k2))
-            if best is None:
-                skip.add((s1, k1))  # unmergeable; keep trying the others
+
+def _chain_partition(heights: np.ndarray, counts: np.ndarray, k: int, cost: float):
+    """Exact minimiser of ``sum_b E_b * S_b * k + cost * buckets`` over
+    partitions of ascending distinct ``heights`` (``counts`` entities each)
+    into contiguous ranges, a bucket as tall as the last height of its range.
+    Returns ``(total, ends)``: the minimum and the index one past each range."""
+    m = len(heights)
+    cum = np.concatenate([[0], np.cumsum(counts)]).astype(np.float64)
+    best = np.zeros(m + 1)
+    cut = np.zeros(m + 1, dtype=np.int64)
+    for j in range(1, m + 1):
+        cand = best[:j] + (cum[j] - cum[:j]) * (float(heights[j - 1]) * k) + cost
+        cut[j] = int(np.argmin(cand))
+        best[j] = cand[cut[j]]
+    ends, j = [], m
+    while j > 0:
+        ends.append(j)
+        j = int(cut[j])
+    return float(best[m]), ends[::-1]
+
+
+def bucket_layout(
+    rows: np.ndarray,
+    k_pads: np.ndarray,
+    bucket_cost: float,
+    *,
+    min_rows: int = 8,
+    pow2_heights: bool = False,
+) -> dict[tuple[int, int], np.ndarray]:
+    """Assign entities to padded ``[E_b, S_b, K_b]`` buckets so that
+
+        sum over buckets of E_b * S_b * K_b  +  bucket_cost * (number of buckets)
+
+    is least. ``rows`` is every entity's active row count, ``k_pads`` its
+    padded column count; a bucket is as wide as its widest member and as tall
+    as its tallest, rounded up to an allowed height: a multiple of
+    ``min_rows``, or with ``pow2_heights`` ``min_rows`` times a power of two.
+    Padding is inert by construction (weight-0 rows; zero columns keep their
+    coefficients at 0 under L2), so the layout changes shapes, never results.
+    Within one width the sizes are one-dimensional and the partition is the
+    exact optimum (a dynamic programme over the distinct heights); a width
+    class joins the next wider one only where the joined optimum is cheaper
+    than the two apart. Counted in cells, one huge entity cannot raise
+    everyone's height: that costs more than the bucket it saves.
+    ``bucket_cost = 0`` keeps every occupied (height, width) a bucket.
+    Returns ``{(S_b, K_b): entity indices}``."""
+    rows = np.asarray(rows, dtype=np.int64)
+    if pow2_heights:
+        s_pads = np.asarray([_next_pow2(int(r), min_rows) for r in rows], dtype=np.int64)
+    else:
+        s_pads = np.maximum(-(-rows // min_rows), 1) * min_rows
+    k_pads = np.asarray(k_pads, dtype=np.int64)
+
+    def optimum(members: np.ndarray, k: int):
+        """``(cost, ascending bucket heights)`` of ``members`` at width ``k``."""
+        heights, counts = np.unique(s_pads[members], return_counts=True)
+        total, ends = _chain_partition(heights, counts, k, bucket_cost)
+        return total, heights[np.asarray(ends) - 1]
+
+    # (width, members, cost, bucket heights) of each class, widths ascending
+    classes: list[tuple[int, np.ndarray, float, np.ndarray]] = []
+    for k in (int(k) for k in np.unique(k_pads)):
+        members = np.flatnonzero(k_pads == k)
+        total, tops = optimum(members, k)
+        if classes:
+            _, m_prev, total_prev, _ = classes[-1]
+            both = np.concatenate([m_prev, members])
+            total_both, tops_both = optimum(both, k)
+            if total_both < total_prev + total:
+                classes[-1] = (k, both, total_both, tops_both)
                 continue
-            added, (s2, k2) = best
-            m2 = merged.pop((s2, k2))
-            merged.pop((s1, k1))
-            key = (max(s1, s2), max(k1, k2))
-            combined = np.sort(np.concatenate([m1, m2]))
-            if key in merged:
-                combined = np.sort(np.concatenate([merged[key], combined]))
-            merged[key] = combined
-            added_total += added
-            skip.clear()  # a merge changes the partner landscape
-            progressed = True
-            break  # re-sort candidates against the new bucket set
-        if not progressed:
-            break
-    return merged
+        classes.append((k, members, total, tops))
+    layout: dict[tuple[int, int], np.ndarray] = {}
+    for _, members, _, tops in classes:
+        which = np.searchsorted(tops, s_pads[members])
+        for b, top in enumerate(tops):
+            inside = np.sort(members[which == b])
+            # of a joined class, a bucket that holds narrow entities only stays narrow
+            layout[(int(top), int(k_pads[inside].max()))] = inside
+    return layout
 
 
 def build_random_effect_dataset(
@@ -254,7 +265,7 @@ def build_random_effect_dataset(
     dtype=jnp.float32,
     min_samples_pad: int = 8,
     min_features_pad: int = 4,
-    bucket_merge_fraction: Optional[float] = None,
+    bucket_cost: Optional[float] = None,
     scoring_only: bool = False,
     projector: Optional[object] = None,
     entity_order: Optional[Sequence] = None,
@@ -270,6 +281,9 @@ def build_random_effect_dataset(
     - ``normalization``: applied to the materialized blocks (x' = (x-shift)*factor);
       models are converted back to original space after the solve, so scoring and
       model export always live in the original space.
+    - ``bucket_cost``: what one more bucket costs, in padded cells, for
+      ``bucket_layout`` (heights then any multiple of ``min_samples_pad``);
+      default: the backend's (``_bucket_policy``).
     - ``scoring_only``: skip training-bucket materialization entirely (validation /
       transform datasets only need the per-sample scoring view); caps, lower-bound
       filtering and Pearson selection don't apply to scoring data.
@@ -466,38 +480,32 @@ def build_random_effect_dataset(
             s_cols[rows_per_nnz[keep], slot_per_nnz[keep]] = local[keep]
             s_vals[rows_per_nnz[keep], slot_per_nnz[keep]] = X.data[keep]
 
-    with span("ingest.re_buckets", re_type=re_type):
+    with span("ingest.re_buckets", re_type=re_type) as buckets_span:
         # ---- bucketing by (padded sample count, padded feature count) ---------------
         norm_factors = None if normalization is None or normalization.factors is None else np.asarray(normalization.factors)
         norm_shifts = None if normalization is None or normalization.shifts is None else np.asarray(normalization.shifts)
 
         k_counts = np.asarray([len(c) for c in col_of], dtype=np.int64)
+        k_pads = np.asarray(
+            [_next_pow2(max(int(k), 1), min_features_pad) for k in k_counts],
+            dtype=np.int64,
+        )
         bucket_members: dict[tuple[int, int], np.ndarray] = {}
-        if n_ent:
-            s_pads = np.asarray([_next_pow2(int(c), min_samples_pad) for c in lens])
-            k_pads = np.asarray(
-                [_next_pow2(max(int(k), 1), min_features_pad) for k in k_counts]
+        if n_ent and not scoring_only:  # scoring datasets hold no buckets
+            cost, pow2_heights = _bucket_policy(bucket_cost)
+            bucket_members = bucket_layout(
+                lens, k_pads, cost, min_rows=min_samples_pad, pow2_heights=pow2_heights
             )
-            pad_keys = s_pads * (2 ** 32) + k_pads
-            for key in np.unique(pad_keys):
-                members = np.flatnonzero(pad_keys == key)
-                bucket_members[(int(key >> 32), int(key & (2 ** 32 - 1)))] = members
-            if not scoring_only:  # scoring datasets discard the buckets entirely
-                bucket_members = _consolidate_buckets(
-                    bucket_members, n_ent, _resolve_merge_fraction(bucket_merge_fraction)
-                )
 
-        # Dataset-wide projection table is as wide as the widest PADDED bucket so that
+        # Dataset-wide projection table is as wide as the widest PADDED entity so that
         # bucket slices coeffs_global[:, :K_bucket] always fit.
-        max_k_all = max((k for _, k in bucket_members), default=min_features_pad)
+        max_k_all = int(k_pads.max()) if n_ent else min_features_pad
         proj_table = np.full((n_ent, max_k_all), -1, dtype=np.int32)
         for i, cols in enumerate(col_of):
             proj_table[i, : len(cols)] = cols
 
         # padded blocks as HOST arrays: placed together in the ingest.h2d span below
         host_buckets: list[tuple] = []
-        if scoring_only:
-            bucket_members = {}
         scale_arr = np.asarray([weights_scale[e] for e in entities], dtype=np.float64)
         local_of_act_nnz = local[act_nnz_idx] if total_act_nnz else local[:0]
 
@@ -569,9 +577,11 @@ def build_random_effect_dataset(
             slots = None
         else:
             slots = np.where(slots >= 0, slots, slot_base).astype(np.int32)
+        # the layout on the record: slot_base has counted E_b * S_b over the buckets
+        padded_rows = slot_base
+        buckets_span.attrs.update(buckets=n_buckets, padded_rows=padded_rows)
 
     n_active = sum(len(active_rows[e]) for e in entities)
-    padded_rows = sum(Xb.shape[0] * Xb.shape[1] for _rows, Xb, *_rest in host_buckets)
     # device placement, synced: the one span that waits for the device (set-up
     # only), so that host index building and H2D are separate numbers
     with span("ingest.h2d", re_type=re_type):

@@ -368,10 +368,11 @@ def test_all_entities_filtered_returns_empty_dataset():
 
 
 def test_bucket_consolidation_parity_and_guard(rng):
-    """Rare shape classes merge into larger buckets without changing results;
-    a pathological huge entity must NOT inflate everyone's sample axis."""
+    """Buckets merge where a bucket costs more than the padding that saves it,
+    without changing results; a pathological huge entity must NOT inflate
+    everyone's sample axis."""
     X, ents, labels, _ = make_re_data(rng, n_entities=40, min_s=4, max_s=9)
-    # one rare large entity (its own shape class, 1/41 < 5%)
+    # one rare large entity, a height of its own
     extra_n = 200
     Xe = sp.vstack([X, sp.csr_matrix(np.ones((extra_n, X.shape[1])))]).tocsr()
     ents_e = np.concatenate([ents, np.asarray(["big"] * extra_n, dtype=object)])
@@ -379,15 +380,18 @@ def test_bucket_consolidation_parity_and_guard(rng):
 
     merged = build_random_effect_dataset(
         Xe, ents_e, "entity", labels=labels_e, dtype=jnp.float64,
-        bucket_merge_fraction=0.05,  # explicit: auto resolves to 0 on CPU
+        # explicit: on the CPU the backend's own answer is 0. Widening the
+        # 8-row entities to their widest neighbours pads a few thousand cells;
+        # raising all 40 to the big entity's 200 rows would pad over 30,000.
+        bucket_cost=6000.0,
     )
     unmerged = build_random_effect_dataset(
         Xe, ents_e, "entity", labels=labels_e, dtype=jnp.float64,
-        bucket_merge_fraction=0.0,
+        bucket_cost=0.0,
     )
     assert len(merged.buckets) < len(unmerged.buckets)  # a merge DID happen
-    # guard: the big entity's 256-row shape class must not swallow the small
-    # buckets' sample axis (added padding would exceed total cells)
+    # guard: the big entity's 256-row bucket must not swallow the small
+    # buckets' sample axis (the padding would cost more than the bucket)
     small_s = [b.X.shape[1] for b in merged.buckets if b.n_entities > 1]
     assert small_s and max(small_s) <= 64
 

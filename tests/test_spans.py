@@ -369,7 +369,7 @@ def test_padding_waste_matches_a_hand_built_dataset():
     t0 = time.time_ns()
     dataset = build_random_effect_dataset(
         sp.csr_matrix(np.ones((n, 1))), ids, "entity", labels=np.zeros(n),
-        bucket_merge_fraction=0.0,
+        bucket_cost=0.0,
     )
     assert sorted((b.n_entities, b.shape[0]) for b in dataset.buckets) == [(1, 16), (2, 8)]
     assert dataset.padding_waste == pytest.approx(1.0 - 17 / 32)
