@@ -588,8 +588,10 @@ def run_coordinate_descent(
                     with span("descent.validate_score", cid=cid):
                         val_scores[cid] = score_model_on_dataset(model, validation_datasets[cid])
                         total_val = sum(val_scores.values())
-                    # ends in the evaluators' own host read of the scores
-                    with span("descent.evaluate", cid=cid):
+                    # ends in the evaluators' own host read: the scores, or
+                    # (metric_path="device") the few integers of a device metric
+                    metric_path = evaluation_suite.metric_path(total_val)
+                    with span("descent.evaluate", cid=cid, metric_path=metric_path):
                         metrics = evaluation_suite.evaluate(total_val)
                     metrics_history.append((iteration, cid, metrics))
                     metric = metrics[primary.name]

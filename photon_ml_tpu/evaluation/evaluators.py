@@ -5,21 +5,38 @@ Re-creates the reference evaluation stack (photon-lib evaluation/EvaluationSuite
 evaluators: AreaUnderROCCurveLocalEvaluator.scala:72, PrecisionAtKLocalEvaluator.scala:76,
 RMSE/loss evaluators, EvaluatorFactory.scala:65).
 
-TPU design: a metric is a pure function over (scores, labels, weights) arrays. AUC is
-the rank-statistic form (sort once, tie-averaged ranks) — O(n log n) on device. The
-MultiEvaluator (per-group AUC averaged over groups, e.g. per-user AUC) replaces the
-reference's groupByKey with a host-side sort + segmented evaluation.
+TPU design: a metric is a pure function over (scores, labels, weights) arrays.
+
+On the HOST (NumPy, float64): every free metric function here. ``auc_roc`` is the
+rank-statistic form (one merge sort, tie groups by ``reduceat``) and stays the
+reference; AUPR, RMSE and PRECISION@k likewise. The MultiEvaluator (per-group AUC
+averaged over groups, e.g. per-user AUC) replaces the reference's groupByKey with
+a host-side sort + segmented evaluation. The pointwise losses reduce in ``jnp``.
+
+On the DEVICE: ``EvaluationSuite.evaluate`` computes the plain unweighted AUC where
+the scores already are (``_auc_rank_sums``: one sort, three scans) and reads back
+six integers, where ``EvaluationSuite._on_device`` admits the input; everything
+else reads the scores to the host once and runs the functions above. The device
+path is INTEGER arithmetic because its result has to be the host's bit for bit:
+the benchmark compares the last validation metric under 3e-6 and best-model
+selection compares successive metrics with ``>``. With unit weights every
+quantity of ``auc_roc`` is an integer or a half-integer under 2^53, so its
+float64 result does not depend on the order of its sums, and an exact integer
+count followed by the same one division is the same float64. A float32 rank sum
+would be a different number.
 """
 
 from __future__ import annotations
 
 import dataclasses
 import enum
+import functools
 from typing import Callable, Optional, Sequence
 
 import jax
 import jax.numpy as jnp
 import numpy as np
+from jax import lax
 
 from photon_ml_tpu.function.losses import (
     logistic_loss,
@@ -69,6 +86,67 @@ def auc_roc(scores, labels, weights=None) -> float:
     cum_neg_below = np.concatenate([[0.0], np.cumsum(grp_neg)[:-1]])
     num = float(np.sum(grp_pos * (cum_neg_below + 0.5 * grp_neg)))
     return float(num / (w_pos_total * w_neg_total))
+
+
+# The device AUC's integer sums are proven for label counts to this bound: a
+# positive's term (below) is at most 2 * (2^24 - 1) < 2^25, so four 7-bit limbs
+# hold it, and a limb's sum over all rows is at most 2^24 * 127 < 2^31.
+DEVICE_AUC_MAX_ROWS = 1 << 24
+_LIMB_BITS = 7
+_LIMBS = 4
+
+
+def _ordered_keys(scores):
+    """Scores as integers of their own width whose order and equality are those
+    of ``auc_roc``'s float64 merge sort: ``-0.0 == 0.0``, every NaN last. Bit
+    operations only, so no backend's treatment of subnormals can move a tie."""
+    itype = {4: jnp.int32, 8: jnp.int64}[scores.dtype.itemsize]
+    top = jnp.iinfo(itype).max
+    inf = lax.bitcast_convert_type(jnp.asarray(jnp.inf, scores.dtype), itype)
+    raw = lax.bitcast_convert_type(scores, itype)
+    magnitude = raw & top  # the sign bit cleared
+    return jnp.where(magnitude > inf, top, jnp.where(raw < 0, -magnitude, magnitude))
+
+
+@jax.jit
+def _auc_rank_sums(scores, positive):
+    """``int32[_LIMBS + 2]``: the limb sums of ``2 * num`` of ``auc_roc`` with
+    unit weights, then the two class counts.
+
+    For every positive row the integer 2 * (negatives strictly below its tie
+    group) + (negatives inside its tie group), i.e. (negatives below the group)
+    + (negatives to the group's end); the sum of those reaches 2^47, so it
+    leaves the device as sums of 7-bit limbs that cannot overflow an int32
+    (``DEVICE_AUC_MAX_ROWS``). Tie groups are ``auc_roc``'s: a row starts one
+    where ``diff != 0``, which also holds between equal infinities and between
+    NaNs (their difference is NaN), so among those the merge sort's row order
+    counts: the row index is the second sort key, with the label in its low
+    bit. (Written so for the TPU compiler's sake: ``is_stable=True`` and a
+    ``reverse=True`` running minimum each more than double the program's cold
+    compile, ``PERF.md`` §6, PR 36.)"""
+    n = positive.shape[0]
+    keys = _ordered_keys(scores[:n])
+    row_and_label = 2 * jnp.arange(n, dtype=jnp.int32) + positive.astype(jnp.int32)
+    keys, row_and_label = lax.sort((keys, row_and_label), num_keys=2, is_stable=False)
+    pos = row_and_label & 1
+    neg = 1 - pos
+    neg_to_here = jnp.cumsum(neg, dtype=jnp.int32)
+    not_finite = jnp.abs(keys[1:]) >= _ordered_keys(jnp.asarray(jnp.inf, scores.dtype))
+    differs = (keys[1:] != keys[:-1]) | not_finite
+    edge = jnp.ones((1,), bool)
+    starts = jnp.concatenate([edge, differs])
+    ends = jnp.concatenate([differs, edge])
+    # both counts are non-decreasing along the sort, so a running maximum
+    # carries a group's first value forward and a running minimum from the
+    # far end carries its last value back: no segment loop
+    neg_below_group = lax.cummax(jnp.where(starts, neg_to_here - neg, 0))
+    neg_to_group_end = lax.cummin(jnp.where(ends, neg_to_here, n)[::-1])[::-1]
+    terms = pos * (neg_below_group + neg_to_group_end)
+    shifts = _LIMB_BITS * jnp.arange(_LIMBS, dtype=jnp.int32)
+    limbs = (terms[:, None] >> shifts) & ((1 << _LIMB_BITS) - 1)
+    limb_sums = jnp.sum(limbs, axis=0, dtype=jnp.int32)
+    n_pos = jnp.sum(pos, dtype=jnp.int32)
+    return jnp.concatenate([limb_sums, jnp.stack([n_pos, n - n_pos])])
 
 
 def auc_pr(scores, labels, weights=None) -> float:
@@ -248,18 +326,70 @@ class EvaluationSuite:
     def primary(self):
         return self.evaluators[0]
 
+    @functools.cached_property
+    def _unit_weights_zero_offsets(self) -> bool:
+        return bool(np.all(np.asarray(self.weights) == 1) and np.all(np.asarray(self.offsets) == 0))
+
+    @functools.cached_property
+    def _positive(self):
+        """The labels as ``auc_roc`` reads them, placed once per suite (at its
+        first device evaluation, so a warm-up pays it): an explicit, uncommitted
+        ``device_put``, so the program runs wherever the scores are."""
+        return jax.device_put(np.asarray(self.labels, dtype=np.float64) > 0.5)
+
+    def _on_device(self, evaluator, raw_scores) -> bool:
+        """THE rule of the device path, read from the input alone (no option, no
+        backend): the plain ``AUC`` evaluator, unit weights and zero offsets (f32
+        + f64 zero is the f32 value, so order and ties are the host's), a float
+        score vector on ONE device (a mesh-placed score keeps the host path),
+        at least as long as the labels, whose count is one the integer sums are
+        proven for. Both paths give the same bits, so nothing is chosen but time."""
+        return (
+            isinstance(evaluator, Evaluator)
+            and evaluator.fn is auc_roc
+            and isinstance(raw_scores, jax.Array)
+            and raw_scores.ndim == 1
+            and raw_scores.dtype in (jnp.float32, jnp.float64)
+            and len(raw_scores.devices()) == 1
+            and 0 < len(self.labels) <= min(raw_scores.shape[0], DEVICE_AUC_MAX_ROWS)
+            and self._unit_weights_zero_offsets
+        )
+
+    def metric_path(self, raw_scores) -> str:
+        """``device`` where ``evaluate(raw_scores)`` leaves the scores on the
+        device (every evaluator is served there), else ``host``: the
+        ``metric_path`` attribute of the ``descent.evaluate`` span."""
+        on_device = all(self._on_device(ev, raw_scores) for ev in self.evaluators)
+        return "device" if on_device else "host"
+
+    def _device_auc(self, raw_scores) -> float:
+        sums = jax.device_get(_auc_rank_sums(raw_scores, self._positive))
+        *limbs, n_pos, n_neg = (int(v) for v in sums)
+        if n_pos == 0 or n_neg == 0:
+            return float("nan")
+        twice_num = sum(limb << (_LIMB_BITS * i) for i, limb in enumerate(limbs))
+        # auc_roc's own last line: num is a half-integer under 2^53, exact
+        return (twice_num / 2) / (n_pos * n_neg)
+
     def evaluate(self, raw_scores) -> dict[str, float]:
         """raw_scores are coordinate-score sums; offsets are added before metrics
         (reference: scores + offsets, EvaluationSuite.evaluate:56-81).
 
         Scores longer than the label array are sliced: mesh placement pads the
         sample axis to the device count and padded rows are metric-inert."""
-        # the one device->host transfer of a validation round, named so that
-        # runtime_guard.sync_discipline regions can hold a validating fit
-        total = np.asarray(jax.device_get(raw_scores))[: len(self.labels)] + self.offsets
+        on_device = [self._on_device(ev, raw_scores) for ev in self.evaluators]
+        # the device->host transfers of a validation round, named so that
+        # runtime_guard.sync_discipline regions can hold a validating fit: the
+        # scores, ONCE, where some evaluator needs them on the host; six
+        # integers for each evaluator served on the device (_device_auc)
+        total = None
+        if not all(on_device):
+            total = np.asarray(jax.device_get(raw_scores))[: len(self.labels)] + self.offsets
         results: dict[str, float] = {}
-        for ev in self.evaluators:
-            if isinstance(ev, MultiEvaluator):
+        for ev, served in zip(self.evaluators, on_device):
+            if served:
+                results[ev.name] = self._device_auc(raw_scores)
+            elif isinstance(ev, MultiEvaluator):
                 if not self.id_columns or ev.id_tag not in self.id_columns:
                     raise ValueError(f"Missing id column {ev.id_tag!r} for {ev.name}")
                 results[ev.name] = ev.evaluate_grouped(

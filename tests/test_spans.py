@@ -217,6 +217,13 @@ def test_update_spans_carry_coordinate_kind_and_iteration(fit):
     assert [r.attrs["cid"] for r in _spans(fit, "descent.init_score")] == list(COORDINATES)
 
 
+def test_evaluate_spans_say_where_the_metric_was_computed(fit):
+    """The default AUC suite over one-device scores, unit weights and zero
+    offsets is served on the device (``EvaluationSuite.metric_path``)."""
+    got = [(r.attrs["cid"], r.attrs["metric_path"]) for r in _spans(fit, "descent.evaluate")]
+    assert got == [(cid, "device") for _ in range(PASSES) for cid in COORDINATES]
+
+
 @pytest.mark.parametrize("start", ["fresh", "warm"])
 def test_init_score_spans_say_whether_the_kernel_scored(fit, start):
     """A fresh fit's random effects answer their initial score themselves
