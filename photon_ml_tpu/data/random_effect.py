@@ -273,6 +273,13 @@ def build_random_effect_dataset(
 ) -> RandomEffectDataset:
     """Host-side construction of the bucketed random-effect dataset.
 
+    Two indexes build the same dataset. Where every row of ``X`` stores the
+    same columns (and no Pearson selection runs), the index works on the N
+    rows: each entity's projection is that column set and a block row is a
+    copy of a sample's row (``_index_rows``). Otherwise it works on the
+    stored entries (``_index_nnz``). The spans ``ingest.re_index`` and
+    ``ingest.re_buckets`` name the one that ran: ``index="rows" | "nnz"``.
+
     - ``active_data_upper_bound``: reservoir cap; kept samples get weight * n/cap
       (RandomEffectDataset.scala:358-420). Overflow samples become passive.
     - ``active_data_lower_bound``: entities with fewer active samples train no model
@@ -335,159 +342,35 @@ def build_random_effect_dataset(
     ent = np.asarray(entity_ids_per_sample)
     if len(ent) != n:
         raise ValueError("entity ids and sample count mismatch")
+    labels_arr = None if labels is None else np.asarray(labels, dtype=np.float64)
+    # the index by rows where every row stores the same columns (Pearson
+    # selection is per entity, so it keeps to the index by non-zeros)
+    shared = _shared_columns(X) if features_max is None else None
+    kind = "nnz" if shared is None else "rows"
 
-    with span("ingest.re_index", re_type=re_type):
-        # ---- group samples by entity (the one-time 'shuffle') -----------------------
-        order = np.argsort(ent, kind="mergesort")
-        sorted_ent = ent[order]
-        boundaries = np.flatnonzero(sorted_ent[1:] != sorted_ent[:-1]) + 1
-        if n:
-            starts = np.concatenate([[0], boundaries])
-            stops = np.concatenate([boundaries, [n]])
-        else:  # empty input (e.g. an empty validation split): no groups at all
-            starts = stops = boundaries
-
-        active_rows: dict = {}
-        weights_scale: dict = {}
-        passive_count = 0
-        for a, b in zip(starts, stops):
-            e_id = sorted_ent[a]
-            rows = order[a:b]
-            count = len(rows)
-            if active_data_upper_bound is not None and count > active_data_upper_bound:
-                rng = np.random.default_rng(_entity_seed(str(e_id), seed))
-                keys = rng.random(count)
-                keep = rows[np.argsort(keys, kind="mergesort")[: active_data_upper_bound]]
-                active_rows[e_id] = np.sort(keep)
-                weights_scale[e_id] = count / active_data_upper_bound
-                passive_count += count - active_data_upper_bound
-            else:
-                active_rows[e_id] = np.sort(rows)
-                weights_scale[e_id] = 1.0
-
-        # lower-bound filter: entities below the threshold train no model
-        entities = [e for e, rows in active_rows.items() if len(rows) >= active_data_lower_bound]
-        if exclude_entities:
-            entities = [e for e in entities if e not in exclude_entities]
-        if entity_order is not None:
-            # stable growth: known entities keep the caller's row order, unseen
-            # ones append sorted at the tail (continuous-training alignment)
-            present = set(entities)
-            known = [e for e in entity_order if e in present]
-            known_set = set(known)
-            entities = known + sorted(e for e in entities if e not in known_set)
-        else:
-            entities.sort()
-        row_of_entity = {e: i for i, e in enumerate(entities)}
-        n_ent = len(entities)
-        labels_arr = None if labels is None else np.asarray(labels, dtype=np.float64)
-
-        # Flat active-sample machinery shared by the (vectorized) observed-column
-        # computation and the bucket fill: one concatenated row list replaces the
-        # per-entity scipy CSR slicing that dominated build time at 100k+ entities.
-        lens = np.asarray([len(active_rows[e]) for e in entities], dtype=np.int64)
-        act_concat = (
-            np.concatenate([active_rows[e] for e in entities])
-            if n_ent
-            else np.zeros(0, dtype=np.int64)
+    with span("ingest.re_index", re_type=re_type, index=kind):
+        groups = _group_by_entity(
+            ent,
+            upper=active_data_upper_bound,
+            lower=active_data_lower_bound,
+            exclude=exclude_entities,
+            entity_order=entity_order,
+            seed=seed,
         )
-        ent_row_per_act = np.repeat(np.arange(n_ent, dtype=np.int64), lens)
-        act_starts = np.concatenate([[0], np.cumsum(lens)[:-1]]) if n_ent else lens
-        s_local_per_act = np.arange(len(act_concat)) - np.repeat(act_starts, lens)
-        # active nnz: global nnz positions of every active sample's entries
-        counts_all = np.diff(X.indptr)
-        c_act = counts_all[act_concat]
-        total_act_nnz = int(c_act.sum())
-        nnz_cum = np.concatenate([[0], np.cumsum(c_act)[:-1]]) if len(c_act) else c_act
-        act_nnz_idx = (
-            np.repeat(X.indptr[act_concat], c_act)
-            + (np.arange(total_act_nnz) - np.repeat(nnz_cum, c_act))
-        ).astype(np.int64)
-        ent_of_act_nnz = np.repeat(ent_row_per_act, c_act)
-        s_local_of_act_nnz = np.repeat(s_local_per_act, c_act)
-
-        # ---- per-entity projection (+ optional Pearson selection) -------------------
-        # col_of[i]: sorted global col ids observed in entity i's ACTIVE rows.
-        if n_ent == 0:
-            col_of = []
-        elif features_max is None:
-            keys = ent_of_act_nnz * d + X.indices[act_nnz_idx].astype(np.int64)
-            uniq_keys = np.unique(keys)
-            ent_of_obs = uniq_keys // d
-            obs_counts = np.bincount(ent_of_obs, minlength=n_ent)
-            col_of = np.split(
-                (uniq_keys % d).astype(np.int32), np.cumsum(obs_counts)[:-1]
-            )
+        if shared is None:
+            index = _index_nnz(X, groups, features_max, labels_arr, intercept_index)
         else:
-            # Pearson feature selection needs per-entity column/label statistics —
-            # the per-entity loop stays on this opt-in path only.
-            col_of = []
-            for e in entities:
-                rows = active_rows[e]
-                sub = X[rows]  # csr [s, d]
-                observed = np.unique(sub.indices) if sub.nnz else np.array([], dtype=np.int32)
-                if len(observed) > features_max:
-                    if labels_arr is None:
-                        raise ValueError("features_max (Pearson selection) requires labels")
-                    scores = _pearson_scores(sub, observed, labels_arr[rows])
-                    keep_order = np.argsort(-scores, kind="mergesort")
-                    kept = set(observed[keep_order[:features_max]].tolist())
-                    if intercept_index is not None:
-                        kept.add(intercept_index)
-                    observed = np.asarray(sorted(kept), dtype=observed.dtype)
-                col_of.append(observed.astype(np.int32))
+            index = _index_rows(X, groups, shared)
 
-        # ---- global nnz -> entity-local column mapping ------------------------------
-        # local col = position of the global col in the entity's projection row.
-        # Vectorized over all nnz: a dense [E, D] lookup when it fits, else per-entity
-        # dict fallback (huge-D regimes). Used by BOTH the bucket fill (through
-        # act_nnz_idx) and the per-sample scoring view.
-        # map each sample's entity to its row id (vectorized: entities is sorted)
-        s_ent_rows = np.full(n, -1, dtype=np.int32)
-        uniq = np.asarray(entities)
-        if len(uniq):
-            # entity_order may leave `uniq` unsorted: search through a sorter so
-            # the lookup stays vectorized either way (identity when sorted)
-            sorter = np.argsort(uniq, kind="mergesort")
-            pos = np.searchsorted(uniq, ent, sorter=sorter)
-            rows = sorter[np.clip(pos, 0, len(uniq) - 1)]
-            hit = uniq[rows] == ent
-            s_ent_rows = np.where(hit, rows, -1).astype(np.int32)
-
-        local = np.full(X.nnz, -1, dtype=np.int32)
-        if n and X.nnz:
-            rows_per_nnz = np.repeat(np.arange(n), counts_all)
-            slot_per_nnz = np.arange(X.nnz) - np.repeat(X.indptr[:-1], counts_all)
-            ent_per_nnz = s_ent_rows[rows_per_nnz]
-            valid = ent_per_nnz >= 0
-            if n_ent * d <= 50_000_000:
-                lookup = np.full((max(n_ent, 1), d), -1, dtype=np.int32)
-                for i, cols in enumerate(col_of):
-                    lookup[i, cols] = np.arange(len(cols), dtype=np.int32)
-                local[valid] = lookup[ent_per_nnz[valid], X.indices[valid]]
-            else:
-                local_of = [{int(c): k for k, c in enumerate(cols)} for cols in col_of]
-                idx_valid = np.flatnonzero(valid)
-                for t in idx_valid:
-                    local[t] = local_of[ent_per_nnz[t]].get(int(X.indices[t]), -1)
-
-        # ---- per-sample scoring view over the FULL sample axis ----------------------
-        nnz_max = max(int(counts_all.max()) if n else 1, 1)
-        s_cols = np.full((n, nnz_max), -1, dtype=np.int32)
-        s_vals = np.zeros((n, nnz_max), dtype=np.float64)
-        if n and X.nnz:
-            keep = local >= 0
-            s_cols[rows_per_nnz[keep], slot_per_nnz[keep]] = local[keep]
-            s_vals[rows_per_nnz[keep], slot_per_nnz[keep]] = X.data[keep]
-
-    with span("ingest.re_buckets", re_type=re_type) as buckets_span:
+    with span("ingest.re_buckets", re_type=re_type, index=kind) as buckets_span:
         # ---- bucketing by (padded sample count, padded feature count) ---------------
         norm_factors = None if normalization is None or normalization.factors is None else np.asarray(normalization.factors)
         norm_shifts = None if normalization is None or normalization.shifts is None else np.asarray(normalization.shifts)
 
-        k_counts = np.asarray([len(c) for c in col_of], dtype=np.int64)
+        n_ent = len(groups.entities)
+        lens = groups.lens
         k_pads = np.asarray(
-            [_next_pow2(max(int(k), 1), min_features_pad) for k in k_counts],
+            [_next_pow2(max(int(k), 1), min_features_pad) for k in index.k_counts],
             dtype=np.int64,
         )
         bucket_members: dict[tuple[int, int], np.ndarray] = {}
@@ -501,34 +384,27 @@ def build_random_effect_dataset(
         # bucket slices coeffs_global[:, :K_bucket] always fit.
         max_k_all = int(k_pads.max()) if n_ent else min_features_pad
         proj_table = np.full((n_ent, max_k_all), -1, dtype=np.int32)
-        for i, cols in enumerate(col_of):
-            proj_table[i, : len(cols)] = cols
+        col_entity = np.repeat(np.arange(n_ent), index.k_counts)
+        col_slot = _positions(index.k_counts)
+        proj_table[col_entity, col_slot] = index.cols
+        shift_rows = None
+        if norm_shifts is not None:
+            # x' = (x - shift) * factor = x*factor - shift*factor: the shift term
+            # applies to every VALID (sample, observed-col) cell, zeros included.
+            sh = -norm_shifts[index.cols]
+            if norm_factors is not None:
+                sh = sh * norm_factors[index.cols]
+            shift_rows = np.zeros((n_ent, max_k_all))
+            shift_rows[col_entity, col_slot] = sh
 
         # padded blocks as HOST arrays: placed together in the ingest.h2d span below
         host_buckets: list[tuple] = []
-        scale_arr = np.asarray([weights_scale[e] for e in entities], dtype=np.float64)
-        local_of_act_nnz = local[act_nnz_idx] if total_act_nnz else local[:0]
-
-        # One stable sort groups the flat sample/nnz arrays by bucket, so each
-        # bucket gets a contiguous slice instead of re-scanning everything
-        # (O(total_nnz) overall, not O(buckets x total_nnz)).
         sorted_keys = sorted(bucket_members.items())
-        n_buckets = len(sorted_keys)
-        bucket_id = np.full(max(n_ent, 1), -1, dtype=np.int64)
-        e_local_all = np.zeros(max(n_ent, 1), dtype=np.int64)
-        for b, (_, members) in enumerate(sorted_keys):
-            bucket_id[members] = b
-            e_local_all[members] = np.arange(len(members))
-        act_order = np.argsort(bucket_id[ent_row_per_act], kind="stable") if n_ent else ent_row_per_act
-        act_bounds = np.searchsorted(
-            bucket_id[ent_row_per_act][act_order], np.arange(n_buckets + 1)
-        )
-        nnz_bucket = bucket_id[ent_of_act_nnz] if total_act_nnz else ent_of_act_nnz
-        nnz_valid_local = local_of_act_nnz >= 0
-        nnz_order = np.argsort(np.where(nnz_valid_local, nnz_bucket, -1), kind="stable")
-        nnz_bounds = np.searchsorted(
-            np.where(nnz_valid_local, nnz_bucket, -1)[nnz_order], np.arange(n_buckets + 1)
-        )
+        if shared is None:
+            fill_block = _nnz_filler(X, index, sorted_keys, norm_factors)
+        else:
+            fill_block = _row_filler(X, shared, norm_factors)
+        act_starts = np.cumsum(lens) - lens
         # sample_slots (see RandomEffectDataset): filled bucket by bucket below,
         # samples of no bucket then sent to the zero slot one past the blocks
         slots = np.full(n, -1, dtype=np.int64)
@@ -539,41 +415,23 @@ def build_random_effect_dataset(
             yb = np.zeros((eb, s_pad), dtype=np.float64)
             wb = np.zeros((eb, s_pad), dtype=np.float64)
             sb = np.full((eb, s_pad), -1, dtype=np.int32)
-            # sample-level fills (contiguous bucket slice)
-            ai = act_order[act_bounds[b] : act_bounds[b + 1]]
-            el_s, sl_s, rows_s = e_local_all[ent_row_per_act[ai]], s_local_per_act[ai], act_concat[ai]
+            # sample-level fills: the members' runs of the active samples
+            m_lens = lens[members]
+            el_s = np.repeat(np.arange(eb), m_lens)
+            sl_s = _positions(m_lens)
+            rows_s = groups.act_rows[np.repeat(act_starts[members], m_lens) + sl_s]
             if labels_arr is not None:
                 yb[el_s, sl_s] = labels_arr[rows_s]
-            wb[el_s, sl_s] = base_weights[rows_s] * scale_arr[ent_row_per_act[ai]]
+            wb[el_s, sl_s] = base_weights[rows_s] * np.repeat(groups.scale[members], m_lens)
             sb[el_s, sl_s] = rows_s
             slots[rows_s] = slot_base + el_s * s_pad + sl_s
             slot_base += eb * s_pad
-            # nnz-level X fill (duplicate (row, col) entries sum, as toarray does;
-            # bincount over raveled indices = vectorized scatter-add)
-            ni = nnz_order[nnz_bounds[b] : nnz_bounds[b + 1]]
-            gv = X.data[act_nnz_idx[ni]].astype(np.float64)
-            gc = X.indices[act_nnz_idx[ni]]
-            if norm_factors is not None:
-                gv = gv * norm_factors[gc]
-            flat = np.ravel_multi_index(
-                (e_local_all[ent_of_act_nnz[ni]], s_local_of_act_nnz[ni], local_of_act_nnz[ni]),
-                Xb.shape,
-            )
-            Xb += np.bincount(flat, weights=gv, minlength=Xb.size).reshape(Xb.shape)
-            if norm_shifts is not None:
-                # x' = (x - shift) * factor = x*factor - shift*factor: the shift term
-                # applies to every VALID (sample, observed-col) cell, zeros included.
-                base = np.zeros((eb, k_pad))
-                for bi, i in enumerate(members):
-                    cols = col_of[i]
-                    sh = -norm_shifts[cols]
-                    if norm_factors is not None:
-                        sh = sh * norm_factors[cols]
-                    base[bi, : len(cols)] = sh
-                row_valid = np.arange(s_pad)[None, :] < lens[members][:, None]
-                Xb += base[:, None, :] * row_valid[:, :, None]
+            fill_block(b, Xb, el_s, sl_s, rows_s)
+            if shift_rows is not None:
+                row_valid = np.arange(s_pad)[None, :] < m_lens[:, None]
+                Xb += shift_rows[members, :k_pad][:, None, :] * row_valid[:, :, None]
             host_buckets.append((members.astype(np.int32), Xb, yb, wb, sb))
-        if scoring_only or passive_count or slot_base >= np.iinfo(np.int32).max:
+        if scoring_only or groups.n_passive or slot_base >= np.iinfo(np.int32).max:
             slots = None
         else:
             slots = np.where(slots >= 0, slots, slot_base).astype(np.int32)
@@ -581,11 +439,11 @@ def build_random_effect_dataset(
         # buckets, the lanes are the E_b (the systems a direct solve factors)
         padded_rows = slot_base
         buckets_span.attrs.update(
-            buckets=n_buckets, padded_rows=padded_rows,
+            buckets=len(sorted_keys), padded_rows=padded_rows,
             lanes=sum(int(hb[1].shape[0]) for hb in host_buckets),
         )
 
-    n_active = sum(len(active_rows[e]) for e in entities)
+    n_active = int(lens.sum())
     # device placement, synced: the one span that waits for the device (set-up
     # only), so that host index building and H2D are separate numbers
     with span("ingest.h2d", re_type=re_type):
@@ -602,16 +460,16 @@ def build_random_effect_dataset(
         del host_buckets
         placed = (
             jnp.asarray(proj_table),
-            jnp.asarray(s_ent_rows),
-            jnp.asarray(s_cols),
-            jnp.asarray(s_vals, dtype=dtype),
+            jnp.asarray(groups.sample_rows),
+            jnp.asarray(index.s_cols),
+            jnp.asarray(index.s_vals, dtype=dtype),
             None if slots is None else jnp.asarray(slots),
         )
         jax.block_until_ready((buckets, placed))
     return RandomEffectDataset(
         re_type=re_type,
         feature_shard_id=feature_shard_id,
-        entity_ids=tuple(entities),
+        entity_ids=tuple(groups.entities),
         buckets=buckets,
         proj_indices=placed[0],
         sample_entity_rows=placed[1],
@@ -619,11 +477,285 @@ def build_random_effect_dataset(
         sample_vals=placed[3],
         n_samples=n,
         n_active_samples=n_active,
-        n_passive_samples=passive_count,
+        n_passive_samples=groups.n_passive,
         projector=projector,
         padding_waste=1.0 - n_active / padded_rows if padded_rows else 0.0,
         sample_slots=placed[4],
     )
+
+
+def _positions(lens: np.ndarray) -> np.ndarray:
+    """Each element's position inside its run, for runs of ``lens`` elements
+    laid end to end: ``[0, 1, 2, 0, 1]`` for ``[3, 2]``."""
+    lens = np.asarray(lens, dtype=np.int64)
+    return np.arange(int(lens.sum())) - np.repeat(np.cumsum(lens) - lens, lens)
+
+
+def _shared_columns(X: sp.csr_matrix) -> Optional[np.ndarray]:
+    """The one column set every row of ``X`` stores, ascending, or None where
+    the rows differ in their count of stored entries, in their columns or in
+    their order (an empty matrix shares the empty set). One comparison for
+    each stored entry."""
+    n = X.shape[0]
+    counts = np.diff(X.indptr)
+    c = int(counts[0]) if n else 0
+    if not (counts == c).all():
+        return None
+    indices = X.indices[: X.nnz]
+    cols = indices[:c]
+    if (np.diff(cols) <= 0).any() or not (indices.reshape(n, c) == cols).all():
+        return None
+    return cols.astype(np.int32)
+
+
+@dataclasses.dataclass
+class _Groups:
+    """Samples grouped by entity, the active-data cap and the entity filters
+    applied: the part both indexes share."""
+
+    entities: list  # ids of the entities that train a model, in row order
+    lens: np.ndarray  # [E] active samples of each
+    scale: np.ndarray  # [E] weight scale of the cap: count / cap, else 1
+    act_rows: np.ndarray  # active sample ids, entity by entity, ascending within one
+    sample_rows: np.ndarray  # [N] int32 row of each sample's entity, -1 = no model
+    n_passive: int
+
+
+def _group_by_entity(ent, *, upper, lower, exclude, entity_order, seed) -> _Groups:
+    """One stable sort of the entity ids groups the samples (the one-time
+    'shuffle'); a group's samples then stand in ascending order. The cap keeps
+    a group's samples by its own seeded draw; the filters and the row order
+    work on groups, so nothing here loops over samples."""
+    n = len(ent)
+    order = np.argsort(ent, kind="mergesort")
+    sorted_ent = ent[order]
+    boundaries = np.flatnonzero(sorted_ent[1:] != sorted_ent[:-1]) + 1
+    # an empty input (e.g. an empty validation split) has no groups at all
+    starts = np.concatenate([[0], boundaries]) if n else boundaries
+    counts = np.diff(np.append(starts, n))
+    ids = sorted_ent[starts]
+
+    lens = counts.copy()
+    scale = np.ones(len(counts))
+    kept = np.ones(n, dtype=bool)  # over `order`
+    if upper is not None:
+        for g in np.flatnonzero(counts > upper):
+            count = int(counts[g])
+            rng = np.random.default_rng(_entity_seed(str(ids[g]), seed))
+            dropped = np.argsort(rng.random(count), kind="mergesort")[upper:]
+            kept[starts[g] + dropped] = False
+            lens[g] = upper
+            scale[g] = count / upper
+
+    # lower-bound filter: entities below the threshold train no model
+    chosen = lens >= lower
+    if exclude:
+        chosen &= np.fromiter((e not in exclude for e in ids), dtype=bool, count=len(ids))
+    chosen = np.flatnonzero(chosen)
+    entities = list(ids[chosen])  # ascending: the historical fully sorted order
+    if entity_order is not None:
+        # stable growth: known entities keep the caller's row order, unseen
+        # ones append sorted at the tail (continuous-training alignment)
+        present = set(entities)
+        known = [e for e in entity_order if e in present]
+        known_set = set(known)
+        group_of = dict(zip(entities, chosen.tolist()))
+        entities = known + sorted(e for e in entities if e not in known_set)
+        chosen = np.asarray([group_of[e] for e in entities], dtype=np.int64)
+
+    # each chosen group's run of active samples, in row order
+    g_lens = lens[chosen]
+    group_starts = np.cumsum(lens) - lens
+    act_rows = order[kept][np.repeat(group_starts[chosen], g_lens) + _positions(g_lens)]
+    row_of_group = np.full(len(ids), -1, dtype=np.int32)
+    row_of_group[chosen] = np.arange(len(chosen), dtype=np.int32)
+    sample_rows = np.empty(n, dtype=np.int32)
+    sample_rows[order] = np.repeat(row_of_group, counts)
+    return _Groups(
+        entities=entities,
+        lens=g_lens,
+        scale=scale[chosen],
+        act_rows=act_rows,
+        sample_rows=sample_rows,
+        n_passive=int((counts - lens).sum()),
+    )
+
+
+@dataclasses.dataclass
+class _Index:
+    """Each entity's projection and the per-sample scoring view: what an index
+    answers. ``entries`` is the index by non-zeros' map of the active entries,
+    None for the index by rows."""
+
+    cols: np.ndarray  # every entity's sorted global column ids, concatenated
+    k_counts: np.ndarray  # [E] columns of each entity
+    s_cols: np.ndarray  # [N, nnz] int32 entity-local column of each entry, -1 pad
+    s_vals: np.ndarray  # [N, nnz] float64
+    entries: Optional[tuple] = None
+
+
+def _index_rows(X: sp.csr_matrix, groups: _Groups, shared: np.ndarray) -> _Index:
+    """The index by rows, for a matrix whose rows all store the columns
+    ``shared``: every entity observes them all, so each entity's projection
+    is ``shared`` and an entry's local column is its place in the row."""
+    n = X.shape[0]
+    c = len(shared)
+    n_ent = len(groups.entities)
+    if c:
+        s_cols = np.broadcast_to(np.arange(c, dtype=np.int32), (n, c)).copy()
+        s_vals = X.data[: X.nnz].reshape(n, c).astype(np.float64)
+    else:  # rows that store nothing: one padding slot, as the non-zero view has
+        s_cols = np.full((n, 1), -1, dtype=np.int32)
+        s_vals = np.zeros((n, 1), dtype=np.float64)
+    no_model = groups.sample_rows < 0
+    s_cols[no_model] = -1
+    s_vals[no_model] = 0.0
+    return _Index(
+        cols=np.tile(shared, n_ent),
+        k_counts=np.full(n_ent, c, dtype=np.int64),
+        s_cols=s_cols,
+        s_vals=s_vals,
+    )
+
+
+def _index_nnz(X, groups: _Groups, features_max, labels_arr, intercept_index) -> _Index:
+    """The index by non-zeros, for any matrix: each entity's projection is the
+    columns its active samples store (optionally Pearson-selected), and every
+    stored entry is mapped to its entity's local column."""
+    n, d = X.shape
+    n_ent = len(groups.entities)
+    # Flat active-sample machinery shared by the (vectorized) observed-column
+    # computation and the bucket fill: one concatenated row list replaces the
+    # per-entity scipy CSR slicing that dominated build time at 100k+ entities.
+    act_rows = groups.act_rows
+    ent_row_per_act = np.repeat(np.arange(n_ent, dtype=np.int64), groups.lens)
+    s_local_per_act = _positions(groups.lens)
+    # active nnz: global nnz positions of every active sample's entries
+    counts_all = np.diff(X.indptr)
+    c_act = counts_all[act_rows]
+    act_nnz_idx = (np.repeat(X.indptr[act_rows], c_act) + _positions(c_act)).astype(np.int64)
+    ent_of_act_nnz = np.repeat(ent_row_per_act, c_act)
+    s_local_of_act_nnz = np.repeat(s_local_per_act, c_act)
+
+    # ---- per-entity projection (+ optional Pearson selection) -------------------
+    # cols: sorted global col ids observed in each entity's ACTIVE rows.
+    if n_ent == 0:
+        cols, k_counts = np.zeros(0, dtype=np.int32), np.zeros(0, dtype=np.int64)
+    elif features_max is None:
+        keys = ent_of_act_nnz * d + X.indices[act_nnz_idx].astype(np.int64)
+        uniq_keys = np.unique(keys)
+        k_counts = np.bincount(uniq_keys // d, minlength=n_ent)
+        cols = (uniq_keys % d).astype(np.int32)
+    else:
+        # Pearson feature selection needs per-entity column/label statistics —
+        # the per-entity loop stays on this opt-in path only.
+        col_of = []
+        for start, count in zip(np.cumsum(groups.lens) - groups.lens, groups.lens):
+            rows = act_rows[start : start + count]
+            sub = X[rows]  # csr [s, d]
+            observed = np.unique(sub.indices) if sub.nnz else np.array([], dtype=np.int32)
+            if len(observed) > features_max:
+                if labels_arr is None:
+                    raise ValueError("features_max (Pearson selection) requires labels")
+                scores = _pearson_scores(sub, observed, labels_arr[rows])
+                keep_order = np.argsort(-scores, kind="mergesort")
+                kept = set(observed[keep_order[:features_max]].tolist())
+                if intercept_index is not None:
+                    kept.add(intercept_index)
+                observed = np.asarray(sorted(kept), dtype=observed.dtype)
+            col_of.append(observed.astype(np.int32))
+        cols = np.concatenate(col_of)
+        k_counts = np.asarray([len(c) for c in col_of], dtype=np.int64)
+
+    # ---- global nnz -> entity-local column mapping ------------------------------
+    # local col = position of the global col in the entity's projection row.
+    # Vectorized over all nnz: a dense [E, D] lookup when it fits, else per-entity
+    # dict fallback (huge-D regimes). Used by BOTH the bucket fill (through
+    # act_nnz_idx) and the per-sample scoring view.
+    local = np.full(X.nnz, -1, dtype=np.int32)
+    if n and X.nnz:
+        rows_per_nnz = np.repeat(np.arange(n), counts_all)
+        slot_per_nnz = np.arange(X.nnz) - np.repeat(X.indptr[:-1], counts_all)
+        ent_per_nnz = groups.sample_rows[rows_per_nnz]
+        valid = ent_per_nnz >= 0
+        if n_ent * d <= 50_000_000:
+            lookup = np.full((max(n_ent, 1), d), -1, dtype=np.int32)
+            lookup[np.repeat(np.arange(n_ent), k_counts), cols] = _positions(k_counts)
+            local[valid] = lookup[ent_per_nnz[valid], X.indices[valid]]
+        else:
+            col_of = np.split(cols, np.cumsum(k_counts)[:-1])
+            local_of = [{int(c): k for k, c in enumerate(cs)} for cs in col_of]
+            idx_valid = np.flatnonzero(valid)
+            for t in idx_valid:
+                local[t] = local_of[ent_per_nnz[t]].get(int(X.indices[t]), -1)
+
+    # ---- per-sample scoring view over the FULL sample axis ----------------------
+    nnz_max = max(int(counts_all.max()) if n else 1, 1)
+    s_cols = np.full((n, nnz_max), -1, dtype=np.int32)
+    s_vals = np.zeros((n, nnz_max), dtype=np.float64)
+    if n and X.nnz:
+        keep = local >= 0
+        s_cols[rows_per_nnz[keep], slot_per_nnz[keep]] = local[keep]
+        s_vals[rows_per_nnz[keep], slot_per_nnz[keep]] = X.data[keep]
+    return _Index(
+        cols=cols,
+        k_counts=k_counts,
+        s_cols=s_cols,
+        s_vals=s_vals,
+        entries=(act_nnz_idx, ent_of_act_nnz, s_local_of_act_nnz, local[act_nnz_idx]),
+    )
+
+
+def _row_filler(X: sp.csr_matrix, shared: np.ndarray, norm_factors):
+    """``fill(b, Xb, e_local, s_local, rows)`` of the index by rows: a block's
+    rows are copies of its samples' rows of ``X``, whose rows all store the
+    columns ``shared``."""
+    c = len(shared)
+    dense = X.data[: X.nnz].reshape(X.shape[0], c)  # the CSR's own buffer
+    factors = None if norm_factors is None else norm_factors[shared]
+
+    def fill(b, Xb, e_local, s_local, rows):
+        vals = dense[rows].astype(np.float64)
+        if factors is not None:
+            vals *= factors
+        vals += 0.0  # a stored -0.0 lands as +0.0, as the non-zero fill's sum leaves it
+        Xb.reshape(-1, Xb.shape[2])[e_local * Xb.shape[1] + s_local, :c] = vals
+
+    return fill
+
+
+def _nnz_filler(X: sp.csr_matrix, index: _Index, sorted_keys: list, norm_factors):
+    """``fill(b, Xb, e_local, s_local, rows)`` of the index by non-zeros: each
+    bucket's active entries scatter-added into its block (duplicate (row, col)
+    entries sum, as toarray does)."""
+    act_nnz_idx, ent_of_act_nnz, s_local_of_act_nnz, local_of_act_nnz = index.entries
+    n_buckets = len(sorted_keys)
+    n_ent = len(index.k_counts)
+    bucket_id = np.full(max(n_ent, 1), -1, dtype=np.int64)
+    e_local_all = np.zeros(max(n_ent, 1), dtype=np.int64)
+    for b, (_, members) in enumerate(sorted_keys):
+        bucket_id[members] = b
+        e_local_all[members] = np.arange(len(members))
+    # One stable sort groups the active entries by bucket, so each bucket gets
+    # a contiguous slice instead of re-scanning everything.
+    nnz_bucket = np.where(local_of_act_nnz >= 0, bucket_id[ent_of_act_nnz], -1)
+    nnz_order = np.argsort(nnz_bucket, kind="stable")
+    nnz_bounds = np.searchsorted(nnz_bucket[nnz_order], np.arange(n_buckets + 1))
+
+    def fill(b, Xb, e_local, s_local, rows):
+        # bincount over raveled indices = vectorized scatter-add
+        ni = nnz_order[nnz_bounds[b] : nnz_bounds[b + 1]]
+        gv = X.data[act_nnz_idx[ni]].astype(np.float64)
+        gc = X.indices[act_nnz_idx[ni]]
+        if norm_factors is not None:
+            gv = gv * norm_factors[gc]
+        flat = np.ravel_multi_index(
+            (e_local_all[ent_of_act_nnz[ni]], s_local_of_act_nnz[ni], local_of_act_nnz[ni]),
+            Xb.shape,
+        )
+        Xb += np.bincount(flat, weights=gv, minlength=Xb.size).reshape(Xb.shape)
+
+    return fill
 
 
 def in_layout_of(
