@@ -17,6 +17,9 @@ Reference behavior catalogued in /root/repo/SURVEY.md; parity targets cite
 reference files as ``photon-lib/.../File.scala:line``.
 """
 
+# The recorder registers the process's compile listener when it is imported:
+# first, so that every program the package makes lands in it as jit.* spans.
+from photon_ml_tpu.util.timed import records as _records  # noqa: F401
 from photon_ml_tpu.types import (
     TaskType,
     OptimizerType,

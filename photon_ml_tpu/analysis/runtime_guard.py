@@ -3,7 +3,8 @@
 jaxlint catches hazards statically; this module turns the two properties PR 1
 only *documented* into assertions tests and benchmarks can enforce:
 
-- **no retraces**: a process-wide trace counter fed by ``jax.monitoring``'s
+- **no retraces**: the process-wide trace counter that the recorder's
+  ``jax.monitoring`` listener keeps (``util/timed.trace_events``), fed by the
   ``/jax/core/compile/jaxpr_trace_duration`` event, which fires on every
   jaxpr trace (including nested sub-traces) and never on a jit cache hit —
   so "zero events in the guarded region" is exactly "the compile cache held".
@@ -44,38 +45,10 @@ from __future__ import annotations
 
 import contextlib
 import dataclasses
-import threading
 
 import jax
 
-_TRACE_EVENT = "/jax/core/compile/jaxpr_trace_duration"
-
-_lock = threading.Lock()
-_listener_installed = False
-_trace_events = 0
-
-
-def _install_listener() -> None:
-    """Register the monitoring listener once per process (listeners cannot be
-    unregistered through public jax API, so a counter + snapshots it is)."""
-    global _listener_installed
-    with _lock:
-        if _listener_installed:
-            return
-
-        def _on_event_duration(event: str, duration: float, **kwargs) -> None:
-            global _trace_events
-            if event == _TRACE_EVENT:
-                _trace_events += 1
-
-        jax.monitoring.register_event_duration_secs_listener(_on_event_duration)
-        _listener_installed = True
-
-
-def trace_events() -> int:
-    """Process-lifetime count of jaxpr traces observed so far (0 until the
-    first guarded region installs the listener)."""
-    return _trace_events
+from photon_ml_tpu.util.timed import trace_events
 
 
 class RetraceError(AssertionError):
@@ -91,7 +64,7 @@ class GuardedRegion:
 
     @property
     def traces(self) -> int:
-        return _trace_events - self._start
+        return trace_events() - self._start
 
 
 @contextlib.contextmanager
@@ -101,8 +74,7 @@ def no_retrace(allow_retraces: int = 0, what: str = "guarded region"):
     Warmup belongs OUTSIDE the region: compile first, then guard the steady
     state. Raises RetraceError on exit; raises nothing if the body itself
     raised (the original error is more informative than the trace count)."""
-    _install_listener()
-    region = GuardedRegion(_start=_trace_events, allow_retraces=allow_retraces)
+    region = GuardedRegion(_start=trace_events(), allow_retraces=allow_retraces)
     try:
         yield region
     except BaseException:

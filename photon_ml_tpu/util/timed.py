@@ -15,7 +15,17 @@ the span of the training path:
   ``Record`` to the process-wide bounded recorder that ``records()`` reads;
 - ``count(name, value, **attrs)`` appends a counter record, stamped the same
   way, to the same recorder; ``summary()`` gives name -> (count, total seconds)
-  of every span since the process started.
+  of every span since the process started;
+- JAX's making of each program lands in the same recorder as spans: importing
+  this module registers the process's one ``jax.monitoring`` listener, which
+  turns the three stages JAX reports into ``jit.trace`` (jaxpr tracing),
+  ``jit.lower`` (jaxpr to MLIR) and ``jit.compile`` (backend compile or
+  persistent-cache load), each with ``fun=`` the name JAX gives. Traces nest
+  (an inner ``jit``, and every ``jnp`` function that is itself jitted, fires
+  its own event inside the outer one): only the outermost becomes a record,
+  with ``nested=`` the count of the trace events inside it, so a warm-up adds
+  records in proportion to its programs, not its primitives.
+  ``trace_events()`` counts every trace event, nested ones included.
 
 Import the names (``from photon_ml_tpu.util.timed import Timed, count,
 records, summary``): the package re-exports the decorator ``timed`` under this
@@ -24,7 +34,8 @@ that function.
 
 Always on: there is no switch, no environment variable and no exporter. A span
 costs two clock reads, one deque append and a disabled ``TraceMe`` (a few
-microseconds); it never touches the device.
+microseconds); it never touches the device. The listener runs only when JAX
+traces, lowers or compiles, which a warmed program does not.
 """
 
 from __future__ import annotations
@@ -89,6 +100,71 @@ def count(name: str, value, **attrs) -> None:
     _records.append(Record(name, now, now, attrs, float(value)))
 
 
+def _keep_span(record: Record) -> None:
+    _records.append(record)
+    with _totals_lock:
+        total = _totals.setdefault(record.name, [0, 0.0])
+        total[0] += 1
+        total[1] += record.seconds
+
+
+# jax.monitoring's stages of making a program -> the recorder's span names
+_TRACE_EVENT = "/jax/core/compile/jaxpr_trace_duration"
+_STAGES = {
+    _TRACE_EVENT: "jit.trace",
+    "/jax/core/compile/jaxpr_to_mlir_module_duration": "jit.lower",
+    "/jax/core/compile/backend_compile_duration": "jit.compile",
+}
+
+
+class _CompileListener:
+    """The process's one ``jax.monitoring`` listener. JAX stamps each stage
+    with ``time.time()``, the recorder's clock: it reports the start as a
+    scalar (``stage_started``) and the whole stage as a time span
+    (``stage_ended``). Depth is per thread, since a trace nests only inside
+    a trace of the same thread."""
+
+    def __init__(self):
+        self._open = threading.local()  # .depth, .nested: the thread's open traces
+        self._lock = threading.Lock()
+        self.trace_events = 0
+
+    def stage_started(self, event: str, value, **kwargs) -> None:
+        if event == _TRACE_EVENT:
+            self._open.depth = getattr(self._open, "depth", 0) + 1
+
+    def stage_ended(self, event: str, start: float, end: float, fun_name: str = "", **kwargs) -> None:
+        name = _STAGES.get(event)
+        if name is None:
+            return
+        attrs = {"fun": fun_name}
+        if event == _TRACE_EVENT:
+            with self._lock:
+                self.trace_events += 1
+            # a trace this listener did not see start (it was registered
+            # meanwhile) ends as an outermost one
+            depth = getattr(self._open, "depth", 1) - 1
+            nested = getattr(self._open, "nested", 0)
+            self._open.depth = max(depth, 0)
+            if depth > 0:
+                self._open.nested = nested + 1
+                return
+            self._open.nested = 0
+            attrs["nested"] = nested
+        _keep_span(Record(name, round(start * 1e9), round(end * 1e9), attrs))
+
+
+_listener = _CompileListener()
+jax.monitoring.register_scalar_listener(_listener.stage_started)
+jax.monitoring.register_event_time_span_listener(_listener.stage_ended)
+
+
+def trace_events() -> int:
+    """Jaxpr traces since this module was imported, nested ones included: a
+    jit cache hit traces nothing, a persistent-cache load still traces."""
+    return _listener.trace_events
+
+
 def summary() -> dict:
     """Span name -> (count, total seconds) since the process started."""
     with _totals_lock:
@@ -116,18 +192,13 @@ class Timed:
         self._annotation = jax.profiler.TraceAnnotation(TRACE_PREFIX + self.name, **self.attrs)
         self._annotation.__enter__()
         self._start_ns = time.time_ns()
-        self._start = time.perf_counter()
         return self
 
     def __exit__(self, exc_type, exc, tb) -> None:
-        self.seconds = time.perf_counter() - self._start
-        end_ns = time.time_ns()
+        record = Record(self.name, self._start_ns, time.time_ns(), self.attrs)
         self._annotation.__exit__(exc_type, exc, tb)
-        _records.append(Record(self.name, self._start_ns, end_ns, self.attrs))
-        with _totals_lock:
-            total = _totals.setdefault(self.name, [0, 0.0])
-            total[0] += 1
-            total[1] += self.seconds
+        self.seconds = record.seconds
+        _keep_span(record)
         status = "" if exc_type is None else " (failed)"
         log = getattr(self._logger, "info", None)
         if hasattr(self._logger, "log"):

@@ -14,7 +14,7 @@ import pytest
 import scipy.sparse as sp
 
 from photon_ml_tpu.algorithm.random_effect import RandomEffectTracker
-from photon_ml_tpu.analysis.runtime_guard import sync_discipline
+from photon_ml_tpu.analysis.runtime_guard import no_retrace, sync_discipline
 from photon_ml_tpu.data.game_data import GameInput
 from photon_ml_tpu.data.random_effect import build_random_effect_dataset
 from photon_ml_tpu.estimators import (
@@ -36,6 +36,7 @@ from photon_ml_tpu.util.timed import MAX_RECORDS, Record, Timed, count, records,
 timed_module = importlib.import_module("photon_ml_tpu.util.timed")
 
 PASSES = 2
+JIT = ("jit.trace", "jit.lower", "jit.compile")  # what JAX's making of a program records
 COORDINATES = ("fixed", "per-user", "per-item")
 # table (b) of the span contract; descent.checkpoint needs a checkpointer
 FIT_SPANS = {
@@ -115,6 +116,126 @@ def test_count_and_summary():
     assert counter == Record("things", counter.start_ns, counter.start_ns, {"cid": "a"}, 3.0)
     n, seconds = summary()["summed"]
     assert n == before[0] + 2 and seconds >= before[1]
+
+
+def test_timed_states_one_duration():
+    """``.seconds``, the record and ``summary()`` come from the same two stamps."""
+    t0 = time.time_ns()
+    before = summary().get("once", (0, 0.0))
+    with Timed("once") as t:
+        sum(range(1000))
+    (r,) = records(since_ns=t0, name="once")
+    assert t.seconds == r.seconds == (r.end_ns - r.start_ns) / 1e9
+    assert summary()["once"] == (before[0] + 1, pytest.approx(before[1] + r.seconds, abs=1e-12))
+
+
+# ------------------------------------------------------- the compile pipeline
+
+
+def _jit_records(since_ns):
+    return [r for r in records(since_ns=since_ns) if r.name in JIT]
+
+
+def test_a_fresh_program_adds_one_record_of_each_stage():
+    x = jnp.ones(8)
+
+    def fresh_program(a):
+        return a * 2.0 + 1.0
+
+    f = jax.jit(fresh_program)
+    t0 = time.time_ns()
+    before = {name: summary().get(name, (0, 0.0))[0] for name in JIT}
+    f(x)
+    got = _jit_records(t0)
+    assert [r.name for r in got] == list(JIT)
+    assert all("fresh_program" in r.attrs["fun"] for r in got)
+    assert all(r.end_ns >= r.start_ns and r.value is None for r in got)
+    assert {name: summary()[name][0] for name in JIT} == {name: n + 1 for name, n in before.items()}
+
+
+def _outer_program():
+    @jax.jit
+    def inner_program(a):
+        return jnp.sin(a) * 2.0
+
+    @jax.jit
+    def outer_program(a):
+        return inner_program(a) + jnp.where(a > 0, a, 0.0).sum()
+
+    return outer_program
+
+
+def test_nested_traces_make_one_record_for_the_outermost():
+    x = jnp.ones(8)
+    f = _outer_program()
+    t0 = time.time_ns()
+    f(x)
+    (trace,) = records(since_ns=t0, name="jit.trace")
+    assert trace.attrs["fun"] == "outer_program" and trace.attrs["nested"] > 0
+    assert [r.name for r in _jit_records(t0)] == list(JIT)
+
+
+def test_a_cache_hit_adds_no_jit_record():
+    x = jnp.ones(8)
+    f = _outer_program()
+    f(x)
+    t0 = time.time_ns()
+    f(x)
+    f(x)
+    assert _jit_records(t0) == []
+
+
+def test_jit_records_share_the_clock_of_an_enclosing_span():
+    x = jnp.ones(8)
+    f = _outer_program()
+    t0 = time.time_ns()
+    with Timed("encloses"):
+        f(x)
+    (outer,) = records(since_ns=t0, name="encloses")
+    got = _jit_records(t0)
+    assert len(got) == 3
+    assert all(outer.start_ns <= r.start_ns <= r.end_ns <= outer.end_ns for r in got)
+    trace, lower, compile_ = got
+    assert trace.end_ns <= lower.start_ns and lower.end_ns <= compile_.start_ns
+
+
+def test_no_retrace_counts_every_trace_event_as_before():
+    """The guard counts every jaxpr trace event, nested ones included: what a
+    listener of the trace event's duration counts."""
+    x = jnp.ones(8)
+    f = _outer_program()
+    seen = []
+
+    def count_traces(event, duration, **kwargs):
+        if event == "/jax/core/compile/jaxpr_trace_duration":
+            seen.append(kwargs.get("fun_name"))
+
+    jax.monitoring.register_event_duration_secs_listener(count_traces)
+    try:
+        t0 = time.time_ns()
+        with no_retrace(allow_retraces=1000) as region:
+            f(x)
+        traces = region.traces
+    finally:
+        jax.monitoring.unregister_event_duration_listener(count_traces)
+    (trace,) = records(since_ns=t0, name="jit.trace")
+    assert traces == len(seen) == 1 + trace.attrs["nested"] > 1
+
+
+def test_the_program_registers_one_monitoring_listener():
+    from jax._src import monitoring
+
+    import photon_ml_tpu.analysis.runtime_guard  # noqa: F401
+
+    def ours(listeners):
+        return [cb for cb in listeners if getattr(cb, "__module__", "").startswith("photon_ml_tpu")]
+
+    owners = {cb.__self__ for cb in ours(monitoring.get_event_time_span_listeners())}
+    owners |= {cb.__self__ for cb in ours(monitoring.get_scalar_listeners())}
+    assert owners == {timed_module._listener}
+    assert len(ours(monitoring.get_event_time_span_listeners())) == 1
+    assert ours(monitoring.get_event_duration_listeners()) == []
+    assert ours(monitoring.get_event_listeners()) == []
 
 
 # ---------------------------------------------------------------- the fit
@@ -253,7 +374,7 @@ def test_checkpointing_fit_adds_the_checkpoint_span(tmp_path):
     train, val = _data()
     t0 = time.time_ns()
     _estimator(checkpoint_directory=str(tmp_path)).fit(train, validation_data=val)
-    names = {r.name for r in records(since_ns=t0) if r.value is None}
+    names = {r.name for r in records(since_ns=t0) if r.value is None and r.name not in JIT}
     assert names == FIT_SPANS | {"descent.checkpoint"}
     assert len(records(since_ns=t0, name="descent.checkpoint")) == PASSES
 
@@ -380,7 +501,7 @@ def test_padding_waste_matches_a_hand_built_dataset():
     )
     assert sorted((b.n_entities, b.shape[0]) for b in dataset.buckets) == [(1, 16), (2, 8)]
     assert dataset.padding_waste == pytest.approx(1.0 - 17 / 32)
-    assert [r.name for r in records(since_ns=t0)] == [
+    assert [r.name for r in records(since_ns=t0) if r.name not in JIT] == [
         "ingest.re_index", "ingest.re_buckets", "ingest.h2d"
     ]
     scoring = build_random_effect_dataset(
