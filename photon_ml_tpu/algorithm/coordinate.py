@@ -1045,6 +1045,23 @@ class RandomEffectCoordinate(Coordinate):
             return "view"
         return "bucket"
 
+    @property
+    def solve_loop(self) -> str:
+        """How the bucket solves run their L-BFGS loops, the ``loop``
+        attribute of this coordinate's ``descent.update`` spans:
+        ``"lanes"`` where plain L-BFGS buckets run both loops over the
+        entity lanes (``solver_cache.lane_loop``; under ``"auto"`` the
+        buckets its plan gives the direct solve are vmapped), ``"vmap"``
+        where every bucket is ``jax.vmap`` of a one-entity solve (the
+        direct solve, OWLQN, TRON, NEWTON, LBFGSB)."""
+        from photon_ml_tpu.optimization.solver_cache import lane_loop
+
+        cfg = self.configuration
+        lanes = self.re_solver != "direct" and lane_loop(
+            cfg.optimizer_config, bool(cfg.l1_weight)
+        )
+        return "lanes" if lanes else "vmap"
+
     def _state_shardings(self):
         """``(table, score)`` shardings of the state a mesh-placed dataset's
         updates donate, None off a mesh: the table (and variances)

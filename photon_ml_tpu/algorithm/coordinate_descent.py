@@ -53,15 +53,20 @@ def _model_kind(model) -> str:
 
 
 def _update_attrs(coord, fused_path: bool) -> dict:
-    """The ``score_path`` and ``solver`` attributes of a ``descent.update``
-    span, for the coordinates that name them (random effects: ``bucket`` |
-    ``view``, and ``direct`` | ``lbfgs`` | ``auto``, read from the coordinate
-    before the span opens). Off the fused protocol the loop scores the model
-    itself, through the view."""
+    """The ``score_path``, ``solver`` and ``loop`` attributes of a
+    ``descent.update`` span, for the coordinates that name them (random
+    effects: ``bucket`` | ``view``; ``direct`` | ``lbfgs`` | ``auto``; and
+    ``lanes`` | ``vmap``, read from the coordinate before the span opens).
+    Off the fused protocol the loop scores the model itself, through the
+    view."""
     path = getattr(coord, "score_path", None)
     if path is None:
         return {}
-    return {"score_path": path if fused_path else "view", "solver": coord.re_solver}
+    return {
+        "score_path": path if fused_path else "view",
+        "solver": coord.re_solver,
+        "loop": coord.solve_loop,
+    }
 
 
 def _device_guard(model, tracker) -> tuple:

@@ -17,9 +17,11 @@ introduces, and convergence is per-problem state inside the masked while_loop.
 from __future__ import annotations
 
 import dataclasses
-from typing import NamedTuple, Optional
+from typing import Callable, NamedTuple, Optional
 
+import jax
 import jax.numpy as jnp
+from jax import lax
 
 from photon_ml_tpu.types import ConvergenceReason, OptimizerType
 
@@ -135,3 +137,27 @@ def record_tracking(values, gnorms, idx, f, gnorm):
     if values is None:
         return None, None
     return values.at[idx].set(f), gnorms.at[idx].set(gnorm)
+
+
+def while_lanes(live: Callable, body: Callable, init):
+    """``lax.while_loop`` over a leading lane axis of every carry.
+
+    ``live(carry) -> [E] bool`` and ``body(carry) -> carry`` compute all
+    lanes at once; the loop runs while any lane is live, and a lane that is
+    not keeps its carry. This is the program ``jax.vmap`` of a one-lane
+    ``while_loop`` with a per-lane predicate lowers to (the body selects each
+    carry on the predicate of the carry it started from), written out once:
+    tracing it batches no loop, where vmap's while-loop rule re-batches the
+    body until it learns which carries are batched, and again for every
+    loop nested inside."""
+
+    def step(carry):
+        new = body(carry)
+        keep = live(carry)
+        return jax.tree.map(
+            lambda n, o: lax.select(lax.broadcast_in_dim(keep, n.shape, (0,)), n, o),
+            new,
+            carry,
+        )
+
+    return lax.while_loop(lambda carry: lax.reduce_or(live(carry), (0,)), step, init)

@@ -13,11 +13,14 @@ accepted point (one extra evaluation is never wasted: callers reuse them).
 
 from __future__ import annotations
 
+import functools
 from typing import Callable, NamedTuple
 
 import jax
 import jax.numpy as jnp
 from jax import lax
+
+from photon_ml_tpu.optimization.common import while_lanes
 
 Array = jnp.ndarray
 
@@ -110,8 +113,60 @@ def strong_wolfe(
     any converging lane's numerics.
     """
 
+    big = jnp.asarray(jnp.inf, f0.dtype)
+    st = _wolfe_start(phi, f0, g0, dphi0, init_alpha, max_iters, active, big)
+    final = lax.while_loop(
+        functools.partial(_wolfe_live, max_iters=max_iters),
+        functools.partial(_wolfe_step, phi, f0, dphi0, big),
+        st,
+    )
+    return _wolfe_result(final, f0)
+
+
+def strong_wolfe_lanes(
+    phi: Callable[..., tuple[Array, Array, Array]],
+    lanes,
+    f0: Array,
+    g0: Array,
+    dphi0: Array,
+    init_alpha: Array,
+    max_iters: int = 10,
+    active=None,
+) -> LineSearchResult:
+    """``strong_wolfe`` for a leading lane axis: every argument but ``phi``
+    carries it, and lane ``e`` of the result is ``strong_wolfe`` of lane
+    ``e`` bit for bit. ``phi(lane, a)`` is ONE lane's phi, ``lane`` that
+    lane's slice of the pytree ``lanes`` (the lane's data, iterate and
+    direction).
+
+    One ``while_loop`` over the lanes (``common.while_lanes``); only the
+    loop-free pieces of the one-problem search are vmapped, so tracing it
+    batches no loop."""
+
+    def one(lane):
+        return functools.partial(phi, lane)
+
+    big = jnp.asarray(jnp.inf, f0.dtype)
+    st = jax.vmap(
+        lambda lane, f0, g0, dphi0, a0, act: _wolfe_start(
+            one(lane), f0, g0, dphi0, a0, max_iters, act, big
+        )
+    )(lanes, f0, g0, dphi0, init_alpha, active)
+    step = jax.vmap(
+        lambda lane, f0, dphi0, st: _wolfe_step(one(lane), f0, dphi0, big, st)
+    )
+    final = while_lanes(
+        jax.vmap(functools.partial(_wolfe_live, max_iters=max_iters)),
+        lambda st: step(lanes, f0, dphi0, st),
+        st,
+    )
+    return jax.vmap(_wolfe_result)(final, f0)
+
+
+def _wolfe_start(phi, f0, g0, dphi0, init_alpha, max_iters, active, big) -> _State:
+    """The search's first trial and its state machine's initial state
+    (``big``: the infinite end of a bracket not found yet)."""
     dtype = f0.dtype
-    big = jnp.asarray(jnp.inf, dtype)
     fin = jnp.finfo(dtype)
     # ~(>=), not (<): a NaN dphi0 must stay SEARCHABLE so it reaches the
     # failure path (a no-op "success" would report convergence at a NaN
@@ -121,13 +176,6 @@ def strong_wolfe(
     searchable = ~(dphi0 >= -thresh) | ~jnp.isfinite(f0)
     if active is not None:
         searchable = searchable & active
-
-    def mk(stage, i, a, f_a, g_a, dphi_a, a_lo, f_lo, dphi_lo, a_hi, f_hi, dphi_hi, a_best, f_best, g_best):
-        return _State(
-            jnp.asarray(stage, jnp.int32), jnp.asarray(i, jnp.int32),
-            a, f_a, g_a, dphi_a, a_lo, f_lo, dphi_lo, a_hi, f_hi, dphi_hi,
-            a_best, f_best, g_best,
-        )
 
     zero = jnp.zeros((), dtype)
     # unsearchable lanes trial alpha=0 (an exact no-op point) and start DONE
@@ -139,102 +187,105 @@ def strong_wolfe(
         lambda gn, g_0: jnp.where(searchable, gn, g_0), g_a1, g0
     )
     # best-so-far starts at alpha = 0; the first body pass folds in the a1 trial.
-    st = mk(
-        jnp.where(searchable, _BRACKETING, _DONE), 1, a1, f_a1, g_a1, dphi_a1,
+    return _State(
+        jnp.asarray(jnp.where(searchable, _BRACKETING, _DONE), jnp.int32),
+        jnp.asarray(1, jnp.int32),
+        a1, f_a1, g_a1, dphi_a1,
         zero, f0, dphi0,  # lo starts at 0
         big, big, big,
         zero, f0, g0,
     )
 
-    armijo = lambda a, f_a: f_a <= f0 + C1 * a * dphi0
-    curvature = lambda dphi_a: jnp.abs(dphi_a) <= -C2 * dphi0
 
-    def cond(st: _State):
-        return (st.stage < _DONE) & (st.i < max_iters)
+def _wolfe_live(st: _State, max_iters: int):
+    return (st.stage < _DONE) & (st.i < max_iters)
 
-    def body(st: _State):
-        # ---- evaluate transition for the current trial point -------------------
-        is_bracket = st.stage == _BRACKETING
 
-        arm = armijo(st.a, st.f_a)
-        curv = curvature(st.dphi_a)
+def _wolfe_step(phi, f0, dphi0, big, st: _State) -> _State:
+    """One pass of the bracketing/zoom state machine: the transition for the
+    current trial, then the next trial's evaluation."""
+    # ---- evaluate transition for the current trial point -------------------
+    is_bracket = st.stage == _BRACKETING
 
-        # track best Armijo point
-        better = arm & (st.f_a < st.f_best)
-        a_best = jnp.where(better, st.a, st.a_best)
-        f_best = jnp.where(better, st.f_a, st.f_best)
-        g_best = jax.tree.map(lambda new, old: jnp.where(better, new, old), st.g_a, st.g_best)
+    arm = st.f_a <= f0 + C1 * st.a * dphi0  # sufficient decrease
+    curv = jnp.abs(st.dphi_a) <= -C2 * dphi0  # curvature
 
-        # -- bracketing phase (Alg 3.5) -----------------------------------------
-        # violation: armijo fails, or f_a >= f_lo (after first step)
-        brk_hi = (~arm) | ((st.f_a >= st.f_lo) & (st.i > 1))
-        brk_done = arm & curv
-        brk_pos = arm & ~curv & (st.dphi_a >= 0)
-        # else: extend interval
+    # track best Armijo point
+    better = arm & (st.f_a < st.f_best)
+    a_best = jnp.where(better, st.a, st.a_best)
+    f_best = jnp.where(better, st.f_a, st.f_best)
+    g_best = jax.tree.map(lambda new, old: jnp.where(better, new, old), st.g_a, st.g_best)
 
-        # -- zoom phase (Alg 3.6) ------------------------------------------------
-        zm_shrink_hi = (~arm) | (st.f_a >= st.f_lo)
-        zm_done = arm & curv
-        zm_move_hi = arm & ~curv & (st.dphi_a * (st.a_hi - st.a_lo) >= 0)
+    # -- bracketing phase (Alg 3.5) -----------------------------------------
+    # violation: armijo fails, or f_a >= f_lo (after first step)
+    brk_hi = (~arm) | ((st.f_a >= st.f_lo) & (st.i > 1))
+    brk_done = arm & curv
+    brk_pos = arm & ~curv & (st.dphi_a >= 0)
+    # else: extend interval
 
-        stage = jnp.where(
-            is_bracket,
-            jnp.where(brk_done, _DONE, _ZOOM * (brk_hi | brk_pos) + _BRACKETING * (~(brk_hi | brk_pos))),
-            jnp.where(zm_done, _DONE, _ZOOM),
-        ).astype(jnp.int32)
+    # -- zoom phase (Alg 3.6) ------------------------------------------------
+    zm_shrink_hi = (~arm) | (st.f_a >= st.f_lo)
+    zm_done = arm & curv
+    zm_move_hi = arm & ~curv & (st.dphi_a * (st.a_hi - st.a_lo) >= 0)
 
-        # new lo/hi for bracketing transitions (zoom-entry keeps the old lo; both the
-        # dphi>=0 entry and the plain interval extension move lo to the current trial)
-        b_a_lo = jnp.where(brk_hi, st.a_lo, st.a)
-        b_f_lo = jnp.where(brk_hi, st.f_lo, st.f_a)
-        b_dphi_lo = jnp.where(brk_hi, st.dphi_lo, st.dphi_a)
-        b_a_hi = jnp.where(brk_hi, st.a, jnp.where(brk_pos, st.a_lo, big))
-        b_f_hi = jnp.where(brk_hi, st.f_a, jnp.where(brk_pos, st.f_lo, big))
-        b_dphi_hi = jnp.where(brk_hi, st.dphi_a, jnp.where(brk_pos, st.dphi_lo, big))
+    stage = jnp.where(
+        is_bracket,
+        jnp.where(brk_done, _DONE, _ZOOM * (brk_hi | brk_pos) + _BRACKETING * (~(brk_hi | brk_pos))),
+        jnp.where(zm_done, _DONE, _ZOOM),
+    ).astype(jnp.int32)
 
-        # new lo/hi for zoom transitions
-        z_a_lo = jnp.where(zm_shrink_hi, st.a_lo, st.a)
-        z_f_lo = jnp.where(zm_shrink_hi, st.f_lo, st.f_a)
-        z_dphi_lo = jnp.where(zm_shrink_hi, st.dphi_lo, st.dphi_a)
-        z_a_hi = jnp.where(zm_shrink_hi, st.a, jnp.where(zm_move_hi, st.a_lo, st.a_hi))
-        z_f_hi = jnp.where(zm_shrink_hi, st.f_a, jnp.where(zm_move_hi, st.f_lo, st.f_hi))
-        z_dphi_hi = jnp.where(zm_shrink_hi, st.dphi_a, jnp.where(zm_move_hi, st.dphi_lo, st.dphi_hi))
+    # new lo/hi for bracketing transitions (zoom-entry keeps the old lo; both the
+    # dphi>=0 entry and the plain interval extension move lo to the current trial)
+    b_a_lo = jnp.where(brk_hi, st.a_lo, st.a)
+    b_f_lo = jnp.where(brk_hi, st.f_lo, st.f_a)
+    b_dphi_lo = jnp.where(brk_hi, st.dphi_lo, st.dphi_a)
+    b_a_hi = jnp.where(brk_hi, st.a, jnp.where(brk_pos, st.a_lo, big))
+    b_f_hi = jnp.where(brk_hi, st.f_a, jnp.where(brk_pos, st.f_lo, big))
+    b_dphi_hi = jnp.where(brk_hi, st.dphi_a, jnp.where(brk_pos, st.dphi_lo, big))
 
-        a_lo = jnp.where(is_bracket, b_a_lo, z_a_lo)
-        f_lo = jnp.where(is_bracket, b_f_lo, z_f_lo)
-        dphi_lo = jnp.where(is_bracket, b_dphi_lo, z_dphi_lo)
-        a_hi = jnp.where(is_bracket, b_a_hi, z_a_hi)
-        f_hi = jnp.where(is_bracket, b_f_hi, z_f_hi)
-        dphi_hi = jnp.where(is_bracket, b_dphi_hi, z_dphi_hi)
+    # new lo/hi for zoom transitions
+    z_a_lo = jnp.where(zm_shrink_hi, st.a_lo, st.a)
+    z_f_lo = jnp.where(zm_shrink_hi, st.f_lo, st.f_a)
+    z_dphi_lo = jnp.where(zm_shrink_hi, st.dphi_lo, st.dphi_a)
+    z_a_hi = jnp.where(zm_shrink_hi, st.a, jnp.where(zm_move_hi, st.a_lo, st.a_hi))
+    z_f_hi = jnp.where(zm_shrink_hi, st.f_a, jnp.where(zm_move_hi, st.f_lo, st.f_hi))
+    z_dphi_hi = jnp.where(zm_shrink_hi, st.dphi_a, jnp.where(zm_move_hi, st.dphi_lo, st.dphi_hi))
 
-        # ---- next trial point ---------------------------------------------------
-        in_zoom_next = stage == _ZOOM
-        # zoom step: quadratic interpolation, safeguarded to the middle 80% of [lo, hi]
-        lo, hi = jnp.minimum(a_lo, a_hi), jnp.maximum(a_lo, a_hi)
-        cand = _interp_quadratic(a_lo, f_lo, dphi_lo, a_hi, f_hi)
-        width = hi - lo
-        cand = jnp.clip(cand, lo + 0.1 * width, hi - 0.1 * width)
-        a_zoom = jnp.where(jnp.isfinite(cand), cand, 0.5 * (lo + hi))
-        a_extend = 2.0 * st.a  # bracketing: grow
-        a_next = jnp.where(in_zoom_next, a_zoom, a_extend)
-        a_next = jnp.where(stage == _DONE, st.a, a_next)
+    a_lo = jnp.where(is_bracket, b_a_lo, z_a_lo)
+    f_lo = jnp.where(is_bracket, b_f_lo, z_f_lo)
+    dphi_lo = jnp.where(is_bracket, b_dphi_lo, z_dphi_lo)
+    a_hi = jnp.where(is_bracket, b_a_hi, z_a_hi)
+    f_hi = jnp.where(is_bracket, b_f_hi, z_f_hi)
+    dphi_hi = jnp.where(is_bracket, b_dphi_hi, z_dphi_hi)
 
-        # evaluate (wasted when DONE, but keeps the loop shape static; the loop exits
-        # immediately after, so at most one redundant eval per search)
-        f_n, g_n, dphi_n = phi(a_next)
-        keep = stage == _DONE
-        f_n = jnp.where(keep, st.f_a, f_n)
-        dphi_n = jnp.where(keep, st.dphi_a, dphi_n)
-        g_n = jax.tree.map(lambda new, old: jnp.where(keep, old, new), g_n, st.g_a)
+    # ---- next trial point ---------------------------------------------------
+    in_zoom_next = stage == _ZOOM
+    # zoom step: quadratic interpolation, safeguarded to the middle 80% of [lo, hi]
+    lo, hi = jnp.minimum(a_lo, a_hi), jnp.maximum(a_lo, a_hi)
+    cand = _interp_quadratic(a_lo, f_lo, dphi_lo, a_hi, f_hi)
+    width = hi - lo
+    cand = jnp.clip(cand, lo + 0.1 * width, hi - 0.1 * width)
+    a_zoom = jnp.where(jnp.isfinite(cand), cand, 0.5 * (lo + hi))
+    a_extend = 2.0 * st.a  # bracketing: grow
+    a_next = jnp.where(in_zoom_next, a_zoom, a_extend)
+    a_next = jnp.where(stage == _DONE, st.a, a_next)
 
-        return _State(
-            stage, st.i + 1, a_next, f_n, g_n, dphi_n,
-            a_lo, f_lo, dphi_lo, a_hi, f_hi, dphi_hi,
-            a_best, f_best, g_best,
-        )
+    # evaluate (wasted when DONE, but keeps the loop shape static; the loop exits
+    # immediately after, so at most one redundant eval per search)
+    f_n, g_n, dphi_n = phi(a_next)
+    keep = stage == _DONE
+    f_n = jnp.where(keep, st.f_a, f_n)
+    dphi_n = jnp.where(keep, st.dphi_a, dphi_n)
+    g_n = jax.tree.map(lambda new, old: jnp.where(keep, old, new), g_n, st.g_a)
 
-    final = lax.while_loop(cond, body, st)
+    return _State(
+        stage, st.i + 1, a_next, f_n, g_n, dphi_n,
+        a_lo, f_lo, dphi_lo, a_hi, f_hi, dphi_hi,
+        a_best, f_best, g_best,
+    )
 
+
+def _wolfe_result(final: _State, f0) -> LineSearchResult:
     success = final.stage == _DONE
     # Fallback: best Armijo point if any, else failure.
     has_fallback = final.a_best > 0

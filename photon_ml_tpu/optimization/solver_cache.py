@@ -26,7 +26,7 @@ import jax.numpy as jnp
 
 from photon_ml_tpu.function.losses import loss_for_task
 from photon_ml_tpu.function.objective import GLMObjective
-from photon_ml_tpu.optimization import normal_equations
+from photon_ml_tpu.optimization import lbfgs, normal_equations
 from photon_ml_tpu.optimization.common import OptimizerConfig, OptResult
 from photon_ml_tpu.optimization.factory import build_minimizer
 from photon_ml_tpu.optimization.precision import FLOAT32, PrecisionPolicy
@@ -153,7 +153,7 @@ def _re_bucket_solve_fn(
     re_solver: str = "lbfgs",
     with_active: bool = False,
 ):
-    """Unjitted vmapped bucket solve shared by ``re_bucket_solver`` (one jit
+    """Unjitted bucket solve shared by ``re_bucket_solver`` (one jit
     per bucket) and ``re_coordinate_update_program`` (every bucket chained in
     one trace) — one body, so the two paths stay bitwise interchangeable.
 
@@ -174,10 +174,17 @@ def _re_bucket_solve_fn(
     Returns per lane ``(coefficients, reason, iterations, evaluations,
     variances)``; ``evaluations`` is the minimiser's own count of
     value-and-gradient evaluations (``OptResult.evaluations``), None from a
-    minimiser that does not count them."""
+    minimiser that does not count them.
+
+    A bucket that runs plain L-BFGS (``lane_loop``) solves its lanes with
+    ``lbfgs.minimize_lbfgs_lanes``: both loops written over the lanes, bit
+    for bit ``jax.vmap`` of the one-lane solve, without the while-loop
+    batching rule's passes at trace time. Every other bucket is ``jax.vmap``
+    of the one-lane solve (``lane_solve``)."""
     task = TaskType(task)
     loss = loss_for_task(task)
     minimize = build_minimizer(opt_config)
+    lanes_form = lane_loop(opt_config, has_l1)
     use_hvp = OptimizerType(opt_config.optimizer_type) == OptimizerType.TRON
     use_hess = OptimizerType(opt_config.optimizer_type) == OptimizerType.NEWTON
     variance = VarianceComputationType(variance)
@@ -194,7 +201,7 @@ def _re_bucket_solve_fn(
                 evals = jnp.where(active, evals, jnp.zeros_like(evals))
         return iters, evals
 
-    def re_bucket_solve(Xe, ye, we, oe, w0, l2, l1, active=None):
+    def lane_solve(Xe, ye, we, oe, w0, l2, l1, active=None):
         data = LabeledData(X=DenseDesignMatrix(Xe), labels=ye, offsets=oe, weights=we)
         obj = GLMObjective(loss, allow_fused=False)  # vmapped: no pallas path
 
@@ -243,9 +250,56 @@ def _re_bucket_solve_fn(
         iters, evals = lane_counts(res, active)
         return res.coefficients, res.convergence_reason, iters, evals, var
 
-    if with_active:
-        return jax.vmap(re_bucket_solve, in_axes=(0, 0, 0, 0, 0, 0, None, None))
-    return jax.vmap(re_bucket_solve, in_axes=(0, 0, 0, 0, 0, 0, None))
+    def lanes_solve(Xe, ye, we, oe, w0, l2, active):
+        obj = GLMObjective(loss, allow_fused=False)
+        data = LabeledData(X=DenseDesignMatrix(Xe), labels=ye, offsets=oe, weights=we)
+
+        def vg(lane, w):
+            data, l2 = lane
+            one = lambda w: obj.value_and_gradient(data, w, l2)  # noqa: E731
+            return one(w) if active is None else _masked_value_and_grad(one, active)(w)
+
+        res = lbfgs.minimize_lbfgs_lanes(
+            vg,
+            (data, l2),
+            w0,
+            max_iterations=opt_config.max_iterations,
+            tolerance=opt_config.tolerance,
+            history_length=opt_config.history_length,
+            max_line_search_iterations=opt_config.max_line_search_iterations,
+            track_states=opt_config.track_states,
+        )
+        var = jax.vmap(
+            lambda data, coef, l2: compute_variances(obj, data, coef, l2, variance, w0.dtype)
+        )(data, res.coefficients, l2)
+        iters, evals = lane_counts(res, active)
+        return res.coefficients, res.convergence_reason, iters, evals, var
+
+    def re_bucket_solve(Xe, ye, we, oe, w0, l2, l1, active=None):
+        if lanes_form and not normal_equations.use_direct(
+            re_solver, k=Xe.shape[-1], has_l1=has_l1
+        ):
+            return lanes_solve(Xe, ye, we, oe, w0, l2, active)
+        if with_active:
+            return jax.vmap(lane_solve, in_axes=(0, 0, 0, 0, 0, 0, None, None))(
+                Xe, ye, we, oe, w0, l2, l1, active
+            )
+        return jax.vmap(lane_solve, in_axes=(0, 0, 0, 0, 0, 0, None))(
+            Xe, ye, we, oe, w0, l2, l1
+        )
+
+    return re_bucket_solve
+
+
+def lane_loop(opt_config: OptimizerConfig, has_l1: bool) -> bool:
+    """Whether a random-effect bucket that does not take the direct solve
+    runs its L-BFGS loops over the lanes (``lbfgs.minimize_lbfgs_lanes``)
+    rather than as ``jax.vmap`` of the one-lane solve: plain L-BFGS, no L1
+    (bucket solves take no bounds). OWLQN, TRON, NEWTON and LBFGSB keep
+    ``jax.vmap``."""
+    return (
+        OptimizerType(opt_config.optimizer_type) == OptimizerType.LBFGS and not has_l1
+    )
 
 
 @functools.lru_cache(maxsize=None)
